@@ -161,24 +161,35 @@ def _as_jaxprs(value):
     return []
 
 
+def _block_dim(dim):
+    """One entry of BlockMapping.block_shape as the rules read it: an int
+    block size, or None for a squeezed dim."""
+    from jax._src.pallas import core as pallas_core
+
+    if isinstance(dim, pallas_core.Squeezed):
+        return None
+    if isinstance(dim, (pallas_core.Blocked, pallas_core.Element,
+                        pallas_core.BoundedSlice)):
+        return int(dim.block_size)
+    raise TypeError(f'unrecognised pallas block dim {dim!r}')
+
+
 def _normalize(eqn):
-    """PallasCall from one pallas_call equation (jax >= 0.4.3x
-    GridMapping layout; anything unrecognised raises and surfaces as an
-    ML000 trace-error instead of a silent pass)."""
+    """PallasCall from one pallas_call equation (the GridMapping /
+    BlockMapping layout of the installed jax; anything unrecognised
+    raises and surfaces as an ML000 trace-error instead of a silent
+    pass)."""
     gm = eqn.params['grid_mapping']
     body = eqn.params['jaxpr']
-    if hasattr(body, 'jaxpr'):          # ClosedJaxpr on some versions
-        body = body.jaxpr
     blocks = []
     kinds = (['input'] * gm.num_inputs) + (['output'] * gm.num_outputs)
     for kind, bm in zip(kinds, gm.block_mappings):
-        sd = bm.array_shape_dtype
         blocks.append(BlockInfo(
             kind=kind,
-            origin=str(getattr(bm, 'origin', '') or ''),
-            block_shape=tuple(bm.block_shape),
-            array_shape=tuple(sd.shape),
-            dtype=sd.dtype,
+            origin=str(bm.origin or ''),
+            block_shape=tuple(_block_dim(d) for d in bm.block_shape),
+            array_shape=tuple(bm.array_aval.shape),
+            dtype=bm.array_aval.dtype,
         ))
     n_lead = gm.num_index_operands + gm.num_inputs + gm.num_outputs
     scratch = []
@@ -189,9 +200,8 @@ def _normalize(eqn):
             dtype=getattr(aval, 'dtype', None),
             memory_space=str(getattr(aval, 'memory_space', 'vmem')),
         ))
-    name = getattr(eqn.params.get('name_and_src_info'), 'name', None)
     return PallasCall(
-        name=name or 'pallas_call',
+        name=eqn.params['name'] or body.debug_info.func_name,
         grid=tuple(gm.grid),
         blocks=blocks,
         scratch=scratch,

@@ -398,6 +398,28 @@ def _onchip_headmajor():
     assert np.isfinite(out.astype(np.float32)).all()
 
 
+def _onchip_quant_matmul_int4():
+    """The packed-int4 unpack (widen, shift, interleave) against the
+    native-XLA path over the same packed codes."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops.pallas.quant_matmul import (_quant_matmul_xla,
+                                                    _unpack_int4,
+                                                    quant_matmul_int4,
+                                                    quantize_weight_int4)
+
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(64, 1024)), jnp.bfloat16)
+    wq, scale = quantize_weight_int4(
+        jnp.asarray(rng.normal(size=(1024, 512)), jnp.float32))
+    got = np.asarray(quant_matmul_int4(x, wq, scale), np.float32)
+    want = np.asarray(_quant_matmul_xla(x, _unpack_int4(wq), scale,
+                                        jnp.bfloat16), np.float32)
+    assert np.isfinite(got).all()
+    assert np.max(np.abs(got - want)) <= 2e-2 * np.max(np.abs(want))
+
+
 # ---------------------------------------------------------------------------
 # the registry
 # ---------------------------------------------------------------------------
@@ -443,7 +465,8 @@ ENTRIES = (
           onchip=_onchip_headmajor),
     Entry('quant_matmul/int8', _QMM, _build_quant_matmul('int8')),
     Entry('quant_matmul/fp8', _QMM, _build_quant_matmul('float8_e4m3fn')),
-    Entry('quant_matmul/int4', _QMM4, _build_quant_matmul('int4')),
+    Entry('quant_matmul/int4', _QMM4, _build_quant_matmul('int4'),
+          onchip=_onchip_quant_matmul_int4),
     Entry('rms_norm/fwd_bwd', _RMS, _build_rms(12288)),
     Entry('rms_norm/ragged_rows', _RMS, _build_rms(1000),
           suppress={

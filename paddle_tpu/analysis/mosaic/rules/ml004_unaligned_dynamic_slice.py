@@ -30,16 +30,13 @@ from . import register
 def _const_val(atom):
     if isinstance(atom, int):
         return atom
-    val = getattr(atom, 'val', None)    # jax.core.Literal
-    if isinstance(val, int):
-        return val
+    if not hasattr(atom, 'val'):        # not a jax.core.Literal
+        return None
     import numpy as np
 
-    # literals trace as 0-d numpy arrays (array(64, dtype=int32))
-    if isinstance(val, np.integer):
-        return int(val)
-    if (isinstance(val, np.ndarray) and val.ndim == 0
-            and np.issubdtype(val.dtype, np.integer)):
+    # literals carry 0-d typed arrays (TypedNdArray(64, dtype=int32))
+    val = np.asarray(atom.val)
+    if val.ndim == 0 and np.issubdtype(val.dtype, np.integer):
         return int(val)
     return None
 

@@ -5,9 +5,9 @@ Inside `shard_map` the collectives are hand-written, and the classic
 silent bug is a collective over the WRONG axis: `psum(x, 'tp')` where
 nothing in the body varies over 'tp' multiplies every value by the
 axis size; a ppermute over it is an expensive identity.  The repo's
-sequence/pipeline wrappers run with the replication checker off
-(`check_vma=False` — the varying-types system predates this jaxlib),
-so nothing at trace time catches it.  This rule re-derives the check
+sequence-parallel wrappers (ring attention, Ulysses) run with jax's
+varying-axes checker off (`check_vma=False`), so nothing at trace
+time catches it there.  This rule re-derives the check
 statically from the traced jaxpr: for each shard_map equation it
 collects the axes the body CAN vary over — axes an in_spec splits,
 axes promoted by pvary/pcast, axes branched on via axis_index — and

@@ -1,88 +1,30 @@
-"""Version bridge for the shard_map surface the distributed layer uses.
+"""The shard_map surface the distributed layer uses, in one place.
 
-The distributed modules (ring_attention, ulysses, pipeline, llama's
-sharded decode dispatch) are written against the current shard_map API:
-`jax.shard_map(..., axis_names=..., check_vma=...)` plus
-`lax.axis_size` and `lax.pvary`/`lax.pcast`.  Older jaxlibs (the pinned
-0.4.x line) ship shard_map as `jax.experimental.shard_map.shard_map`
-with the predecessor knobs — `check_rep` instead of `check_vma`,
-`auto` (the complement set) instead of `axis_names` — and no
-axis_size/pvary at all.  Every caller goes through this module so the
-difference lives in exactly one place:
-
-  - `shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-    check_vma=None)`: new-API passthrough when `jax.shard_map` exists;
-    otherwise the experimental entry point with
-    `auto = mesh.axis_names - axis_names` and `check_rep=False` (the
-    old replication checker predates the varying-manual-axes system
-    these bodies are written for — pvary-less code trips it even when
-    the collectives are right, so the bridge disables it and shardlint's
-    SL006 statically checks the collective/axis pairing instead).
-  - `axis_size(axis)`: `lax.axis_size` when present, else the classic
-    `psum(1, axis)` — which jax constant-folds to a static int under
-    shard_map, so loop bounds stay Python ints.
-  - `pvary(x, axis)`: pcast/pvary when present; identity on the old
-    rep system (with check_rep=False nothing consumes the annotation).
+ring_attention, ulysses, pipeline and llama's sharded decode dispatch
+call `jax.shard_map(..., axis_names=..., check_vma=...)`,
+`lax.axis_size` and `lax.pcast` through these three names.
 """
 from __future__ import annotations
 
 import jax
 from jax import lax
 
-
-def axis_size(axis) -> int:
-    if hasattr(lax, 'axis_size'):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+axis_size = lax.axis_size
 
 
 def pvary(x, axis):
-    """Promote a replicated value to varying over `axis` (identity on
-    jax versions without the vma type system)."""
-    if hasattr(lax, 'pcast'):
-        return lax.pcast(x, axis, to='varying')
-    if hasattr(lax, 'pvary'):
-        return lax.pvary(x, axis)
-    return x
+    """Promote a replicated value to varying over `axis`."""
+    return lax.pcast(x, axis, to='varying')
 
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None,
               axis_names=None, check_vma=None):
-    """`jax.shard_map` with the current keyword surface on any jax.
-
-    `axis_names` is the set of MANUAL axes (None = all mesh axes);
-    `check_vma` maps to the old `check_rep` only in the False direction
-    (see module docstring).
-    """
-    if hasattr(jax, 'shard_map'):
-        kwargs = {}
-        if axis_names is not None:
-            kwargs['axis_names'] = set(axis_names)
-        if check_vma is not None:
-            kwargs['check_vma'] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset()
+    """`jax.shard_map`; `axis_names` is the set of MANUAL axes (None =
+    all mesh axes), `check_vma=None` keeps jax's default."""
+    kwargs = {}
     if axis_names is not None:
-        # size-1 axes are semantically identical manual or auto (no
-        # collective can span them, specs split nothing) — keeping them
-        # manual avoids the old partial-auto path entirely on the
-        # common "only the scheduled axis is > 1" meshes, which this
-        # jaxlib's SPMD partitioner cannot lower (PartitionId refusal)
-        auto = frozenset(a for a in mesh.axis_names
-                         if a not in frozenset(axis_names)
-                         and mesh.shape[a] > 1)
-    fn = _shard_map(f, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_rep=False, auto=auto)
-    if auto:
-        # the old implementation refuses partial-auto OUTSIDE a jit
-        # (`if auto: raise NotImplementedError` in its eager impl);
-        # under jit it stages fine — so eager callers get a jitted view.
-        # tracelint: disable=TL001 - the wrapper is built once per
-        # shard_map construction and cached by the CALLER exactly like
-        # the shard_map closure it wraps; inside an outer jit it stages
-        # as a no-op nested pjit
-        fn = jax.jit(fn)
-    return fn
+        kwargs['axis_names'] = set(axis_names)
+    if check_vma is not None:
+        kwargs['check_vma'] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

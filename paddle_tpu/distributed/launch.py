@@ -13,7 +13,10 @@ multi-process testing:
     python -m paddle_tpu.distributed.launch train.py --args...
 
     # spawn N local processes wired through a localhost coordinator
-    # (reference: --nproc_per_node), per-rank logs under --log_dir:
+    # (reference: --nproc_per_node), per-rank logs under --log_dir.
+    # CPU/GPU hosts only: on a host with TPU chips this is refused,
+    # since every rank would claim every chip (one process drives all
+    # local chips there):
     python -m paddle_tpu.distributed.launch --nproc_per_node 4 \\
         --log_dir ./logs train.py --args...
 
@@ -83,6 +86,28 @@ def _free_port():
         return s.getsockname()[1]
 
 
+def _refuse_shared_tpu(nprocs, child_env):
+    """A chip belongs to one process, and every rank spawned here would
+    claim ALL of this host's chips: the first wins, the rest fail or hang
+    in backend init. One process drives every local chip (`jax.devices()`
+    under one mesh), so on a TPU host several local ranks are refused
+    unless their environment pins them to another platform."""
+    platforms = child_env.get('JAX_PLATFORMS', '')
+    if nprocs < 2 or (platforms and 'tpu' not in platforms.split(',')):
+        return
+    # the PCI scan jax itself uses to decide whether this is a TPU host;
+    # it initialises no backend
+    from jax._src import hardware_utils
+
+    chips, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    if chips:
+        raise RuntimeError(
+            f'launch: refusing to spawn {nprocs} local processes on a host '
+            f'with {chips} TPU chip(s): each would claim every chip and '
+            f'all but one would fail or hang. Run ONE process (it sees '
+            f'all local chips), or set JAX_PLATFORMS=cpu for CPU ranks.')
+
+
 def launch_local(script, script_args=(), nprocs=1, log_dir=None, env=None,
                  poll_s=0.2, timeout_s=None, with_info=False):
     """Spawn `nprocs` local ranks of `script` wired through a localhost
@@ -100,6 +125,7 @@ def launch_local(script, script_args=(), nprocs=1, log_dir=None, env=None,
     -SIGKILL for a straggler that ignored SIGTERM) are collateral, not
     the root failure, and must not masquerade as it.
     """
+    _refuse_shared_tpu(nprocs, {**os.environ, **(env or {})})
     port = _free_port()
     procs = []
     logs = []

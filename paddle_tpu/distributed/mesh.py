@@ -108,9 +108,16 @@ def force_virtual_devices(n: int) -> None:
     XLA_FLAGS unless a count is already forced. Only effective BEFORE
     the backend initialises (and ignored by jax afterwards) — callers
     that need the devices to actually exist must still count them.
-    The 8 floor matches the shardlint / test-rig virtual mesh."""
+    The 8 floor matches the shardlint / test-rig virtual mesh.
+
+    A crutch for the CPU rig only: a no-op unless jax is pinned to the
+    CPU (`JAX_PLATFORMS=cpu`). On a TPU host the mesh is built from the
+    real chips and nothing about the process is changed."""
     import os
 
+    if (jax.config.jax_platforms
+            or os.environ.get('JAX_PLATFORMS', '')) != 'cpu':
+        return
     flags = os.environ.get('XLA_FLAGS', '')
     if 'xla_force_host_platform_device_count' not in flags:
         os.environ['XLA_FLAGS'] = (
@@ -123,13 +130,13 @@ def serving_mesh(tp: int, devices=None) -> Mesh:
     (`ServingEngine(model, tp=4)` builds one of these internally; pass
     an explicit `devices` slice to pin which chips serve).
 
-    Virtual-device fallback: when `devices` is not given and jax has
-    not initialised a backend yet, the host-platform device-count flag
-    is forced (to at least `tp`, and at least the 8 the shardlint /
-    test rig uses) so CPU dev boxes can stand up a tp>1 engine without
-    exporting XLA_FLAGS by hand. A backend that already woke up with
-    fewer devices cannot be grown — that raises with the recipe
-    instead of silently serving single-device."""
+    The mesh is the first `tp` of `jax.devices()` — real chips on a TPU
+    host. Where jax is pinned to the CPU and has not initialised a
+    backend yet, the host-platform device-count flag is forced first
+    (to at least `tp`, and at least the 8 the shardlint / test rig
+    uses) so CPU dev boxes can stand up a tp>1 engine without
+    exporting XLA_FLAGS by hand. A backend with fewer than `tp`
+    devices raises instead of silently serving single-device."""
     tp = int(tp)
     if tp < 1:
         raise ValueError(f'tp must be >= 1, got {tp}')
@@ -141,10 +148,10 @@ def serving_mesh(tp: int, devices=None) -> Mesh:
     if len(devices) < tp:
         raise ValueError(
             f'serving_mesh(tp={tp}) needs {tp} devices, found '
-            f'{len(devices)}: the backend initialised before the '
-            f'virtual-device flag could be set — run with XLA_FLAGS='
-            f'--xla_force_host_platform_device_count={max(tp, 8)} '
-            f'(and JAX_PLATFORMS=cpu) for a virtual mesh')
+            f'{len(devices)} on backend {jax.default_backend()!r} — '
+            f'for a virtual CPU mesh run with JAX_PLATFORMS=cpu XLA_FLAGS='
+            f'--xla_force_host_platform_device_count={max(tp, 8)} set '
+            f'before jax initialises')
     return build_mesh(devices=devices[:tp], tp=tp)
 
 

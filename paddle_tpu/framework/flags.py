@@ -13,9 +13,6 @@ _flags: typing.Dict[str, typing.Any] = {
     'FLAGS_embedding_deterministic': 0,
     'FLAGS_check_nan_inf': False,
     'FLAGS_use_pallas_kernels': True,
-    # make a failing pallas kernel raise instead of silently taking the
-    # (much slower) lax fallback
-    'FLAGS_pallas_strict': False,
     'FLAGS_default_dtype': 'float32',
 }
 

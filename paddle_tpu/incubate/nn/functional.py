@@ -367,18 +367,13 @@ def masked_multihead_attention(x, cache_kv=None, bias=None, src_mask=None,
         from ...ops import use_pallas
 
         if use_pallas() and D % 8 == 0:
-            try:
-                # head-major contiguous variant of the paged kernel:
-                # streams any cache length blockwise, no transpose
-                from ...ops.pallas.paged_attention import (
-                    decode_attention_headmajor)
+            # head-major contiguous variant of the paged kernel:
+            # streams any cache length blockwise, no transpose
+            from ...ops.pallas.paged_attention import (
+                decode_attention_headmajor)
 
-                out = decode_attention_headmajor(
-                    q[:, None], ck, cv, counts)[:, 0]
-            except Exception as e:  # noqa: BLE001
-                from ...ops import pallas_failed
-
-                pallas_failed('paged_attention', e)
+            out = decode_attention_headmajor(
+                q[:, None], ck, cv, counts)[:, 0]
     if out is None:
         logits = jnp.einsum('bhd,bhsd->bhs', q.astype(jnp.float32),
                             ck.astype(jnp.float32)) / (D ** 0.5)
@@ -570,18 +565,13 @@ def block_multihead_attention(
         from ...ops import use_pallas
 
         if use_pallas() and D % 8 == 0 and tgt_mask is None:
-            try:
-                from ...ops.pallas.paged_attention import (
-                    paged_decode_attention)
+            from ...ops.pallas.paged_attention import (
+                paged_decode_attention)
 
-                out = paged_decode_attention(
-                    q[:, None], key_cache, value_cache, tbl, counts,
-                    k_scale=kds if quant_cache else None,
-                    v_scale=vds if quant_cache else None)[:, 0]
-            except Exception as e:  # noqa: BLE001
-                from ...ops import pallas_failed
-
-                pallas_failed('paged_attention', e)
+            out = paged_decode_attention(
+                q[:, None], key_cache, value_cache, tbl, counts,
+                k_scale=kds if quant_cache else None,
+                v_scale=vds if quant_cache else None)[:, 0]
         if out is None:
             # XLA fallback: gather each row's pages to a contiguous view
             maxb = tbl.shape[1]

@@ -468,24 +468,14 @@ class DecodeEngine:
                              else None)
         self.buckets = tuple(sorted(buckets)) if buckets else DEFAULT_BUCKETS
         if persistent_cache is None:
-            # env contract: boolean-ish values toggle the DEFAULT dir
-            # ('true'/'yes'/'on' count as on — a deployment writing a
-            # conventional boolean must not get a junk './true' cache
-            # dir); anything else is an explicit cache DIRECTORY
-            env = os.environ.get('PADDLE_TPU_PERSISTENT_CACHE', '')
-            low = env.strip().lower()
-            if low in ('', '0', 'false', 'no', 'off'):
-                persistent_cache = False
-            elif low in ('1', 'true', 'yes', 'on'):
-                persistent_cache = True
-            else:
-                persistent_cache = env
+            # on/off only: where the cache lives is sysconfig's rule
+            persistent_cache = os.environ.get(
+                'PADDLE_TPU_PERSISTENT_CACHE', '').strip().lower() in (
+                    '1', 'true', 'yes', 'on')
         if persistent_cache:
             from .. import sysconfig
 
-            sysconfig.enable_persistent_compilation_cache(
-                persistent_cache if isinstance(persistent_cache, str)
-                else None)
+            sysconfig.enable_persistent_compilation_cache()
         params = inspect.signature(model.forward).parameters
         self._supports_padding = ('positions' in params
                                   and 'kv_start' in params)

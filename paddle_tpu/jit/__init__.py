@@ -160,26 +160,22 @@ def enable_to_static(flag=True):
     return None
 
 
-def enable_compilation_cache(cache_dir='~/.cache/paddle_tpu/xla_cache',
-                             min_compile_time_secs=1.0):
+def enable_compilation_cache(cache_dir=None, min_compile_time_secs=1.0):
     """AOT compile cache (ref capability: CINN compile cache + Paddle's
     program cache). Wires jax's persistent compilation cache so repeat
     runs skip XLA compilation entirely.
 
     Delegates to sysconfig.enable_persistent_compilation_cache — the
-    ONE place that owns the wiring (explicit directory, telemetry
-    instant/gauge, and the reset of jax's once-per-process cache-used
-    verdict, without which enabling after any compile silently never
-    persists; paddle_tpu.aot artifacts depend on all three) — then
+    ONE place that owns the wiring and the default directory
+    (`JAX_COMPILATION_CACHE_DIR`, else `<checkout>/.jax_cache`) — then
     re-raises the persistence threshold to `min_compile_time_secs`
     (this entry point's contract: only compilations worth caching)."""
     import jax
 
     from ..sysconfig import enable_persistent_compilation_cache
 
-    path = enable_persistent_compilation_cache(
-        os.path.expanduser(cache_dir))
-    if path is not None and min_compile_time_secs:
+    path = enable_persistent_compilation_cache(cache_dir)
+    if min_compile_time_secs:
         jax.config.update('jax_persistent_cache_min_compile_time_secs',
                           min_compile_time_secs)
     return path

@@ -308,76 +308,34 @@ def cached_attention(q, k, v, cache, cache_index, kvalid=None,
             # fused single-token decode: one streaming pass over the
             # cache, routed through the serving dispatcher
             # (ops/pallas/decode_attention.py — the same entry point the
-            # DecodeEngine decode loop reaches); under a tp mesh each
-            # head-shard runs its own kernel via shard_map (the GQA
-            # group alignment survives contiguous head sharding)
-            try:
-                from ..ops.pallas.decode_attention import (
-                    dispatch_decode_attention)
+            # DecodeEngine decode loop reaches). Under a mesh each shard
+            # runs its own kernel, placed as init_cache places the cache
+            # (batch over dp/fsdp, heads over tp), so a sharded cache is
+            # NOT all-gathered every decode step
+            from jax.sharding import PartitionSpec as P
 
-                mesh = None
-                from ..distributed.mesh import get_mesh
+            from ..ops import DATA_AXES, head_axis, mesh_kernel
+            from ..ops.pallas.decode_attention import (
+                dispatch_decode_attention)
 
-                m = get_mesh()
-                if (m is not None and m.shape.get('tp', 1) > 1
-                        and ck.shape[2] % m.shape['tp'] == 0
-                        and H % m.shape['tp'] == 0):
-                    mesh = m
-                if mesh is not None:
-                    from jax.sharding import PartitionSpec as P
+            def kernel(q_, k_, v_, vl_, st_, *scales):
+                return dispatch_decode_attention(
+                    q_, k_, v_, vl_, start=st_, window=window,
+                    k_scale=scales[0] if scales else None,
+                    v_scale=scales[1] if scales else None)
 
-                    from ..distributed._spmd import shard_map
-
-                    from ..distributed.parallel import _valid_spec
-
-                    # mirror init_cache's placement (batch over dp/fsdp
-                    # when divisible, heads over tp) so a batch-sharded
-                    # cache is NOT all-gathered every decode step
-                    hspec = _valid_spec(
-                        P(('dp', 'fsdp'), None, 'tp', None), ck.shape, mesh)
-                    bat = hspec[0]
-                    vl = jnp.broadcast_to(jnp.asarray(
-                        wp + 1 if kv_write_pos is not None
-                        else cache_index + 1, jnp.int32), (B,))
-                    st = jnp.broadcast_to(jnp.asarray(
-                        0 if kv_start is None else kv_start, jnp.int32),
-                        (B,))
-                    if quant:
-                        sspec = _valid_spec(P('tp', None), kscale.shape,
-                                            mesh)
-
-                        def _da8(q_, k_, v_, vl_, st_, ks_, vs_):
-                            return dispatch_decode_attention(
-                                q_, k_, v_, vl_, start=st_, window=window,
-                                k_scale=ks_, v_scale=vs_)
-
-                        out = shard_map(
-                            _da8, mesh=mesh,
-                            in_specs=(hspec, hspec, hspec, P(bat), P(bat),
-                                      sspec, sspec),
-                            out_specs=hspec, check_vma=False,
-                        )(q, ck, cv, vl, st, kscale, vscale)
-                    else:
-                        def _da(q_, k_, v_, vl_, st_):
-                            return dispatch_decode_attention(
-                                q_, k_, v_, vl_, start=st_, window=window)
-
-                        out = shard_map(
-                            _da, mesh=mesh,
-                            in_specs=(hspec, hspec, hspec, P(bat), P(bat)),
-                            out_specs=hspec, check_vma=False,
-                        )(q, ck, cv, vl, st)
-                else:
-                    vl1 = (wp + 1 if kv_write_pos is not None
-                           else cache_index + 1)
-                    out = dispatch_decode_attention(
-                        q, ck, cv, vl1, start=kv_start, window=window,
-                        k_scale=kscale if quant else None,
-                        v_scale=vscale if quant else None)
-            except Exception as e:
-                from ..ops import pallas_failed
-
-                pallas_failed('decode_attention', e)
+            vl = jnp.broadcast_to(jnp.asarray(
+                wp + 1 if kv_write_pos is not None else cache_index + 1,
+                jnp.int32), (B,))
+            st = jnp.broadcast_to(jnp.asarray(
+                0 if kv_start is None else kv_start, jnp.int32), (B,))
+            heads = head_axis(H, ck.shape[2])
+            hspec = P(DATA_AXES, None, heads, None)
+            scales = (kscale, vscale) if quant else ()
+            out = mesh_kernel(
+                kernel, (q, ck, cv, vl, st) + scales,
+                (hspec, hspec, hspec, P(DATA_AXES), P(DATA_AXES))
+                + (P(heads, None),) * len(scales))
     if out is None:
         # valid keys: position <= current query position (& kvalid)
         kpos = jnp.arange(max_len)
@@ -485,18 +443,28 @@ def _paged_cached_attention(q, k, v, cache, kv_write_pos, block_tables,
         from ..ops import use_pallas
 
         if use_pallas():
-            try:
-                from ..ops.pallas.paged_attention import (
-                    paged_decode_attention)
+            from jax.sharding import PartitionSpec as P
 
-                out = paged_decode_attention(
-                    q, kp, vp, tbl, counts,
-                    k_scale=kss if quant else None,
-                    v_scale=vss if quant else None)
-            except Exception as e:
-                from ..ops import pallas_failed
+            from ..ops import DATA_AXES, head_axis, mesh_kernel
+            from ..ops.pallas.paged_attention import (
+                paged_decode_attention)
 
-                pallas_failed('paged_attention', e)
+            def kernel(q_, kp_, vp_, tbl_, counts_, *scales):
+                return paged_decode_attention(
+                    q_, kp_, vp_, tbl_, counts_,
+                    k_scale=scales[0] if scales else None,
+                    v_scale=scales[1] if scales else None)
+
+            # pools split their kv-head dim over tp (init_paged_cache's
+            # placement); tables and lengths follow the batch
+            heads = head_axis(H, Hkv)
+            pool = P(None, heads, None, None)
+            scales = (kss, vss) if quant else ()
+            out = mesh_kernel(
+                kernel, (q, kp, vp, tbl, counts) + scales,
+                (P(DATA_AXES, None, heads, None), pool, pool,
+                 P(DATA_AXES, None), P(DATA_AXES))
+                + (P(None, heads, None),) * len(scales))
     if out is None:
         # gather reference (CPU tests / non-TPU): pages -> a contiguous
         # (B, MAXB*BS, Hkv, D) view, masked by per-row valid length;

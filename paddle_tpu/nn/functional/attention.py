@@ -92,17 +92,24 @@ def scaled_dot_product_attention(
         and use_pallas()
     )
     if use_flash:
-        try:
-            from ...ops.pallas.flash_attention import flash_attention
+        from jax.sharding import PartitionSpec as P
 
-            return flash_attention(query, key, value, causal=is_causal,
-                                   scale=scale, segment_ids=segment_ids,
-                                   kv_segment_ids=kv_segment_ids,
-                                   window_size=window_size)
-        except Exception as e:
-            from ...ops import pallas_failed
+        from ...ops import DATA_AXES, head_axis, mesh_kernel
+        from ...ops.pallas.flash_attention import flash_attention
 
-            pallas_failed('flash_attention', e)
+        def kernel(q, k, v, *segs):
+            return flash_attention(
+                q, k, v, causal=is_causal, scale=scale,
+                segment_ids=segs[0] if segs else None,
+                kv_segment_ids=segs[1] if segs else None,
+                window_size=window_size)
+
+        segs = (() if segment_ids is None
+                else (segment_ids, kv_segment_ids))
+        qkv = P(DATA_AXES, None,
+                head_axis(query.shape[2], key.shape[2]), None)
+        return mesh_kernel(kernel, (query, key, value) + segs,
+                           (qkv,) * 3 + (P(DATA_AXES, None),) * len(segs))
     if window_size is not None:
         # fold the band into the mask for the reference path
         Sq, Sk = query.shape[1], key.shape[1]

@@ -31,8 +31,10 @@ _COST_FIELDS = (('flops', 'flops'),
                 ('bytes_accessed', 'bytes accessed'),
                 ('transcendentals', 'transcendentals'))
 
-# per-chip dense bf16 peak (the bench.py table; longest-prefix matched
-# so 'TPU v5 lite' cannot shadow 'TPU v5p' or vice versa)
+# per-chip dense bf16 peak, from Google Cloud's per-generation TPU
+# documentation — the repo's ONE peaks table, keyed by `device_kind`
+# (longest-prefix matched so 'TPU v5 lite' cannot shadow 'TPU v5p' or
+# vice versa)
 PEAK_BF16_FLOPS = {
     'TPU v2': 45e12, 'TPU v3': 123e12, 'TPU v4': 275e12,
     'TPU v5 lite': 197e12, 'TPU v5e': 197e12, 'TPU v5': 459e12,
@@ -156,9 +158,11 @@ def measure_dispatch_costs(engine, geometries=None, draft=None):
 def device_peak_flops(device=None):
     """Peak dense flops/s the MFU denominator divides by:
     `PADDLE_TPU_PEAK_FLOPS` (explicit, any backend — what the bench
-    gate pins) wins; else the bf16 table for known TPU kinds; else None
-    — an honest "unknown" beats a fabricated MFU, so the engines skip
-    the `*.mfu_est` gauge and still record achieved flops/s."""
+    gate pins) wins; else the bf16 table for known TPU kinds. A TPU
+    whose kind is not in the table is an error — a peak assumed for an
+    unknown chip makes every MFU derived from it a fabrication. Off the
+    TPU there is no peak: None, and the engines skip the `*.mfu_est`
+    gauge while still recording achieved flops/s."""
     env = os.environ.get('PADDLE_TPU_PEAK_FLOPS')
     if env:
         try:
@@ -171,10 +175,14 @@ def device_peak_flops(device=None):
         d = device if device is not None else jax.devices()[0]
     except Exception:  # noqa: BLE001 - no backend: no peak
         return None
-    kind = str(getattr(d, 'device_kind', '')).lower()
+    kind = str(getattr(d, 'device_kind', ''))
     best = None
     for k, v in PEAK_BF16_FLOPS.items():
-        if kind.startswith(k.lower()):
+        if kind.casefold().startswith(k.casefold()):
             if best is None or len(k) > best[0]:
                 best = (len(k), v)
+    if best is None and getattr(d, 'platform', None) == 'tpu':
+        raise ValueError(
+            f'no peak flops known for TPU device kind {kind!r}: add it '
+            f'(with its source) to observability.costs.PEAK_BF16_FLOPS')
     return best[1] if best else None

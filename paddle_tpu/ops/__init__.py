@@ -1,16 +1,13 @@
-"""Hand-written TPU kernels (pallas) with lax fallbacks.
+"""Hand-written TPU kernels (pallas) with lax references.
 
 Dispatch policy: pallas kernels on TPU backends, pure-lax reference
 implementations elsewhere (CPU tests) — same math, verified against each
-other in tests/test_pallas.py.
+other in tests/test_pallas.py. The choice is made from the backend, never
+from a failure: a kernel that raises on the TPU raises to the caller.
 """
 from __future__ import annotations
 
-import warnings
-
 import jax
-
-_kernel_warned = set()
 
 
 def _on_tpu():
@@ -27,39 +24,68 @@ def use_pallas():
         'FLAGS_use_pallas_kernels']
 
 
-def pallas_failed(kernel_name, exc):
-    """A pallas kernel raised while use_pallas() was true.
+# mesh axes a kernel's batch dim may stay split over
+DATA_AXES = ('dp', 'fsdp')
 
-    Strict mode (``FLAGS_pallas_strict``) re-raises — a broken kernel is
-    a perf cliff that should fail loudly in CI. Otherwise warn ONCE per
-    kernel and let the caller fall back to the lax reference.
+
+def mesh_kernel(kernel, args, specs, out=0):
+    """Call a pallas kernel on array `args` under the active device mesh.
+
+    Mosaic kernels cannot be partitioned by GSPMD (jax refuses to lower
+    one inside a multi-device jit: "wrap the call in a shard_map"), so
+    under a mesh of several devices the call runs per shard: `specs` names,
+    per argument, the dims that may stay split (batch over the data axes,
+    heads over 'tp' — each clamped to what the mesh has and the shape
+    divides), GSPMD reshards the operands to match, and the result carries
+    the clamped spec of argument `out`. With no mesh, on one device, or in
+    a shard_map body that already holds mesh axes manually (ring/Ulysses
+    attention, the pipeline schedules) it is a plain call.
     """
-    from ..framework.flags import get_flags
+    from ..distributed.mesh import get_mesh
 
-    if get_flags(['FLAGS_pallas_strict'])['FLAGS_pallas_strict']:
-        raise RuntimeError(
-            f'pallas kernel {kernel_name!r} failed and FLAGS_pallas_strict '
-            f'is set (lax fallback suppressed): {exc!r}'
-        ) from exc
-    if kernel_name not in _kernel_warned:
-        _kernel_warned.add(kernel_name)
-        warnings.warn(
-            f'pallas kernel {kernel_name!r} failed ({exc!r}); falling back '
-            f'to the lax reference implementation. This is a large perf '
-            f'cliff on TPU — set FLAGS_pallas_strict=True to make it fatal.',
-            stacklevel=3,
-        )
+    mesh = get_mesh()
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return kernel(*args)
+    from ..distributed._spmd import shard_map
+    from ..distributed.parallel import _valid_spec
+
+    in_specs = tuple(_valid_spec(s, a.shape, mesh)
+                     for s, a in zip(specs, args))
+    return shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                     out_specs=in_specs[out], check_vma=False)(*args)
+
+
+def head_axis(q_heads, kv_heads):
+    """The mesh axis attention heads may stay split over: 'tp' when the
+    active mesh's tp degree divides BOTH head counts (a GQA group must not
+    straddle shards), else None (heads whole on every shard)."""
+    from ..distributed.mesh import get_mesh
+
+    mesh = get_mesh()
+    tp = mesh.shape.get('tp', 1) if mesh is not None else 1
+    return 'tp' if q_heads % tp == 0 and kv_heads % tp == 0 else None
+
+
+def _rows_spec(ndim):
+    """Spec of a (batch, [seq,] ..., features) operand whose rows are
+    independent: batch over the data axes, seq over 'sp', features whole."""
+    from jax.sharding import PartitionSpec as P
+
+    return P(*((DATA_AXES, 'sp') + (None,) * ndim)[:ndim - 1], None)
 
 
 def rms_norm(x, weight=None, epsilon=1e-6):
     """Fused RMSNorm; pallas kernel on TPU (ops/pallas/rms_norm.py)."""
     if use_pallas() and x.shape[-1] % 128 == 0 and x.dtype != jax.numpy.float64:
-        try:
-            from .pallas.rms_norm import rms_norm as _k
+        from jax.sharding import PartitionSpec as P
 
-            return _k(x, weight, epsilon)
-        except Exception as e:
-            pallas_failed('rms_norm', e)
+        from .pallas.rms_norm import rms_norm as _k
+
+        weights = () if weight is None else (weight,)
+        return mesh_kernel(
+            lambda x_, *w: _k(x_, w[0] if w else None, epsilon),
+            (x,) + weights, (_rows_spec(x.ndim),) + (P(),) * len(weights))
     from ..nn.functional.norm import rms_norm as _ref
 
     return _ref(x, weight, epsilon)
@@ -68,17 +94,19 @@ def rms_norm(x, weight=None, epsilon=1e-6):
 def softmax_cross_entropy(logits, labels):
     """Fused softmax-xent; pallas on TPU (ops/pallas/softmax_xent.py),
     lax reference elsewhere. Per-example nll, fp32."""
-    import jax
     import jax.numpy as jnp
 
     # any vocab size: the kernel masks the padded tail block (the guard
     # only excludes degenerate tiny vocabs where tiling can't help)
     if use_pallas() and logits.shape[-1] >= 128:
-        try:
-            from .pallas.softmax_xent import softmax_cross_entropy_with_logits
+        from jax.sharding import PartitionSpec as P
 
-            return softmax_cross_entropy_with_logits(logits, labels)
-        except Exception as e:
-            pallas_failed('softmax_cross_entropy', e)
+        from .pallas.softmax_xent import softmax_cross_entropy_with_logits
+
+        # the kernel reduces over whole vocab rows: a vocab-parallel
+        # (tp-sharded) logits row is gathered first
+        rows = _rows_spec(logits.ndim)
+        return mesh_kernel(softmax_cross_entropy_with_logits,
+                           (logits, labels), (rows, P(*rows[:-1])), out=1)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     return -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]

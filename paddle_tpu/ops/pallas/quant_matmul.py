@@ -75,8 +75,10 @@ def quantize_weight_int4(w, axis=0):
 
 def _unpack_int4(w8):
     """(bk/2, bn) packed int8 → (bk, bn) fp32 sign-extended codes."""
-    lo = jnp.right_shift(jnp.left_shift(w8, 4), 4)       # arithmetic: sext
-    hi = jnp.right_shift(w8, 4)
+    # widened first: Mosaic has no shifts on int8 vectors
+    w32 = w8.astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(w32, 28), 28)    # arithmetic: sext
+    hi = jnp.right_shift(w32, 4)
     half, bn = w8.shape
     return jnp.stack([lo, hi], axis=1).reshape(2 * half, bn).astype(
         jnp.float32)

@@ -24,57 +24,50 @@ _COMPILATION_CACHE_DIR = None
 
 
 def enable_persistent_compilation_cache(path=None):
-    """Wire jax's on-disk executable cache so serving restarts skip XLA
-    compilation entirely (the in-process jit cache only survives the
-    process; this one survives reboots). Used by
-    inference.engine.DecodeEngine(persistent_cache=True), by
-    `paddle_tpu.aot` artifacts (build persists INTO an artifact's cache
-    dir, warm-attach re-wires it), and honored directly by the
-    PADDLE_TPU_PERSISTENT_CACHE env var ('1' for the default dir, any
-    other non-empty value is an explicit directory).
+    """Wire jax's on-disk executable cache so a restarted process skips
+    XLA compilation. The one place that decides where the cache lives:
 
-    `path` is the explicit cache directory; an explicit path always
-    wins over (and replaces) a previously wired one — an artifact
-    attach must not silently keep writing into the default cache.
-    Default is get_lib()/xla_cache (the same PADDLE_TPU_CACHE root the
-    native helpers use). Thresholds are dropped to zero so even small
-    decode-step executables persist. Idempotent; returns the cache
-    directory (None if this jax build has no compilation-cache
-    support).
+    - an explicit `path` wins (and replaces a previously wired one):
+      `paddle_tpu.aot` artifacts pass their own directory, because there
+      the directory *is* the artifact;
+    - otherwise `JAX_COMPILATION_CACHE_DIR` when it is set — the handle a
+      deployment places the cache with from outside; jax has already read
+      it, and no other directory is set in code;
+    - otherwise `<checkout>/.jax_cache`, next to the package. Always the
+      same name: a directory that moves between runs never hits.
 
-    The wired directory is observable in the PR-6 telemetry: a
+    Used by `chip_smoke.py` and `bench.py` at their top, by
+    `DecodeEngine(persistent_cache=True)` and by
+    `PADDLE_TPU_PERSISTENT_CACHE=1` (an on/off switch only). Thresholds
+    drop to zero so even small decode-step executables persist.
+    Idempotent; returns the cache directory.
+
+    The wired directory is observable in telemetry: a
     `compile.persistent_cache_dir` instant on the host trace (with the
-    path) and a `compile.persistent_cache_enabled` gauge in the
-    registry, so artifact-backed runs are distinguishable from
-    cold ones in every telemetry dump."""
+    path) and a `compile.persistent_cache_enabled` gauge in the registry,
+    so artifact-backed runs are distinguishable from cold ones."""
     global _COMPILATION_CACHE_DIR
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-    if path is None:
-        path = _COMPILATION_CACHE_DIR or os.path.join(get_lib(), 'xla_cache')
-    path = os.path.abspath(os.path.expanduser(path))
-    if 'jax_compilation_cache_dir' not in jax.config.values:
-        return None
+    if path is not None:
+        path = os.path.abspath(os.path.expanduser(path))
+    else:
+        path = os.environ.get('JAX_COMPILATION_CACHE_DIR') or os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            '.jax_cache')
     os.makedirs(path, exist_ok=True)
-    jax.config.update('jax_compilation_cache_dir', path)
-    for opt, val in (('jax_persistent_cache_min_compile_time_secs', 0.0),
-                     ('jax_persistent_cache_min_entry_size_bytes', -1)):
-        try:
-            jax.config.update(opt, val)
-        except Exception:  # noqa: BLE001 - older jax: keep its defaults
-            pass
+    if jax.config.jax_compilation_cache_dir != path:
+        jax.config.update('jax_compilation_cache_dir', path)
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
+    jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
     _COMPILATION_CACHE_DIR = path
     # jax freezes its is-the-cache-used verdict at the FIRST compile of
     # the process; wiring a directory after any compile (engine
     # construction alone compiles helpers) would silently never
     # persist. reset_cache() clears that verdict so the next compile
     # re-evaluates against the directory just wired.
-    try:
-        from jax._src.compilation_cache import reset_cache
-
-        reset_cache()
-    except Exception:  # noqa: BLE001 - private API moved: best effort
-        pass
+    compilation_cache.reset_cache()
     from .observability import metrics as _obs
     from .observability import tracing as _obs_trace
 
@@ -91,18 +84,19 @@ def persistent_compilation_cache_dir():
 
 
 def restore_persistent_compilation_cache(path):
-    """Re-wire the persistent cache to `path`, or fully UNWIRE it when
-    `path` is None — the restore half of a scoped redirection (aot.build
-    points the cache at an artifact directory for the duration of the
-    build only; leaving it wired would leak every later compile of a
-    still-serving builder into the artifact, and starve whatever dir
-    the process had wired before)."""
+    """Re-wire the persistent cache to `path`, or UNWIRE it (back to what
+    the environment gave jax at start-up) when `path` is None — the
+    restore half of a scoped redirection (aot.build points the cache at
+    an artifact directory for the duration of the build only; leaving it
+    wired would leak every later compile of a still-serving builder into
+    the artifact, and starve whatever dir the process had wired
+    before)."""
     global _COMPILATION_CACHE_DIR
     if path is not None:
         return enable_persistent_compilation_cache(path)
     import jax
 
     _COMPILATION_CACHE_DIR = None
-    if 'jax_compilation_cache_dir' in jax.config.values:
-        jax.config.update('jax_compilation_cache_dir', None)
+    jax.config.update('jax_compilation_cache_dir',
+                      os.environ.get('JAX_COMPILATION_CACHE_DIR'))
     return None

@@ -74,9 +74,7 @@ def serving_engine(model=None, **kw):
 def _reset_persistent_cache():
     """Unwire the process-global persistent cache so later tests don't
     keep persisting executables into a vanished tmp dir."""
-    sysconfig._COMPILATION_CACHE_DIR = None
-    if 'jax_compilation_cache_dir' in jax.config.values:
-        jax.config.update('jax_compilation_cache_dir', None)
+    sysconfig.restore_persistent_compilation_cache(None)
 
 
 # ---------------------------------------------------------------------------
@@ -506,5 +504,37 @@ class TestSysconfig:
             want2 = str(tmp_path / 'cache_two')
             assert sysconfig.enable_persistent_compilation_cache(
                 want2) == os.path.abspath(want2)
+        finally:
+            _reset_persistent_cache()
+
+    @pytest.mark.parametrize('from_env', [True, False])
+    def test_default_dir_rule(self, from_env, tmp_path, monkeypatch):
+        """No explicit path: JAX_COMPILATION_CACHE_DIR when set, else
+        <checkout>/.jax_cache — and the same directory on every call
+        (a name that moves never hits)."""
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(sysconfig.__file__)))
+        if from_env:
+            want = str(tmp_path / 'placed_from_outside')
+            monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', want)
+        else:
+            want = os.path.join(checkout, '.jax_cache')
+            monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+        try:
+            first = sysconfig.enable_persistent_compilation_cache()
+            second = sysconfig.enable_persistent_compilation_cache()
+            assert first == second == want
+            assert jax.config.jax_compilation_cache_dir == want
+            assert os.path.isdir(want)
+        finally:
+            _reset_persistent_cache()
+
+    def test_env_switch_is_on_off_only(self, monkeypatch):
+        """PADDLE_TPU_PERSISTENT_CACHE no longer names a directory: a
+        value that is not boolean-ish leaves the cache off."""
+        monkeypatch.setenv('PADDLE_TPU_PERSISTENT_CACHE', '/some/dir')
+        try:
+            DecodeEngine(tiny_model(), max_new_tokens=2)
+            assert sysconfig.persistent_compilation_cache_dir() is None
         finally:
             _reset_persistent_cache()

@@ -1,0 +1,184 @@
+"""The main path's pallas kernels, compiled by the TPU's own compiler for a
+described (not attached) v5e chip, at Llama-2-7B width.
+
+Interpret mode cannot see what Mosaic refuses (an op it cannot legalize, a
+block not aligned to the tiling, too much VMEM): `quant_matmul_int4` passed
+every interpret-mode test and mosaiclint while Mosaic rejected its int8
+shifts. These compiles are the authority on "would lower on the chip";
+nothing here runs, so they say nothing about results or speed.
+
+The topology is described inside a module-scoped fixture, never at import
+or collection time: only one process may hold libtpu, and under xdist every
+worker imports this file while only one is handed its tests. Keep these
+tests in this one file for the same reason.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+HIDDEN, FFN, HEADS, HEAD_DIM, VOCAB, CTX = 4096, 11008, 32, 128, 32000, 2048
+SLOTS, PAGE = 8, 16                     # ServingEngine defaults
+
+
+@pytest.fixture(scope='module')
+def topo():
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+
+
+@pytest.fixture(scope='module')
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip_compile(one_chip, monkeypatch):
+    """compile(fn, *(shape, dtype)) -> compiled text, with the kernels on
+    their TPU branch and the persistent cache off (an executable for a
+    described device is written to it but cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    import paddle_tpu.ops.pallas as pallas_pkg
+
+    monkeypatch.setattr(pallas_pkg, 'interpret_mode', lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+
+    def compile_(fn, *specs):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in specs]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compile_
+    jax.config.update('jax_enable_compilation_cache', was)
+    compilation_cache.reset_cache()
+
+
+def _grad_of_sum(fn, argnums):
+    def fwd_bwd(*args):
+        return jax.grad(
+            lambda *a: fn(*a).astype(jnp.float32).sum(), argnums)(*args)
+
+    return fwd_bwd
+
+
+QKV = ((2, CTX, HEADS, HEAD_DIM), jnp.bfloat16)
+
+
+def test_flash_attention_fwd(chip_compile):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    text = chip_compile(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                        QKV, QKV, QKV)
+    assert 'tpu_custom_call' in text
+
+
+def test_flash_attention_fwd_bwd(chip_compile):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    text = chip_compile(
+        _grad_of_sum(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                     (0, 1, 2)), QKV, QKV, QKV)
+    assert text.count('tpu_custom_call') >= 3        # fwd, dq, dkv
+
+
+def test_rms_norm_fwd_bwd(chip_compile):
+    from paddle_tpu.ops.pallas.rms_norm import rms_norm
+
+    text = chip_compile(_grad_of_sum(rms_norm, (0, 1)),
+                        ((2 * CTX, HIDDEN), jnp.bfloat16),
+                        ((HIDDEN,), jnp.bfloat16))
+    assert 'tpu_custom_call' in text
+
+
+def test_softmax_xent_fwd_bwd(chip_compile):
+    from paddle_tpu.ops.pallas.softmax_xent import (
+        softmax_cross_entropy_with_logits)
+
+    text = chip_compile(
+        lambda lg, lb: jax.value_and_grad(
+            lambda x: softmax_cross_entropy_with_logits(x, lb).sum())(lg),
+        ((2 * CTX, VOCAB), jnp.float32), ((2 * CTX,), jnp.int32))
+    assert 'tpu_custom_call' in text
+
+
+@pytest.mark.parametrize('kv_heads', [32, 8])
+@pytest.mark.parametrize('cache_dtype', [jnp.bfloat16, jnp.int8])
+def test_decode_attention(chip_compile, cache_dtype, kv_heads):
+    from paddle_tpu.ops.pallas.decode_attention import decode_attention
+
+    q = ((SLOTS, 1, HEADS, HEAD_DIM), jnp.bfloat16)
+    kv = ((SLOTS, CTX, kv_heads, HEAD_DIM), cache_dtype)
+    vl = ((SLOTS,), jnp.int32)
+    if cache_dtype == jnp.int8:
+        sc = ((kv_heads, HEAD_DIM), jnp.float32)
+        text = chip_compile(
+            lambda q, k, v, n, ks, vs: decode_attention(
+                q, k, v, n, k_scale=ks, v_scale=vs), q, kv, kv, vl, sc, sc)
+    else:
+        text = chip_compile(decode_attention, q, kv, kv, vl)
+    assert 'tpu_custom_call' in text
+
+
+@pytest.mark.parametrize('cache_dtype', [jnp.bfloat16, jnp.int8])
+def test_paged_decode_attention(chip_compile, cache_dtype):
+    """The ServingEngine decode dispatch's kernel: full-coverage pool plus
+    the scratch page, default page size; int8 pools carry per-row scales
+    in page-shaped pools (QuantPagedKVCache)."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    maxb = CTX // PAGE
+    nb = SLOTS * maxb + 1
+    q = ((SLOTS, 1, HEADS, HEAD_DIM), jnp.bfloat16)
+    pool = ((nb, HEADS, PAGE, HEAD_DIM), cache_dtype)
+    tbl = ((SLOTS, maxb), jnp.int32)
+    lens = ((SLOTS,), jnp.int32)
+    if cache_dtype == jnp.int8:
+        sc = ((nb, HEADS, PAGE), jnp.float32)
+        text = chip_compile(
+            lambda q, k, v, t, n, ks, vs: paged_decode_attention(
+                q, k, v, t, n, k_scale=ks, v_scale=vs),
+            q, pool, pool, tbl, lens, sc, sc)
+    else:
+        text = chip_compile(paged_decode_attention, q, pool, pool, tbl, lens)
+    assert 'tpu_custom_call' in text
+
+
+def test_decode_attention_headmajor(chip_compile):
+    from paddle_tpu.ops.pallas.paged_attention import (
+        decode_attention_headmajor)
+
+    text = chip_compile(decode_attention_headmajor,
+                        ((SLOTS, 1, HEADS, HEAD_DIM), jnp.bfloat16),
+                        ((SLOTS, HEADS, CTX, HEAD_DIM), jnp.bfloat16),
+                        ((SLOTS, HEADS, CTX, HEAD_DIM), jnp.bfloat16),
+                        ((SLOTS,), jnp.int32))
+    assert 'tpu_custom_call' in text
+
+
+@pytest.mark.parametrize('rows', [SLOTS, 2048])      # decode, prefill
+@pytest.mark.parametrize('bits', [8, 4])
+def test_quant_matmul(chip_compile, bits, rows):
+    """The weight-only matmul at the 7B MLP's up projection."""
+    from paddle_tpu.ops.pallas.quant_matmul import (quant_matmul,
+                                                    quant_matmul_int4)
+
+    x = ((rows, HIDDEN), jnp.bfloat16)
+    scale = ((FFN,), jnp.float32)
+    if bits == 4:
+        text = chip_compile(quant_matmul_int4, x,
+                            ((HIDDEN // 2, FFN), jnp.int8), scale)
+    else:
+        text = chip_compile(quant_matmul, x, ((HIDDEN, FFN), jnp.int8),
+                            scale)
+    assert 'tpu_custom_call' in text

@@ -242,10 +242,13 @@ class TestPersistentCacheWiring:
     def test_sysconfig_round_trip(self, tmp_path):
         from paddle_tpu import sysconfig
 
-        d = sysconfig.enable_persistent_compilation_cache(
-            str(tmp_path / 'xla_cache'))
-        if d is None:
-            pytest.skip('this jax build has no compilation-cache config')
-        assert d == str(tmp_path / 'xla_cache')
-        assert sysconfig.persistent_compilation_cache_dir() == d
-        assert jax.config.jax_compilation_cache_dir == d
+        try:
+            d = sysconfig.enable_persistent_compilation_cache(
+                str(tmp_path / 'xla_cache'))
+            assert d == str(tmp_path / 'xla_cache')
+            assert sysconfig.persistent_compilation_cache_dir() == d
+            assert jax.config.jax_compilation_cache_dir == d
+        finally:
+            # do not leave the worker's later tests persisting into a
+            # vanished tmp dir
+            sysconfig.restore_persistent_compilation_cache(None)

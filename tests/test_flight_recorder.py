@@ -250,6 +250,25 @@ class TestCostsAnalyze:
         monkeypatch.setenv('PADDLE_TPU_PEAK_FLOPS', '2.5e12')
         assert costs.device_peak_flops() == 2.5e12
 
+    @pytest.mark.parametrize('platform,kind,want', [
+        ('tpu', 'TPU v5 lite', 197e12),
+        ('tpu', 'TPU v5p', 459e12),
+        ('cpu', 'cpu', None),
+        ('tpu', 'TPU v99', ValueError),
+    ])
+    def test_peak_flops_table(self, monkeypatch, platform, kind, want):
+        """One table keyed by device_kind; a TPU that is not in it is an
+        error, never an assumed peak."""
+        import types
+
+        monkeypatch.delenv('PADDLE_TPU_PEAK_FLOPS', raising=False)
+        dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+        if want is ValueError:
+            with pytest.raises(ValueError, match='TPU v99'):
+                costs.device_peak_flops(dev)
+        else:
+            assert costs.device_peak_flops(dev) == want
+
     def test_unified_call_sites_flops_and_op_summary(self):
         """The three duplicated cost_analysis sites now share analyze:
         utils.flops and profiler.op_summary agree on the same model."""

@@ -589,7 +589,12 @@ def _engine(model, **kw):
 
 
 class TestServingIntegration:
-    def test_default_engine_feeds_process_ring(self):
+    def test_default_engine_feeds_process_ring(self, monkeypatch):
+        # a fresh process ring whose interval cannot elapse mid-test: on
+        # the default 1 s ring a slow (loaded) host commits a window
+        # during serve() and the forced window below can come out empty
+        monkeypatch.setattr(ts, 'TIMESERIES',
+                            ts.WindowedTimeseries(interval_s=3600.0))
         model = _model()
         srv = _engine(model)
         assert srv._ts is ts.TIMESERIES and srv._watchdog is None
