@@ -239,6 +239,8 @@ _SERVING = ClassDecl(
         'last_postmortem': ephemeral('path of the last postmortem '
                                      'bundle written by THIS process'),
         '_postmortem_seq': ephemeral('postmortem filename counter'),
+        'last_deliveries': ephemeral('(rid, first_index, n) of the step '
+                                     'just ended; rewritten every step'),
         'max_queue': ephemeral('host admission knob; an operator sets '
                                'it per replica, not per snapshot'),
         'admit_watermark': ephemeral('host admission knob'),

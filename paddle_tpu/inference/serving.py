@@ -1746,6 +1746,9 @@ class ServingEngine:
                                or None)
         self._postmortem_seq = 0
         self.last_postmortem = None
+        # what the step just ended delivered: (rid, first_index, n) per
+        # request that received tokens, rewritten by every step()
+        self.last_deliveries = []
         # journal edge-trigger for pool-pressure pauses: the counter
         # ticks every paused sweep, but the rid-keyed journal event
         # fires once per STALL (a multi-hour stall must not grow the
@@ -3524,7 +3527,15 @@ class ServingEngine:
         (_serve_step; _serve_window when nothing was admitted) — and
         finally commit tokens / retire finished rows from the single
         per-window host read. Returns the requests that finished this
-        step.
+        step; `last_deliveries` holds what it delivered, one
+        `(rid, first_index, n)` per request that received tokens.
+
+        Spans (host ring and, under a jax.profiler session, the
+        profiler's trace): `serve.step` with children `serve.admit`,
+        `serve.top_up`, `serve.prefill`, `serve.stage`, `serve.dispatch`,
+        `serve.host_read` and `serve.commit`, each closed on every path
+        out; a step with nothing to run ends `serve.step` as
+        `kind='idle'`.
 
         Telemetry rides the step's EXISTING host points: lifecycle
         timestamps and the ttft/itl/queue-wait histograms are all
@@ -3534,12 +3545,13 @@ class ServingEngine:
         and gate_serve_retrace_zero both hold it to that."""
         t0 = time.perf_counter()
         _step_span = _obs_trace.span('serve.step', cat='scheduler').begin()
+        self.last_deliveries = []
         try:
             # the engine's mesh (None included) is pinned for the whole
             # iteration: any trace this step pays — first-time buckets,
             # chunk pairs — sees exactly the engine's sharding world
             with self._use_mesh():
-                finished = self._step_impl(t0)
+                finished = self._step_impl(t0, _step_span)
         except Exception as e:
             # the PR-8 worker-death path (a propagating window-dispatch
             # or top-up fault): drop the forensic bundle — metrics,
@@ -3591,11 +3603,12 @@ class ServingEngine:
         except Exception:  # noqa: BLE001 - never mask the real crash
             pass
 
-    def _step_impl(self, t0):
+    def _step_impl(self, t0, step_span):
         groups = self._admit()
         if not self.in_flight():
             self._serve_time += time.perf_counter() - t0
             self._update_gauges()   # admission may have expired/failed
+            step_span.set(kind='idle')
             return []
         # assemble this step's CHUNK group: every slot mid chunked /
         # continuation prefill advances one chunk. Completions are
@@ -3617,6 +3630,8 @@ class ServingEngine:
                                  else p + take)
         if chunk_rows:
             self._dev = None
+        top_up = _obs_trace.span('serve.top_up', cat='scheduler').begin()
+        preempted = self.preemption_count
         try:
             self._ensure_window_pages()
         except Exception:
@@ -3638,6 +3653,8 @@ class ServingEngine:
                 if self._slot_req[slot] is r:
                     self._demote(slot, r)
             raise
+        finally:
+            top_up.end(preempted=self.preemption_count - preempted)
         # the top-up above may have preempted (or failed) a
         # just-admitted request: drop it from the prefill groups (its
         # slot is parked on the scratch page; a preempted one
@@ -3683,11 +3700,29 @@ class ServingEngine:
             # decode this step, and step() must not abort
             self._serve_time += time.perf_counter() - t0
             self._update_gauges()
+            step_span.set(kind='idle')
             return []
-        dev = self._device_state()
-        budget = self._put(self._budget)        # shrinks every window
+        live = sum(r is not None and self._pfill[i] is None
+                   for i, r in enumerate(self._slot_req))
+
+        def stage():
+            # the host-to-device uploads a dispatch waits for
+            return _obs_trace.span('serve.stage', cat='scheduler')
+
+        def dispatch(bucket=0, real_lens=()):
+            # the one jitted call of the step (it returns futures), with
+            # the fill of its fused admission: zeros for a bare window
+            return _obs_trace.span(
+                'serve.dispatch', cat='scheduler', kind=kind, live=live,
+                slots=self.max_slots, **self._fill(bucket, real_lens))
+
+        with stage():
+            dev = self._device_state()
+            budget = self._put(self._budget)    # shrinks every window
         common = dict(window=W, eos_token_id=self.eos_token_id)
         spec = self.draft is not None and not chunk_rows
+        kind = ('spec' if spec else 'chunk' if chunk_rows
+                else 'step' if fused is not None else 'window')
         sample_args = (dev['temp'], dev['topk'], dev['topp'],
                        dev['seed'], dev['plen'])
         # a fault scripted at kind='window' models the whole worker
@@ -3734,6 +3769,7 @@ class ServingEngine:
                      if r is not None and self._pfill[s] is None], e)
                 self._serve_time += time.perf_counter() - t0
                 self._update_gauges()
+                step_span.set(kind='idle')
                 return []
         spec_out = None
         t_dispatch = time.perf_counter()
@@ -3743,7 +3779,8 @@ class ServingEngine:
                           for s, r in enumerate(self._slot_req)
                           if r is not None and self._pfill[s] is None)
             Sb_ctx = bucket_length(max_ctx + k + 1, self.buckets)
-            ftok_d, forced_d = self._forced_state()
+            with stage():
+                ftok_d, forced_d = self._forced_state()
             # draft catch-up first (rows whose commits bypassed the
             # draft on a chunk step): the spec window's proposals must
             # run against complete draft KV. Sb_ctx covers every
@@ -3757,16 +3794,18 @@ class ServingEngine:
                 Sb, group = fused
                 for _s, r in group:
                     r.mark('prefill_dispatch', bucket=Sb, fused=True)
-                ids, real_len, btabs, slots = self._prefill_args(Sb,
-                                                                 group)
+                with stage():
+                    ids, real_len, btabs, slots = self._prefill_args(
+                        Sb, group)
                 hit = self._note('serve_spec_step', k, Sb, Sb_ctx)
                 dispatch_key = ('serve_spec_step', k, Sb, Sb_ctx)
-                (cand, nc, nxt, self._last_logits, self._pages,
-                 self._dpages, ctx_out) = _serve_spec_step(
-                    self.model, self.draft, self._pages, self._dpages,
-                    self._last_logits, ids, real_len, btabs, slots,
-                    ftok_d, forced_d, dev['btab'], dev['ctx'],
-                    dev['live'], budget, *sample_args, **scommon)
+                with dispatch(Sb, [r.context_len for _s, r in group]):
+                    (cand, nc, nxt, self._last_logits, self._pages,
+                     self._dpages, ctx_out) = _serve_spec_step(
+                        self.model, self.draft, self._pages, self._dpages,
+                        self._last_logits, ids, real_len, btabs, slots,
+                        ftok_d, forced_d, dev['btab'], dev['ctx'],
+                        dev['live'], budget, *sample_args, **scommon)
                 if self.prefix_cache:
                     for slot, r in group:
                         self._register_prefix_pages(slot, r, 0,
@@ -3774,12 +3813,13 @@ class ServingEngine:
             else:
                 hit = self._note('serve_spec_window', k, Sb_ctx)
                 dispatch_key = ('serve_spec_window', k, Sb_ctx)
-                (cand, nc, nxt, self._last_logits, self._pages,
-                 self._dpages, ctx_out) = _serve_spec_window(
-                    self.model, self.draft, self._pages, self._dpages,
-                    self._last_logits, ftok_d, forced_d, dev['btab'],
-                    dev['ctx'], dev['live'], budget, *sample_args,
-                    **scommon)
+                with dispatch():
+                    (cand, nc, nxt, self._last_logits, self._pages,
+                     self._dpages, ctx_out) = _serve_spec_window(
+                        self.model, self.draft, self._pages, self._dpages,
+                        self._last_logits, ftok_d, forced_d, dev['btab'],
+                        dev['ctx'], dev['live'], budget, *sample_args,
+                        **scommon)
             spec_out = (cand, nc, nxt)
             # a fresh draft catch-up shape paid its compile inside
             # this step's wall: count the window as a MISS so the
@@ -3787,8 +3827,9 @@ class ServingEngine:
             hit = hit and not fresh_draft
             self.spec_counts['windows'] += 1
         elif chunk_rows:
-            (ids, clen, cst, btabs, slots, cow_src, cow_dst, Cb,
-             Sb) = self._chunk_args(chunk_rows)
+            with stage():
+                (ids, clen, cst, btabs, slots, cow_src, cow_dst, Cb,
+                 Sb) = self._chunk_args(chunk_rows)
             for _s, r, _p, _t in chunk_rows:
                 r.mark('prefill_dispatch', chunk=True, start=_p, take=_t)
             hit = self._note('serve_chunk_step', W, Cb, Sb)
@@ -3824,13 +3865,14 @@ class ServingEngine:
                 # non-speculative engines can never have forced rows —
                 # the constant zero uploads skip the per-step scan
                 ftok_d, forced_d = self._zero_ftok, self._zero_forced
-            toks, self._last_logits, self._pages, ctx_out = \
-                _serve_chunk_step(
-                    self.model, self._pages, self._last_logits, ids,
-                    clen, cst, btabs, slots, cow_src, cow_dst,
-                    dev['btab'], dev['ctx'], dev['live'], budget,
-                    *sample_args, ftok_d, forced_d, ctx_bucket=Sb,
-                    **common)
+            with dispatch(Cb, [t for _s, _r, _p, t in chunk_rows]):
+                toks, self._last_logits, self._pages, ctx_out = \
+                    _serve_chunk_step(
+                        self.model, self._pages, self._last_logits, ids,
+                        clen, cst, btabs, slots, cow_src, cow_dst,
+                        dev['btab'], dev['ctx'], dev['live'], budget,
+                        *sample_args, ftok_d, forced_d, ctx_bucket=Sb,
+                        **common)
             self.prefix_counts['chunk_steps'] += 1
             self._inc('serve.chunk_steps')
             if self._cow_release:
@@ -3847,23 +3889,27 @@ class ServingEngine:
             Sb, group = fused
             for _s, r in group:
                 r.mark('prefill_dispatch', bucket=Sb, fused=True)
-            ids, real_len, btabs, slots = self._prefill_args(Sb, group)
+            with stage():
+                ids, real_len, btabs, slots = self._prefill_args(Sb, group)
             hit = self._note('serve_step', W, Sb)
             dispatch_key = ('serve_step', W, Sb)
-            toks, self._last_logits, self._pages, ctx_out = _serve_step(
-                self.model, self._pages, self._last_logits, ids, real_len,
-                btabs, slots, dev['btab'], dev['ctx'], dev['live'],
-                budget, *sample_args, **common)
+            with dispatch(Sb, [r.context_len for _s, r in group]):
+                toks, self._last_logits, self._pages, ctx_out = _serve_step(
+                    self.model, self._pages, self._last_logits, ids,
+                    real_len, btabs, slots, dev['btab'], dev['ctx'],
+                    dev['live'], budget, *sample_args, **common)
             if self.prefix_cache:
                 for slot, r in group:
                     self._register_prefix_pages(slot, r, 0, r.context_len)
         else:
             hit = self._note('serve_window', W)
             dispatch_key = ('serve_window', W)
-            toks, self._last_logits, self._pages, ctx_out = _serve_window(
-                self.model, self._pages, self._last_logits,
-                dev['btab'], dev['ctx'], dev['live'], budget,
-                *sample_args, **common)
+            with dispatch():
+                toks, self._last_logits, self._pages, ctx_out = \
+                    _serve_window(
+                        self.model, self._pages, self._last_logits,
+                        dev['btab'], dev['ctx'], dev['live'], budget,
+                        *sample_args, **common)
         # the returned ctx equals the host's post-commit view whenever
         # no slot is retired below (retiring invalidates the mirror)
         dev['ctx'] = ctx_out
@@ -3872,155 +3918,164 @@ class ServingEngine:
         # counts + carried next-token) to detect eos/budget and refill
         # the batch; all other state is host-authoritative.
         # tracelint: disable=TL002 - single sync per window by design
-        if spec_out is not None:
-            cand_h, nc_h, nxt_h = jax.device_get(spec_out)
-            cand_h, nc_h, nxt_h = (np.asarray(cand_h), np.asarray(nc_h),
-                                   np.asarray(nxt_h))
-            tokens = None
-        else:
-            tokens = np.asarray(jax.device_get(toks))
+        with _obs_trace.span('serve.host_read', cat='scheduler'):
+            if spec_out is not None:
+                cand_h, nc_h, nxt_h = jax.device_get(spec_out)
+                cand_h, nc_h, nxt_h = (np.asarray(cand_h),
+                                       np.asarray(nc_h), np.asarray(nxt_h))
+                tokens = None
+            else:
+                tokens = np.asarray(jax.device_get(toks))
         t_commit = time.perf_counter()
-        if not hit:
-            # a NEW registry key means this dispatch paid trace +
-            # compile: surface it as a compile span whose wall duration
-            # is dispatch-to-commit (trace + compile + first window)
-            _obs_trace.compile_event(
-                f'compile:{dispatch_key[0]}', key=dispatch_key,
-                dur_s=t_commit - t_dispatch,
-                geometry=str(self._geometry()))
-            self._record(
-                'compile', dispatch=dispatch_key[0],
-                key=str(dispatch_key),
-                dur_ms=round((t_commit - t_dispatch) * 1e3, 3))
-        # steady-state per-token latency: the window advances every live
-        # slot one token per scan step, so each committed token costs
-        # window_wall / W — recorded once per token at this commit point
-        # (window granularity, no per-token host syncs). A cache-MISS
-        # window's wall is trace+compile, not decoding: its tokens are
-        # excluded from the ITL histogram (they'd report compile time as
-        # inter-token latency) and counted aside; TTFT keeps including
-        # it — a request that waited on a compile really waited.
-        per_tok_ms = ((t_commit - t_dispatch) * 1e3 / W) if hit else None
-        telemetry = _obs.enabled()
-        mx = self._metrics() if telemetry else None
+        commit = _obs_trace.span('serve.commit', cat='scheduler').begin()
         step_tokens = 0
         finished = []
-        for slot, req in enumerate(self._slot_req):
-            if req is None or self._pfill[slot] is not None:
-                # mid-prefill slots rode the window frozen: they
-                # emitted pad tokens and commit nothing until their
-                # last chunk lands
-                continue
-            if spec_out is not None:
-                # ragged speculative commit: the device already
-                # clamped the accept count by budget and truncated at
-                # eos (ncommit); the carried next-token persists on
-                # the request so preemption/restore resumes bit-equal
-                take = int(nc_h[slot])
-                committed = [int(t) for t in cand_h[slot, :take]]
-                req.spec_next = int(nxt_h[slot])
-                # the draft scan wrote every committed position's KV
-                self._dctx[slot] += take
-                self.spec_counts['proposed'] += self.spec_window
-                self.spec_counts['accepted'] += max(0, take - 1)
-                if telemetry:
-                    self._inc('serve.spec_proposed', self.spec_window)
-                    self._inc('serve.spec_accepted', max(0, take - 1))
-            else:
-                take = min(W, req.remaining)
-                committed = []
-                for t in range(take):
-                    tok = int(tokens[slot, t])
-                    committed.append(tok)
-                    if (self.eos_token_id is not None
-                            and tok == self.eos_token_id):
-                        break
-                if committed:
-                    # the window consumed any pending speculative
-                    # carried token as its first commit (the forced
-                    # path) — a stale spec_next must not force a later
-                    # spec window at the wrong position
-                    req.spec_next = None
-            req.generated.extend(committed)
-            self._ctx[slot] += len(committed)
-            # keep the device-side freeze live: next window's budget is
-            # the CURRENT remaining, so a continuing row can never
-            # commit past its max_new on device and ctx_out stays equal
-            # to the host view
-            self._budget[slot] = req.remaining
-            self._tokens_out += len(committed)
-            step_tokens += len(committed)
-            if telemetry and committed:
-                itl_n = len(committed)
-                if req.when('first_token') is None:
-                    req.mark('first_token', t_commit)
-                    arrived = req.when('arrival')
-                    if arrived is not None:
-                        mx['ttft'].observe((t_commit - arrived) * 1e3)
-                    itl_n -= 1        # the first-ever token is TTFT
-                row_ms = per_tok_ms
-                if spec_out is not None and hit:
-                    # ragged window: this row's per-token latency is
-                    # the window wall over ITS committed count
-                    row_ms = ((t_commit - t_dispatch) * 1e3
-                              / max(len(committed), 1))
-                if row_ms is not None:
-                    mx['itl'].observe(row_ms, n=itl_n)
+        try:
+            if not hit:
+                # a NEW registry key means this dispatch paid trace +
+                # compile: surface it as a compile span whose wall duration
+                # is dispatch-to-commit (trace + compile + first window)
+                _obs_trace.compile_event(
+                    f'compile:{dispatch_key[0]}', key=dispatch_key,
+                    dur_s=t_commit - t_dispatch,
+                    geometry=str(self._geometry()))
+                self._record(
+                    'compile', dispatch=dispatch_key[0],
+                    key=str(dispatch_key),
+                    dur_ms=round((t_commit - t_dispatch) * 1e3, 3))
+            # steady-state per-token latency: the window advances every live
+            # slot one token per scan step, so each committed token costs
+            # window_wall / W — recorded once per token at this commit point
+            # (window granularity, no per-token host syncs). A cache-MISS
+            # window's wall is trace+compile, not decoding: its tokens are
+            # excluded from the ITL histogram (they'd report compile time as
+            # inter-token latency) and counted aside; TTFT keeps including
+            # it — a request that waited on a compile really waited.
+            per_tok_ms = ((t_commit - t_dispatch) * 1e3 / W) if hit else None
+            telemetry = _obs.enabled()
+            mx = self._metrics() if telemetry else None
+            for slot, req in enumerate(self._slot_req):
+                if req is None or self._pfill[slot] is not None:
+                    # mid-prefill slots rode the window frozen: they
+                    # emitted pad tokens and commit nothing until their
+                    # last chunk lands
+                    continue
+                if spec_out is not None:
+                    # ragged speculative commit: the device already
+                    # clamped the accept count by budget and truncated at
+                    # eos (ncommit); the carried next-token persists on
+                    # the request so preemption/restore resumes bit-equal
+                    take = int(nc_h[slot])
+                    committed = [int(t) for t in cand_h[slot, :take]]
+                    req.spec_next = int(nxt_h[slot])
+                    # the draft scan wrote every committed position's KV
+                    self._dctx[slot] += take
+                    self.spec_counts['proposed'] += self.spec_window
+                    self.spec_counts['accepted'] += max(0, take - 1)
+                    if telemetry:
+                        self._inc('serve.spec_proposed', self.spec_window)
+                        self._inc('serve.spec_accepted', max(0, take - 1))
                 else:
-                    self._inc('serve.itl_skipped_compile', itl_n)
-                req.mark('window', t_commit, n=len(committed),
-                         total=len(req.generated))
-            done = (req.remaining == 0
-                    or (self.eos_token_id is not None and committed
-                        and committed[-1] == self.eos_token_id))
-            if done:
-                self._finish(slot, req)
-                finished.append(req)
-            elif req.deadline is not None and t_commit >= req.deadline:
-                # deadline check rides the existing per-window commit
-                # sync (t_commit is already in hand — no extra clock
-                # read, no device sync): an unfinished request past its
-                # deadline expires HERE, pages freed, slot recycled
-                self._clear_slot(slot)
-                self._retire(
-                    req, 'expired',
-                    reason=f'deadline exceeded after '
-                           f'{len(req.generated)} committed token(s)')
-        self._serve_time += time.perf_counter() - t0
-        if telemetry:
-            mx['steps'].inc()
-            mx['tokens'].inc(step_tokens)
-            mx['step_ms'].observe((time.perf_counter() - t0) * 1e3)
-            # live MFU / roofline: static flops of THIS dispatch's
-            # geometry (the AOT manifest's cost stamp) over the
-            # host-measured dispatch-to-commit wall — pure host
-            # arithmetic on numbers already in hand (zero new syncs,
-            # zero retraces). Cache-MISS windows are excluded like ITL:
-            # their wall is trace+compile, not model execution.
-            cost = (self._dispatch_costs.get(dispatch_key)
-                    if self._dispatch_costs and hit else None)
-            if cost is not None:
-                wall = t_commit - t_dispatch
-                fl = cost.get('flops')
-                if fl and wall > 0:
-                    fps = fl / wall
-                    self._set_gauge('serve.model_flops_per_s', fps)
-                    mfu = (fps / self._peak_flops
-                           if self._peak_flops else None)
-                    if mfu is not None:
-                        self._set_gauge('serve.mfu_est', mfu)
-                    ba = cost.get('bytes_accessed')
-                    if ba:
-                        self._set_gauge('serve.roofline_intensity',
-                                        fl / ba)
-                    self._last_mfu = {
-                        'tag': dispatch_key, 'flops': fl,
-                        'bytes_accessed': ba,
-                        'window_wall_ms': wall * 1e3,
-                        'flops_per_s': fps, 'mfu_est': mfu,
-                        'peak_flops': self._peak_flops,
-                    }
-            self._update_gauges()
+                    take = min(W, req.remaining)
+                    committed = []
+                    for t in range(take):
+                        tok = int(tokens[slot, t])
+                        committed.append(tok)
+                        if (self.eos_token_id is not None
+                                and tok == self.eos_token_id):
+                            break
+                    if committed:
+                        # the window consumed any pending speculative
+                        # carried token as its first commit (the forced
+                        # path) — a stale spec_next must not force a later
+                        # spec window at the wrong position
+                        req.spec_next = None
+                if committed:
+                    self.last_deliveries.append(
+                        (req.rid, len(req.generated), len(committed)))
+                req.generated.extend(committed)
+                self._ctx[slot] += len(committed)
+                # keep the device-side freeze live: next window's budget is
+                # the CURRENT remaining, so a continuing row can never
+                # commit past its max_new on device and ctx_out stays equal
+                # to the host view
+                self._budget[slot] = req.remaining
+                self._tokens_out += len(committed)
+                step_tokens += len(committed)
+                if telemetry and committed:
+                    itl_n = len(committed)
+                    if req.when('first_token') is None:
+                        req.mark('first_token', t_commit)
+                        arrived = req.when('arrival')
+                        if arrived is not None:
+                            mx['ttft'].observe((t_commit - arrived) * 1e3)
+                        itl_n -= 1        # the first-ever token is TTFT
+                    row_ms = per_tok_ms
+                    if spec_out is not None and hit:
+                        # ragged window: this row's per-token latency is
+                        # the window wall over ITS committed count
+                        row_ms = ((t_commit - t_dispatch) * 1e3
+                                  / max(len(committed), 1))
+                    if row_ms is not None:
+                        mx['itl'].observe(row_ms, n=itl_n)
+                    else:
+                        self._inc('serve.itl_skipped_compile', itl_n)
+                    req.mark('window', t_commit, n=len(committed),
+                             total=len(req.generated))
+                done = (req.remaining == 0
+                        or (self.eos_token_id is not None and committed
+                            and committed[-1] == self.eos_token_id))
+                if done:
+                    self._finish(slot, req)
+                    finished.append(req)
+                elif req.deadline is not None and t_commit >= req.deadline:
+                    # deadline check rides the existing per-window commit
+                    # sync (t_commit is already in hand — no extra clock
+                    # read, no device sync): an unfinished request past its
+                    # deadline expires HERE, pages freed, slot recycled
+                    self._clear_slot(slot)
+                    self._retire(
+                        req, 'expired',
+                        reason=f'deadline exceeded after '
+                               f'{len(req.generated)} committed token(s)')
+            self._serve_time += time.perf_counter() - t0
+            if telemetry:
+                mx['steps'].inc()
+                mx['tokens'].inc(step_tokens)
+                mx['step_ms'].observe((time.perf_counter() - t0) * 1e3)
+                # live MFU / roofline: static flops of THIS dispatch's
+                # geometry (the AOT manifest's cost stamp) over the
+                # host-measured dispatch-to-commit wall — pure host
+                # arithmetic on numbers already in hand (zero new syncs,
+                # zero retraces). Cache-MISS windows are excluded like ITL:
+                # their wall is trace+compile, not model execution.
+                cost = (self._dispatch_costs.get(dispatch_key)
+                        if self._dispatch_costs and hit else None)
+                if cost is not None:
+                    wall = t_commit - t_dispatch
+                    fl = cost.get('flops')
+                    if fl and wall > 0:
+                        fps = fl / wall
+                        self._set_gauge('serve.model_flops_per_s', fps)
+                        mfu = (fps / self._peak_flops
+                               if self._peak_flops else None)
+                        if mfu is not None:
+                            self._set_gauge('serve.mfu_est', mfu)
+                        ba = cost.get('bytes_accessed')
+                        if ba:
+                            self._set_gauge('serve.roofline_intensity',
+                                            fl / ba)
+                        self._last_mfu = {
+                            'tag': dispatch_key, 'flops': fl,
+                            'bytes_accessed': ba,
+                            'window_wall_ms': wall * 1e3,
+                            'flops_per_s': fps, 'mfu_est': mfu,
+                            'peak_flops': self._peak_flops,
+                        }
+                self._update_gauges()
+        finally:
+            commit.end(committed=step_tokens, finished=len(finished))
+        step_span.set(kind=kind, live=live, committed=step_tokens)
         return finished
 
     # -- internals ---------------------------------------------------------
@@ -4304,7 +4359,7 @@ class ServingEngine:
                         self._inc('serve.chunked_admissions')
                 else:
                     placed.append((slot, req))
-            _sp.args['admitted'] = admitted
+            _sp.set(admitted=admitted, queue_depth=len(self.queue))
         by_bucket: dict = {}
         for slot, req in placed:
             Sb = bucket_length(req.context_len, self.buckets)
@@ -4335,13 +4390,25 @@ class ServingEngine:
         self._paused_head = None     # admission resumed: re-arm the
                                      # admission_paused edge trigger
         req.mark('admitted', slot=slot, pages=len(pages))
+        wait_ms = None
+        if req.enqueued_at is not None:
+            wait_ms = (time.perf_counter() - req.enqueued_at) * 1e3
         if _obs.enabled():
             self._inc('serve.admissions')
-            if req.enqueued_at is not None:
-                self._metrics()['qwait'].observe(
-                    (time.perf_counter() - req.enqueued_at) * 1e3)
-            _obs_trace.instant('serve.admission', cat='scheduler',
-                               rid=req.rid, slot=slot, pages=len(pages))
+            if wait_ms is not None:
+                self._metrics()['qwait'].observe(wait_ms)
+        _obs_trace.instant(
+            'serve.admission', cat='scheduler', rid=req.rid, slot=slot,
+            pages=len(pages), wait_ms=wait_ms, prompt_len=len(req.prompt),
+            bucket=bucket_length(req.context_len, self.buckets))
+
+    def _fill(self, bucket, real_lens):
+        """How full one fixed-width prefill batch is, as `serve.dispatch`
+        and `serve.prefill` report it: its rows' real tokens beside the
+        `max_slots * bucket` positions the batch is padded to."""
+        return dict(bucket=bucket, rows=len(real_lens),
+                    real_tokens=sum(real_lens),
+                    padded_tokens=self.max_slots * bucket)
 
     def _prefill_args(self, Sb, group):
         """Device args for one fixed-width admission-prefill batch
@@ -4372,15 +4439,18 @@ class ServingEngine:
         pages too — the draft must hold every admitted row's prompt KV
         or its proposals would be conditioned on zeros and the accept
         rate would silently collapse."""
-        ids, real_len, btabs, slots = self._prefill_args(Sb, group)
-        self._note('serve_prefill', Sb)
-        self._last_logits, self._pages = _paged_prefill(
-            self.model, self._pages, self._last_logits, ids, real_len,
-            btabs, slots)
-        if self.draft is not None:
-            self._dlogits, self._dpages = _paged_prefill(
-                self.draft, self._dpages, self._dlogits, ids, real_len,
-                btabs, self._dummy_slots)
+        with _obs_trace.span(
+                'serve.prefill', cat='scheduler',
+                **self._fill(Sb, [r.context_len for _s, r in group])):
+            ids, real_len, btabs, slots = self._prefill_args(Sb, group)
+            self._note('serve_prefill', Sb)
+            self._last_logits, self._pages = _paged_prefill(
+                self.model, self._pages, self._last_logits, ids, real_len,
+                btabs, slots)
+            if self.draft is not None:
+                self._dlogits, self._dpages = _paged_prefill(
+                    self.draft, self._dpages, self._dlogits, ids, real_len,
+                    btabs, self._dummy_slots)
 
     def _chunk_args(self, rows):
         """Device args for one fixed-width chunk-continuation batch

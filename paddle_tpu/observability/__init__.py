@@ -6,8 +6,8 @@ process-global metrics registry + one host-span tracer, threaded
 through the serving engine, the train engine, the dataloader, and the
 compile caches. Rebuilds the reference's Profiler/event-collation
 subsystem jax-natively: `jax.profiler` keeps the device timeline, this
-package owns the host one, and `tracing.annotate` /
-`profiler.RecordEvent` bridge the two.
+package owns the host one, and `tracing.span` (which
+`profiler.RecordEvent` routes through) writes each host span into both.
 
 Contracts (tested in tests/test_observability.py, gated in bench.py):
   - zero device syncs: every record happens at an EXISTING host point

@@ -20,15 +20,18 @@ Design constraints, same discipline as the metrics registry:
   - switchable: every record checks `metrics.enabled()`, so the bench
     overhead gate's telemetry-off run skips this too.
 
-`annotate(name)` is the dual-timeline bridge: one context manager that
-opens a host span here AND a `jax.profiler.TraceAnnotation` on the XLA
-timeline (profiler.RecordEvent routes through it), so a single API call
-marks both traces with the same name.
+One primitive, two sinks: `span(name)` opens a
+`jax.profiler.TraceAnnotation` under the same name (a no-op while no
+profiler session runs; the session is its switch) AND, with telemetry
+on, records the ring event here. So the engines' spans are in the
+profiler's trace, on the profiler's clock, beside the device's
+operations, and the ring holds their `args`. A ring event recorded while
+a session was on carries `traced: true`; `HostTracer.traced()` cuts the
+ring to the newest session without a clock match.
 """
 from __future__ import annotations
 
 import collections
-import contextlib
 import json
 import os
 import threading
@@ -48,29 +51,73 @@ def _now_us():
     return (time.perf_counter() - _EPOCH) * 1e6
 
 
+# jax.profiler.TraceAnnotation, looked up at the first span: None = not
+# looked up yet, False = this installation has none (ring only)
+_ANNOTATION = None
+
+
+def _open_annotation(name, args):
+    """(entered TraceAnnotation or None, whether a profiler session is
+    on). Never raises: annotation must not be able to break the
+    annotated code."""
+    global _ANNOTATION
+    try:
+        if _ANNOTATION is None:
+            try:
+                import jax
+
+                _ANNOTATION = jax.profiler.TraceAnnotation
+            except Exception:  # noqa: BLE001 - degrade to ring-only
+                _ANNOTATION = False
+        if not _ANNOTATION:
+            return None, False
+        ann = _ANNOTATION(name, **args)
+        ann.__enter__()
+        return ann, _ANNOTATION.is_enabled()
+    except Exception:  # noqa: BLE001 - annotation is best-effort
+        return None, False
+
+
 class _Span:
     """Open span handle: context manager OR explicit begin()/end()
-    (profiler.RecordEvent needs the latter). A span created while
-    telemetry is disabled is inert."""
+    (profiler.RecordEvent needs the latter). The telemetry switch gates
+    the ring event only; the annotation follows the profiler session."""
 
-    __slots__ = ('_tracer', 'name', 'cat', 'args', '_t0')
+    __slots__ = ('_tracer', 'name', 'cat', 'args', '_t0', '_ann',
+                 '_traced')
 
     def __init__(self, tracer, name, cat, args):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
-        self._t0 = None
+        self._t0 = self._ann = None
+        self._traced = False
 
     def begin(self):
+        self._ann, self._traced = _open_annotation(self.name, self.args)
         if _metrics.enabled():
-            self._t0 = _now_us()
+            self._t0 = self._tracer._observe(self._traced)
         return self
 
-    def end(self):
+    def set(self, **args):
+        """Args known only once the work is done (`committed`,
+        `admitted`): onto the ring event and the annotation's
+        metadata."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def end(self, **args):
+        if args:
+            self.set(**args)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if self._t0 is not None:
             self._tracer._emit(self.name, self.cat, self._t0,
-                               _now_us() - self._t0, self.args)
+                               _now_us() - self._t0, self.args,
+                               traced=self._traced)
             self._t0 = None
 
     def __enter__(self):
@@ -90,10 +137,22 @@ class HostTracer:
             maxlen=self.max_events)
         self.dropped = 0
         self._pid = os.getpid()
+        self._session_on = False
+        self._session_ts = 0.0
 
     # -- recording ---------------------------------------------------------
 
-    def _emit(self, name, cat, ts, dur, args, ph='X'):
+    def _observe(self, session_on):
+        """Now, in us; called as each span or instant begins with whether
+        a profiler session is on, so the newest session's first instant
+        is known to `traced()`."""
+        ts = _now_us()
+        if session_on and not self._session_on:
+            self._session_ts = ts
+        self._session_on = session_on
+        return ts
+
+    def _emit(self, name, cat, ts, dur, args, ph='X', traced=False):
         ev = {'name': name, 'cat': cat, 'ph': ph, 'ts': ts,
               'pid': self._pid, 'tid': threading.get_ident() % 2**31}
         if ph == 'X':
@@ -102,6 +161,8 @@ class HostTracer:
             ev['s'] = 'p'
         if args:
             ev['args'] = args
+        if traced:
+            ev['traced'] = True
         if len(self._events) == self.max_events:
             # silent event loss is itself an observability bug: surface
             # ring overflow as a registry counter so dashboards see a
@@ -111,14 +172,19 @@ class HostTracer:
         self._events.append(ev)
 
     def span(self, name, cat='host', **args):
-        """Context manager (or begin()/end() handle) recording one
-        complete span on exit."""
+        """Context manager (or begin()/end() handle): a TraceAnnotation
+        in the profiler's trace and one complete ring event on exit."""
         return _Span(self, name, cat, args)
 
     def instant(self, name, cat='host', **args):
-        if not _metrics.enabled():
-            return
-        self._emit(name, cat, _now_us(), 0.0, args, ph='i')
+        """A point in time: an annotation opened and closed at once, and
+        a `ph: "i"` ring event."""
+        ann, traced = _open_annotation(name, args)
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if _metrics.enabled():
+            self._emit(name, cat, self._observe(traced), 0.0, args, ph='i',
+                       traced=traced)
 
     def compile_event(self, name, key=None, dur_s=None, **args):
         """One compile/retrace event on the `compile` track. With a
@@ -140,12 +206,22 @@ class HostTracer:
     def events(self):
         return list(self._events)
 
+    def traced(self):
+        """The events of the newest profiler session: the ring cut to
+        exactly the traced window. A session is known by a span or
+        instant that began while it was on, so two sessions with nothing
+        recorded between them read as one."""
+        return [e for e in self.events()
+                if e.get('traced') and e['ts'] >= self._session_ts]
+
     def __len__(self):
         return len(self._events)
 
     def clear(self):
         self._events.clear()
         self.dropped = 0
+        self._session_on = False
+        self._session_ts = 0.0
 
     def to_chrome_trace(self):
         """The `trace_event` ARRAY form (what Perfetto and
@@ -201,29 +277,6 @@ def to_chrome_trace():
     return TRACER.to_chrome_trace()
 
 
-@contextlib.contextmanager
-def annotate(name, cat='host', **args):
-    """The dual-timeline bridge: one `with annotate('train_step'):`
-    records a host span here AND a jax.profiler.TraceAnnotation on the
-    device timeline, so the two traces share a name to line up on.
-
-    The telemetry kill switch gates only the HOST span (the recording
-    this package added); the device-timeline annotation is jax's
-    long-standing behavior and fires regardless, keeping every
-    RecordEvent form consistent with its pre-observability semantics.
-    Degrades to host-only when jax (or its profiler) is unavailable —
-    annotation must never be able to break the annotated code."""
-    ctx = None
-    try:
-        import jax
-
-        ctx = jax.profiler.TraceAnnotation(name)
-        ctx.__enter__()
-    except Exception:  # noqa: BLE001 - annotation is best-effort
-        ctx = None
-    with TRACER.span(name, cat, **args):
-        try:
-            yield
-        finally:
-            if ctx is not None:
-                ctx.__exit__(None, None, None)
+# the name profiler.RecordEvent's decorator form and older callers use
+# for the same primitive
+annotate = span

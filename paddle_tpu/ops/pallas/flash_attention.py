@@ -166,7 +166,7 @@ def _fwd(q, k, v, scale, causal, bq, bk, qseg=None, kseg=None,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_interpret(), name='flash_attention_fwd',
     )(*operands)
     return out, lse
 
@@ -360,7 +360,7 @@ def _bwd(scale, causal, bq, bk, res, g, qseg=None, kseg=None,
         out_specs=pl.BlockSpec((1, 1, bq_, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq_, D), jnp.float32)],
-        interpret=_interpret(),
+        interpret=_interpret(), name='flash_attention_dq',
     )(q, k, v, do, lse, delta, *seg_ops)
 
     # per-q-head dk/dv, then reduce GQA groups
@@ -395,7 +395,7 @@ def _bwd(scale, causal, bq, bk, res, g, qseg=None, kseg=None,
             pltpu.VMEM((bk_, D), jnp.float32),
             pltpu.VMEM((bk_, D), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_interpret(), name='flash_attention_dkv',
     )(q, k, v, do, lse, delta, *seg_ops)
 
     if group > 1:

@@ -133,7 +133,7 @@ def _run(kernel, grid, in_specs, out_spec, args, out_sd, interp):
             ],
         ),
         out_shape=out_sd,
-        interpret=interp,
+        interpret=interp, name='paged_attention',
     )(*args)
 
 

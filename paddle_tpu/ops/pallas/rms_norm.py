@@ -68,7 +68,7 @@ def _run_fwd(x2, w, epsilon, rows_blk):
             jax.ShapeDtypeStruct((R, N), x2.dtype),
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_interpret(), name='rms_norm_fwd',
     )(x2, w)
 
 
@@ -98,7 +98,7 @@ def _rms_bwd(epsilon, res, g):
         ],
         out_specs=pl.BlockSpec((rows_blk, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, N), x2.dtype),
-        interpret=_interpret(),
+        interpret=_interpret(), name='rms_norm_bwd',
     )(x2, w, r, g)
     # dw: cross-row reduction — XLA fuses this fine
     xf = x2.astype(jnp.float32)

@@ -112,7 +112,7 @@ def _run_fwd(x2, labels):
             pltpu.VMEM((br, 128), jnp.float32),
             pltpu.VMEM((br, 128), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_interpret(), name='softmax_xent_fwd',
     )(x2, labels[:, None])
     return loss[:, 0], lse[:, 0]
 
@@ -143,7 +143,7 @@ def _xent_bwd(res, g):
         ],
         out_specs=pl.BlockSpec((br, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, V), x2.dtype),
-        interpret=_interpret(),
+        interpret=_interpret(), name='softmax_xent_bwd',
     )(x2, labels[:, None], lse[:, None], g[:, None].astype(jnp.float32))
     return dx, None
 

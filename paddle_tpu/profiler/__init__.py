@@ -26,30 +26,24 @@ class ProfilerTarget:
 class RecordEvent:
     """ref: paddle.profiler.RecordEvent — named trace annotation.
 
-    Also usable as a decorator. ONE API, BOTH timelines: lowers to
-    jax.profiler.TraceAnnotation (the XLA/TensorBoard device timeline)
-    AND records a host span in observability's tracer (the Perfetto
-    host_trace.json), so the same name lines the two traces up — the
-    reference's host/device event collation, rebuilt on the two
-    recorders this stack actually has.
+    Also usable as a decorator. ONE API, BOTH timelines:
+    `observability.tracing.span` opens the jax.profiler annotation (the
+    XLA/TensorBoard timeline) AND records the host span (the Perfetto
+    host_trace.json) under the same name — the reference's host/device
+    event collation, rebuilt on the two recorders this stack actually
+    has.
     """
 
     def __init__(self, name, event_type=None):
         self.name = name
-        self._ctx = None
         self._span = None
 
     def begin(self):
         from ..observability import tracing as _tracing
 
         self._span = _tracing.span(self.name, cat='record_event').begin()
-        self._ctx = jax.profiler.TraceAnnotation(self.name)
-        self._ctx.__enter__()
 
     def end(self):
-        if self._ctx is not None:
-            self._ctx.__exit__(None, None, None)
-            self._ctx = None
         if self._span is not None:
             self._span.end()
             self._span = None
@@ -69,8 +63,6 @@ class RecordEvent:
 
         @functools.wraps(fn)
         def wrapped(*a, **kw):
-            # annotate() is the same dual-timeline bridge in context-
-            # manager form (TraceAnnotation + host span)
             with _tracing.annotate(self.name, cat='record_event'):
                 return fn(*a, **kw)
 

@@ -628,6 +628,10 @@ class TrainEngine:
                 'TrainEngine has no loss to train on: pass loss_fn '
                 '(hapi prepare(optimizer, loss=...)) or use '
                 'loss_fn=None with a model that defines .loss()')
+        with _obs_trace.span('train.step', cat='train') as sp:
+            return self._step(sp, inputs, labels)
+
+    def _step(self, span, inputs, labels):
         inputs = tuple(jnp.asarray(x) for x in _to_tuple(inputs))
         labels = tuple(jnp.asarray(x) for x in _to_tuple(labels))
         if self.accum_steps > 1:
@@ -640,6 +644,7 @@ class TrainEngine:
             self._window_t0 = time.perf_counter()
         if inputs and hasattr(inputs[0], 'size'):
             self._window_tokens += int(inputs[0].size)
+            span.set(tokens=int(inputs[0].size))
         lr_mode = self._lr_mode()
         if inputs:
             if not TRAIN_COMPILE_CACHE.note(self.registry_key(
@@ -831,7 +836,16 @@ class TrainEngine:
             from ..distributed.sharding import data_sharding
 
             sharding = data_sharding(self.mesh)
-        return prefetch_to_device(iterator, size=size, sharding=sharding)
+        feed = prefetch_to_device(iterator, size=size, sharding=sharding)
+        while True:
+            # one `next()` of the feed, on whichever thread asks: the
+            # upstream iterator's batch and its host-to-device put
+            with _obs_trace.span('train.feed', cat='train'):
+                try:
+                    batch = next(feed)
+                except StopIteration:
+                    return
+            yield batch
 
     # -- bookkeeping -------------------------------------------------------
 

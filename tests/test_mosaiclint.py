@@ -363,8 +363,9 @@ class TestExtraction:
                      if e.name == 'flash_attention/causal_fwd_bwd')
         ctx = trace_entry(entry, root=REPO)
         names = sorted(c.name for c in ctx.calls)
-        assert names == ['_bwd_dkv_kernel', '_bwd_dq_kernel',
-                         '_fwd_kernel']
+        # the `name=` each pallas_call gives its kernel
+        assert names == ['flash_attention_dkv', 'flash_attention_dq',
+                         'flash_attention_fwd']
 
     def test_scratch_and_scalar_prefetch_extracted(self):
         entry = next(e for e in all_entries()

@@ -711,3 +711,301 @@ class TestMetaTracelint:
             top_level = [ln for ln in open(mod.__file__).read().splitlines()
                          if ln.startswith(('import ', 'from '))]
             assert not any('jax' in ln for ln in top_level), mod.__name__
+
+
+# ---------------------------------------------------------------------------
+# The engines' spans in the profiler's trace and in the ring
+# ---------------------------------------------------------------------------
+
+class _Session:
+    """A jax.profiler session over a block (python tracing off), then the
+    program's own spans as the profiler recorded them:
+    `spans` = [(line, name, start_ns, end_ns)] in start order."""
+
+    def __init__(self, trace_dir):
+        self.dir, self.spans = str(trace_dir), None
+
+    def __enter__(self):
+        import jax
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self.dir, profiler_options=options)
+        return self
+
+    def __exit__(self, *exc):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(self.dir, 'plugins', 'profile', '*',
+                                       '*.xplane.pb'))
+        self.spans = sorted(
+            ((f'{plane.name}/{line.name}', e.name, e.start_ns,
+              e.start_ns + e.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith('/host:')
+             for line in plane.lines for e in line.events
+             if e.name.startswith(('serve.', 'train.'))),
+            key=lambda s: (s[2], -s[3]))
+        return False
+
+    def named(self, name):
+        return [s for s in self.spans if s[1] == name]
+
+    def inside(self, parent):
+        """The spans that began while `parent` was open, on its line."""
+        return [s for s in self.spans if s is not parent
+                and s[0] == parent[0] and parent[2] <= s[2] < parent[3]]
+
+
+def _ring(name, events=None):
+    events = obs.TRACER.events() if events is None else events
+    return [e for e in events if e['name'] == name]
+
+
+STEP_CHILDREN = {'serve.admit', 'serve.admission', 'serve.top_up',
+                 'serve.prefill', 'serve.stage', 'serve.dispatch',
+                 'serve.host_read', 'serve.commit'}
+
+
+class TestSpansOnTheProfilersClock:
+    def _engine(self, max_slots=2):
+        from paddle_tpu.inference.serving import ServingEngine
+
+        srv = ServingEngine(_model(), max_slots=max_slots, block_size=8,
+                            max_context_len=64, max_new_tokens=8,
+                            decode_window=4)
+        # both buckets' programs and the window compile outside the
+        # session, and leave spans in the ring from before it
+        srv.serve([_prompt(70, 6), _prompt(71, 20)])
+        return srv
+
+    @staticmethod
+    def _run(srv, prompts):
+        """submit + step to the end. Returns (requests, steps made,
+        deliveries [(rid, first, n)] over all steps)."""
+        reqs = [srv._live[srv.submit(p)] for p in prompts]
+        steps, delivered = 0, []
+        while srv.in_flight() or len(srv.queue):
+            srv.step()
+            steps += 1
+            delivered += srv.last_deliveries
+        return reqs, steps, delivered
+
+    def test_serve_spans_nest_in_the_profilers_trace(self, tmp_path):
+        srv = self._engine()
+        before = len(obs.TRACER)
+        with _Session(tmp_path) as prof:
+            # two slots, four requests of two buckets: steps that admit
+            # (one with a standalone second-bucket prefill) and bare ones
+            _, steps, _ = self._run(srv, [_prompt(1, 6), _prompt(2, 20),
+                                          _prompt(3, 7), _prompt(4, 5)])
+        self._run(srv, [_prompt(5, 6)])          # after the session
+        parents = prof.named('serve.step')
+        assert len(parents) == steps >= 4
+        assert prof.named('serve.prefill')
+        kinds = [e['args']['kind'] for e in _ring('serve.step',
+                                                  obs.TRACER.traced())]
+        assert kinds.count('step') >= 2 and kinds.count('window') >= 2
+        covered = 0
+        for parent in parents:
+            children = prof.inside(parent)
+            assert {c[1] for c in children} <= STEP_CHILDREN
+            assert {'serve.top_up', 'serve.stage', 'serve.dispatch',
+                    'serve.host_read', 'serve.commit'} <= {
+                        c[1] for c in children}
+            assert all(c[3] <= parent[3] for c in children), children
+            edge = parent[2]
+            for _line, _name, start, end in children:    # their union
+                covered += max(0, end - max(start, edge))
+                edge = max(edge, end)
+        whole = sum(p[3] - p[2] for p in parents)
+        assert covered >= 0.9 * whole
+        # the ring's cut to the session: the same spans in the same
+        # number, none from before start_trace or after stop_trace
+        traced = obs.TRACER.traced()
+        count = lambda names: sorted(           # noqa: E731
+            (n, names.count(n)) for n in set(names))
+        assert count([e['name'] for e in traced]) == count(
+            [s[1] for s in prof.spans])
+        assert all(e.get('traced') for e in traced)
+        events = obs.TRACER.events()
+        assert not any(e.get('traced') for e in events[:before])
+        assert len(_ring('serve.step')) > len(parents) + 1
+        assert len(_ring('serve.step', traced)) == len(parents)
+
+    def test_span_args_count_what_the_step_did(self):
+        srv = self._engine(max_slots=4)
+        obs.TRACER.clear()
+        prompts = [_prompt(11, 6), _prompt(12, 20), _prompt(13, 9)]
+        reqs, steps, delivered = self._run(srv, prompts)
+        tokens = sum(len(r.generated) for r in reqs)
+        assert tokens == 3 * 8
+        assert sum(e['args']['committed']
+                   for e in _ring('serve.commit')) == tokens
+        assert sum(e['args']['committed']
+                   for e in _ring('serve.step')) == tokens
+        assert sum(e['args']['finished']
+                   for e in _ring('serve.commit')) == 3
+        admitting = [e['args'] for e in _ring('serve.dispatch')
+                     + _ring('serve.prefill') if e['args']['rows']]
+        assert [a['bucket'] for a in admitting] == [16, 32]   # fused first
+        assert all(a['padded_tokens'] == 4 * a['bucket'] for a in admitting)
+        assert sum(a['real_tokens'] for a in admitting) == 6 + 20 + 9
+        assert sum(a['rows'] for a in admitting) == 3
+        first, = [e['args'] for e in _ring('serve.dispatch')
+                  if e['args']['kind'] == 'step']
+        assert (first['live'], first['slots']) == (3, 4)
+        bare = [e['args'] for e in _ring('serve.dispatch')
+                if e['args']['kind'] == 'window']
+        assert len(bare) == steps - 1
+        assert all(a['padded_tokens'] == a['real_tokens'] == 0
+                   for a in bare)
+        admit, = _ring('serve.admit')
+        assert admit['args'] == {'admitted': 3, 'queue_depth': 0}
+        waits = {e['args']['rid']: e['args'] for e in
+                 _ring('serve.admission')}
+        for req in reqs:
+            a = waits[req.rid]
+            assert a['wait_ms'] == pytest.approx(
+                (req.when('admitted') - req.enqueued_at) * 1e3, abs=1.0)
+            assert a['prompt_len'] == len(req.prompt)
+            assert a['bucket'] == (32 if len(req.prompt) > 16 else 16)
+
+    def test_last_deliveries_is_what_the_step_delivered(self):
+        srv = self._engine(max_slots=4)
+        reqs, steps, delivered = self._run(srv, [_prompt(21, 6),
+                                                 _prompt(22, 20)])
+        for req in reqs:
+            mine = [(first, n) for rid, first, n in delivered
+                    if rid == req.rid]
+            assert mine == [(0, 4), (4, 4)]
+        srv.step()                                   # nothing to run
+        assert srv.last_deliveries == []
+        assert _ring('serve.step')[-1]['args'] == {'kind': 'idle'}
+
+    def test_a_step_that_raises_leaves_no_span_open(self, tmp_path):
+        from paddle_tpu.testing.faults import FaultError, FaultInjector
+
+        srv = self._engine()
+        obs.TRACER.clear()
+        rids = [srv.submit(_prompt(s, 6)) for s in (31, 32)]
+        inj = FaultInjector()
+        inj.script('dispatch', when=lambda c: c.get('kind') == 'window',
+                   times=1)
+        with _Session(tmp_path) as prof:
+            with inj:
+                with pytest.raises(FaultError):
+                    srv.step()
+            srv.run()
+        assert all(srv.result(r) is not None for r in rids)
+        steps = prof.named('serve.step')
+        assert len(steps) == len(_ring('serve.step')) >= 3
+        # the step that raised is closed in both sinks (an open
+        # annotation is never recorded; an open ring span never emitted)
+        # and the next step is not nested inside it
+        assert all(a[3] <= b[2] for a, b in zip(steps, steps[1:]))
+        raised = prof.inside(steps[0])
+        assert {'serve.top_up', 'serve.stage'} <= {c[1] for c in raised}
+        assert 'serve.dispatch' not in {c[1] for c in raised}
+        assert all(c[3] <= steps[0][3] for c in raised)
+        assert 'kind' not in _ring('serve.step')[0].get('args', {})
+
+    def test_telemetry_off_keeps_the_profilers_spans(self, tmp_path):
+        srv = self._engine()
+        obs.TRACER.clear()
+        obs.set_enabled(False)
+        try:
+            with _Session(tmp_path) as prof:
+                _, steps, _ = self._run(srv, [_prompt(41, 6)])
+            # and with no session either: nothing recorded, nothing raised
+            self._run(srv, [_prompt(42, 6)])
+        finally:
+            obs.set_enabled(True)
+        assert len(obs.TRACER) == 0 and obs.TRACER.traced() == []
+        assert len(prof.named('serve.step')) == steps
+        assert len(prof.named('serve.dispatch')) == steps
+        assert len(prof.named('serve.admission')) == 1
+
+    def test_train_spans_in_both_sinks(self, tmp_path):
+        eng, batch = TestTrainTelemetry._engine(None, log_window=10 ** 9)
+        eng.step((batch,))
+        eng.sync()                               # compiled
+        obs.TRACER.clear()
+        with _Session(tmp_path) as prof:
+            feed = eng.prefetch(np.asarray(batch) for _ in range(3))
+            for b in feed:
+                eng.step((b,))
+            eng.sync()
+        for name, n in (('train.step', 3), ('train.sync', 1),
+                        ('train.feed', 4)):      # the 4th finds the end
+            assert len(prof.named(name)) == n, name
+            assert len(_ring(name, obs.TRACER.traced())) == n, name
+        assert [e['args'] for e in _ring('train.step')] == [
+            {'tokens': batch.size}] * 3
+        assert _ring('train.sync')[0]['args'] == {'window': 3}
+        # a step is dispatch only: no span of the feed or the sync inside
+        for step in prof.named('train.step'):
+            assert prof.inside(step) == []
+
+    def test_traced_is_the_newest_session(self, tmp_path):
+        t = HostTracer()
+        with t.span('before'):
+            pass
+        with _Session(tmp_path / 'a'):
+            with t.span('first', n=1):
+                pass
+        t.instant('between')
+        assert [e['name'] for e in t.traced()] == ['first']
+        with _Session(tmp_path / 'b'):
+            with t.span('outer') as sp:
+                t.instant('tick', k=2)
+                sp.set(late=3)
+        with t.span('after'):
+            pass
+        assert [(e['name'], e.get('args')) for e in t.traced()] == [
+            ('tick', {'k': 2}), ('outer', {'late': 3})]
+        assert [e['name'] for e in t.events() if e.get('traced')] == [
+            'first', 'tick', 'outer']
+        t.clear()
+        assert t.traced() == []
+
+    def test_late_args_reach_the_annotation(self, tmp_path):
+        import glob
+
+        from jax.profiler import ProfileData
+
+        with _Session(tmp_path):
+            sp = obs.span('serve.commit', cat='test', slot=1).begin()
+            sp.end(committed=5)
+            obs.instant('serve.admission', rid=7)
+        path, = glob.glob(str(tmp_path / 'plugins' / 'profile' / '*' /
+                              '*.xplane.pb'))
+        stats = {e.name: dict(e.stats)
+                 for plane in ProfileData.from_file(path).planes
+                 for line in plane.lines for e in line.events
+                 if e.name.startswith('serve.')}
+        assert stats == {'serve.commit': {'slot': 1, 'committed': 5},
+                         'serve.admission': {'rid': 7}}
+        assert _ring('serve.commit')[0]['args'] == {'slot': 1,
+                                                    'committed': 5}
+
+    def test_one_code_path_opens_a_span(self):
+        """`annotate` is a name for `span`, and nothing else in the
+        package opens a TraceAnnotation of its own."""
+        import re
+
+        from paddle_tpu.observability import tracing
+
+        assert tracing.annotate is tracing.span
+        hits = []
+        for root, _dirs, files in os.walk(os.path.join(REPO, 'paddle_tpu')):
+            for name in files:
+                if name.endswith('.py'):
+                    path = os.path.join(root, name)
+                    if re.search(r'TraceAnnotation\(', open(path).read()):
+                        hits.append(os.path.relpath(path, REPO))
+        assert hits in ([], ['paddle_tpu/observability/tracing.py'])
