@@ -72,14 +72,16 @@ class Sizes:
 
 # Depth and batch as the chip's compiler sizes them for one 16 GB v5e
 # (15.75 GiB usable), by memory_analysis() of the largest program dispatched:
-# - serve: the fused admit+decode step at the 1024 bucket needs 14.29 GiB at
-#   12 layers (weights 5.0, pools 3.0, temporaries 6.3) and each layer adds
-#   1.04 (13.25 at 11), so 12 of Llama-2-7B's 32 layers is the deepest stack
-#   that leaves >= 1 GiB;
+# - serve: the fused admit+decode step needs 6-7 GiB of temporaries whatever
+#   it admits (the decode half's 4.2 and per-layer copies on top), most at
+#   the 128 bucket, which this smoke's largest admission group now rides
+#   (PR 26): 14.90 GiB at 12 layers (weights 5.0, pools 3.0, temporaries
+#   6.9) and each layer adds 1.21 (13.69 at 11), so 11 of Llama-2-7B's 32
+#   layers is the deepest stack that leaves >= 1 GiB;
 # - train: weights + AdamW moments are 9.98 GiB at 4 layers and each
 #   2048-token sample adds ~1.27 GiB of temporaries: batch 3 is 13.85 GiB,
 #   batch 4 would be 15.1 and the compiler rematerialises to squeeze it in.
-FULL = Sizes(width=WIDTH, serve_layers=12, slots=8, context=2048,
+FULL = Sizes(width=WIDTH, serve_layers=11, slots=8, context=2048,
              prompt_lens=(900, 640, 530, 100, 90, 70), reference=(0, 3),
              new_tokens=24, tolerance=0.25, train_layers=4, train_batch=3,
              train_seq=2048, train_steps=4)
@@ -245,22 +247,20 @@ def check_served(outs, prompts, sizes):
 
 
 def serving_specs(engine, sizes):
-    """The programs this smoke's admission pattern dispatches: everything
-    is submitted before the first step and fits the slots, so step one is
-    the fused admit+decode step at the largest bucket plus a standalone
-    prefill at each smaller one, and every later step is the pure decode
-    window."""
+    """The programs this smoke's admissions dispatched, as the engine's own
+    registry noted them: everything is submitted before the first step and
+    fits the slots, so step one is the fused admit+decode step of the
+    largest admission group plus a standalone prefill for every other
+    group (another bucket, or what of a bucket did not fit its batch's
+    rows), and every later step is the pure decode window."""
     from paddle_tpu.aot import geometry
-    from paddle_tpu.inference.engine import bucket_length
+    from paddle_tpu.inference.engine import COMPILE_CACHE
 
-    big, *smaller = sorted(
-        {bucket_length(n, engine.buckets) for n in sizes.prompt_lens},
-        reverse=True)
-    want = {('serve_step', big), ('serve_window', None),
-            *(('serve_prefill', b) for b in smaller)}
+    noted = set(COMPILE_CACHE.keys())
     for g in geometry.for_serving_engine(engine,
                                          prompt_lens=sizes.prompt_lens):
-        if (g.kind, g.params.get('bucket')) in want:
+        key, = geometry.GeometrySet([g]).registry_keys(engine)
+        if key in noted:
             for fn, args, kwargs in engine._cost_specs(g):
                 yield g.label(), fn, args, kwargs
 

@@ -56,6 +56,9 @@ WIRE_STRUCTURAL = {
     },
     'aot_config': {
         'engine': 'class tag, not instance state',
+        'prefill_tokens': 'module constant (serving.PREFILL_TOKENS): '
+                          'the admission batch\'s token budget, which '
+                          'fixes the row count of every prefill program',
     },
     'train_aot_config': {
         'engine': 'class tag, not instance state',

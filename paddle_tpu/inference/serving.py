@@ -21,8 +21,11 @@ the ITERATION level instead:
      in-flight batch (`max_slots` rows, shapes never change):
        - retire/admit: finished rows already freed their pages; queued
          requests prefill into freshly allocated pages through the
-         bucketed `_paged_prefill` (one compilation per bucket, the
-         PR-1 discipline);
+         bucketed `_paged_prefill`. An admission batch is sized by a
+         TOKEN budget, not by the slot count: `PREFILL_TOKENS // bucket`
+         rows (`ServingEngine._prefill_rows`), so each bucket still has
+         ONE row count and one compilation, and what does not fit one
+         batch prefills in further dispatches of the same step;
        - decode: ALL slots advance `decode_window` tokens in ONE fused
          jitted dispatch (`_serve_window`: a lax.scan whose single-token
          steps route the model through `cached_attention`'s
@@ -129,6 +132,18 @@ from ..testing import faults as _faults
 from ._schema import KV_BLOB_KIND, SNAPSHOT_SCHEMA
 from .engine import (COMPILE_CACHE, DEFAULT_BUCKETS, _count_trace,
                      bucket_length, total_traces, trace_counts)
+
+# Padded positions one admission-prefill dispatch may hold: a batch at
+# bucket Sb is `PREFILL_TOKENS // Sb` rows wide (at least 1, at most
+# max_slots — `ServingEngine._prefill_rows`, the one place that knows).
+# A few times the chip's ridge point (v5e: 197e12 FLOP/s over 819e9 B/s
+# is ~240 tokens a dispatch for bf16 weights): a dispatch this size is
+# already compute-bound, so every further padded row costs what a real
+# one does and buys nothing. A constant, not an option: the row count is
+# a traced shape, so it keys every compiled admission program and AOT
+# artifact (`_geometry`, `aot_config`); the value was settled by a chip
+# sweep over {512, 1024, 2048} (PERF.md section 6, PR 26).
+PREFILL_TOKENS = 1024
 
 
 class OutOfBlocks(RuntimeError):
@@ -833,17 +848,18 @@ def _sample_rows_dist(logits, temp, topk, topp, keys):
 def _prefill_kv(model, pages, ids, real_len, btabs):
     """Bucketed BATCHED admission prefill INTO pages (traced body,
     shared by the standalone `_paged_prefill` jit and the fused
-    `_serve_step`/`_serve_spec_step`): run the model once over up to
-    max_slots RIGHT-padded prompts (K, Sb) with a throwaway contiguous
+    `_serve_step`/`_serve_spec_step`): run the model once over K
+    RIGHT-padded prompts (K, Sb) with a throwaway contiguous
     cache in the pool's quantization world (the standard causal path —
     pad rows come after the real tokens, so rows < real_len never see
     them), then scatter every K/V row into its request's pages: row s
     of request b lands in page btabs[b, s // BS] slot s % BS, pad and
     DUMMY rows (real_len == 0) land on the scratch page 0. The batch
-    width is FIXED at max_slots and real lengths ride as device data,
-    so one compilation per bucket serves every admission count and
-    every prompt length in the bucket. Returns (per-row last-token
-    logits (K, V), pages)."""
+    width K is read off `ids`: the engine fixes ONE width per bucket
+    (`ServingEngine._prefill_rows`, a token budget) and real lengths
+    ride as device data, so one compilation per bucket serves every
+    admission count and every prompt length in the bucket. Returns
+    (per-row last-token logits (K, V), pages)."""
     K, Sb = ids.shape
     tmp = _tmp_cache(model, pages, K, Sb)
     logits, tmp = model(ids, caches=tmp, cache_index=0)
@@ -928,9 +944,10 @@ def _window_body(model, pages, last_logits, btab, ctx, live, budget,
 
 @functools.partial(jax.jit, donate_argnames=('pages', 'last_logits'))
 def _paged_prefill(model, pages, last_logits, ids, real_len, btabs, slots):
-    """Standalone admission prefill (see _prefill_body) — used only for
-    the rare step that admits across SEVERAL buckets at once; the first
-    (largest) bucket group rides fused inside _serve_step."""
+    """Standalone admission prefill (see _prefill_body) — for every
+    admission group of a step beyond the first: further buckets, and
+    what of one bucket did not fit its batch's rows. The first
+    (largest) group rides fused inside _serve_step."""
     _count_trace('serve_prefill')
     return _prefill_body(model, pages, last_logits, ids, real_len, btabs,
                          slots)
@@ -959,7 +976,8 @@ def _serve_step(model, pages, last_logits, ids, real_len, btabs, slots,
     admitted rows bucket-prefill into their newly allocated pages
     (_prefill_body), then every slot — new and old — decodes a window
     through the paged kernel (_window_body). One compilation per
-    (bucket, window) pair covers every admission count; a step with no
+    (bucket, window) pair covers every admission count (the prefill
+    batch's width is a function of the bucket alone); a step with no
     admissions uses _serve_window instead."""
     _count_trace('serve_step')
     last_logits, pages = _prefill_body(model, pages, last_logits, ids,
@@ -1605,10 +1623,10 @@ class ServingEngine:
                 self._dlogits = self._put(jnp.zeros(
                     (self.max_slots, self.draft.config.vocab_size),
                     self.draft.cache_dtype()))
-                # all-dummy slot indices: the draft-side prefill legs
-                # drop their logits commit through the OOB scatter
-                self._dummy_slots = self._put(np.full(
-                    (self.max_slots,), self.max_slots, np.int32))
+                # all-dummy slot indices, by batch width (`_dummy`):
+                # the draft-side prefill and chunk legs drop their
+                # logits commit through the OOB scatter
+                self._dummy_slots = {}
         # (chunk bucket, ctx bucket) shapes the draft's chunk/catch-up
         # legs have dispatched — a fresh shape's step counts as a
         # cache MISS (it paid trace + compile)
@@ -1862,8 +1880,11 @@ class ServingEngine:
         # additionally folds in its draft's identity + window: two
         # engines over the same target but different drafts trace
         # different programs.
+        # So is the admission batch's token budget: it fixes the row
+        # count of every prefill program (`_prefill_rows`).
         g = ('paged', self.max_slots, self.allocator.num_blocks,
-             self.block_size, self.max_blocks_per_seq, self.tp)
+             self.block_size, self.max_blocks_per_seq, PREFILL_TOKENS,
+             self.tp)
         if self.draft is not None:
             from .engine import model_tag
 
@@ -2059,6 +2080,10 @@ class ServingEngine:
             'top_p': self.top_p,
             'eos_token_id': self.eos_token_id,
             'buckets': list(self.buckets),
+            # the admission batch's width is `_prefill_rows(bucket)`: an
+            # artifact built under another budget holds programs of
+            # other row counts, which this engine would never look up
+            'prefill_tokens': PREFILL_TOKENS,
             'prefix_cache': self.prefix_cache,
             'prefill_chunk': self.prefill_chunk,
             # speculative + quantized serving are compilation-relevant:
@@ -2146,7 +2171,7 @@ class ServingEngine:
                     # the live standalone prefill runs a draft leg too
                     self._dlogits, self._dpages = _paged_prefill(
                         self.draft, self._dpages, self._dlogits, ids,
-                        real_len, btabs, self._dummy_slots)
+                        real_len, btabs, self._dummy(slots.shape[0]))
             elif g.kind == 'serve_chunk_step':
                 Cb, Sb = int(p['chunk']), int(p['bucket'])
                 ids = self._put(np.zeros((K, Cb), np.int32))
@@ -2269,7 +2294,7 @@ class ServingEngine:
             ids = self._put(np.zeros((K, cb), np.int32))
             self._dlogits, self._dpages = _draft_chunk(
                 self.draft, self._dpages, self._dlogits, ids, z, z,
-                btabs, self._dummy_slots, z, z, ctx_bucket=Sb)
+                btabs, self._dummy(K), z, z, ctx_bucket=Sb)
 
     def warmup(self, artifact=None, geometries=None, draft=None):
         """Pre-populate the module-level jit caches (and the
@@ -2312,11 +2337,13 @@ class ServingEngine:
         samp = (fvec, ivec, fvec, svec, ivec)   # temp/topk/topp/seed/plen
         common = dict(window=W, eos_token_id=self.eos_token_id)
         if g.kind in ('serve_step', 'serve_prefill', 'serve_spec_step'):
-            ids = jax.ShapeDtypeStruct((K, int(p['bucket'])), jnp.int32)
-            rl = jax.ShapeDtypeStruct((K,), jnp.int32)
-            btabs = jax.ShapeDtypeStruct((K, self.max_blocks_per_seq),
+            # the admission batch: `_prefill_args`'s shapes, not K rows
+            R = self._prefill_rows(int(p['bucket']))
+            ids = jax.ShapeDtypeStruct((R, int(p['bucket'])), jnp.int32)
+            rl = jax.ShapeDtypeStruct((R,), jnp.int32)
+            btabs = jax.ShapeDtypeStruct((R, self.max_blocks_per_seq),
                                          jnp.int32)
-            slots = jax.ShapeDtypeStruct((K,), jnp.int32)
+            slots = jax.ShapeDtypeStruct((R,), jnp.int32)
         elif g.kind == 'serve_chunk_step':
             ids = jax.ShapeDtypeStruct((K, int(p['chunk'])), jnp.int32)
             rl = jax.ShapeDtypeStruct((K,), jnp.int32)
@@ -2392,23 +2419,25 @@ class ServingEngine:
         svec = jax.ShapeDtypeStruct((K,), jnp.uint32)
         samp = (fvec, vec, fvec, svec, vec)
         common = dict(window=W, eos_token_id=self.eos_token_id)
-        if g.kind == 'serve_step':
-            ids = jax.ShapeDtypeStruct((K, int(p['bucket'])), jnp.int32)
-            btabs = jax.ShapeDtypeStruct((K, self.max_blocks_per_seq),
+        if g.kind in ('serve_step', 'serve_prefill', 'serve_spec_step'):
+            # the admission batch: `_prefill_args`'s shapes (`rvec` is
+            # per ROW of it, `vec` per SLOT)
+            R = self._prefill_rows(int(p['bucket']))
+            ids = jax.ShapeDtypeStruct((R, int(p['bucket'])), jnp.int32)
+            btabs = jax.ShapeDtypeStruct((R, self.max_blocks_per_seq),
                                          jnp.int32)
+            rvec = jax.ShapeDtypeStruct((R,), jnp.int32)
+        if g.kind == 'serve_step':
             yield (_serve_step,
-                   (self.model, pages, logits, ids, vec, btabs, vec,
+                   (self.model, pages, logits, ids, rvec, btabs, rvec,
                     btab, vec, live, vec) + samp, common)
         elif g.kind == 'serve_window':
             yield (_serve_window,
                    (self.model, pages, logits, btab, vec, live, vec)
                    + samp, common)
         elif g.kind == 'serve_prefill':
-            ids = jax.ShapeDtypeStruct((K, int(p['bucket'])), jnp.int32)
-            btabs = jax.ShapeDtypeStruct((K, self.max_blocks_per_seq),
-                                         jnp.int32)
             yield (_paged_prefill,
-                   (self.model, pages, logits, ids, vec, btabs, vec), {})
+                   (self.model, pages, logits, ids, rvec, btabs, rvec), {})
         elif g.kind == 'serve_chunk_step':
             ids = jax.ShapeDtypeStruct((K, int(p['chunk'])), jnp.int32)
             btabs = jax.ShapeDtypeStruct((K, self.max_blocks_per_seq),
@@ -2421,13 +2450,10 @@ class ServingEngine:
                    dict(ctx_bucket=int(p['bucket']), **common))
         elif g.kind == 'serve_spec_step':
             dpages = sds(self._dpages)
-            ids = jax.ShapeDtypeStruct((K, int(p['bucket'])), jnp.int32)
-            btabs = jax.ShapeDtypeStruct((K, self.max_blocks_per_seq),
-                                         jnp.int32)
             fbool = jax.ShapeDtypeStruct((K,), jnp.bool_)
             yield (_serve_spec_step,
                    (self.model, self.draft, pages, dpages, logits, ids,
-                    vec, btabs, vec, vec, fbool, btab, vec, live, vec)
+                    rvec, btabs, rvec, vec, fbool, btab, vec, live, vec)
                    + samp,
                    dict(k=int(p['spec']), ctx_bucket=int(p['ctx']),
                         eos_token_id=self.eos_token_id))
@@ -3709,12 +3735,13 @@ class ServingEngine:
             # the host-to-device uploads a dispatch waits for
             return _obs_trace.span('serve.stage', cat='scheduler')
 
-        def dispatch(bucket=0, real_lens=()):
+        def dispatch(bucket=0, real_lens=(), padded_rows=None):
             # the one jitted call of the step (it returns futures), with
             # the fill of its fused admission: zeros for a bare window
             return _obs_trace.span(
                 'serve.dispatch', cat='scheduler', kind=kind, live=live,
-                slots=self.max_slots, **self._fill(bucket, real_lens))
+                slots=self.max_slots,
+                **self._fill(bucket, real_lens, padded_rows))
 
         with stage():
             dev = self._device_state()
@@ -3844,8 +3871,8 @@ class ServingEngine:
                     hit = False          # this step pays its compile
                 self._dlogits, self._dpages = _draft_chunk(
                     self.draft, self._dpages, self._dlogits, ids, clen,
-                    cst, btabs, self._dummy_slots, cow_src, cow_dst,
-                    ctx_bucket=Sb)
+                    cst, btabs, self._dummy(self.max_slots), cow_src,
+                    cow_dst, ctx_bucket=Sb)
                 for s, _r, p, t in chunk_rows:
                     self._dctx[s] = p + t
                 # decoding rows' draft holes (the PREVIOUS chunk-step
@@ -3865,7 +3892,8 @@ class ServingEngine:
                 # non-speculative engines can never have forced rows —
                 # the constant zero uploads skip the per-step scan
                 ftok_d, forced_d = self._zero_ftok, self._zero_forced
-            with dispatch(Cb, [t for _s, _r, _p, t in chunk_rows]):
+            with dispatch(Cb, [t for _s, _r, _p, t in chunk_rows],
+                          self.max_slots):
                 toks, self._last_logits, self._pages, ctx_out = \
                     _serve_chunk_step(
                         self.model, self._pages, self._last_logits, ids,
@@ -4129,7 +4157,7 @@ class ServingEngine:
         self._dlogits, self._dpages = _draft_chunk(
             self.draft, self._dpages, self._dlogits, self._put(ids),
             self._put(clen), self._put(start), self._put(btabs),
-            self._dummy_slots, z, z, ctx_bucket=Sb)
+            self._dummy(K), z, z, ctx_bucket=Sb)
         for slot, req, p, take in rows:
             self._dctx[slot] = p + take
         return fresh
@@ -4185,11 +4213,13 @@ class ServingEngine:
     def _admit(self):
         """Fill free slots from the queue head (priority order — a head
         that cannot get its prefill pages waits, no barging past it).
-        Returns this step's admissions grouped by prefill bucket,
-        LARGEST group first (that one rides fused inside _serve_step;
-        the batch width is pinned at max_slots with dummy rows masked
-        to the scratch page, so the admission count never changes a
-        traced shape)."""
+        Returns this step's admissions as prefill groups [(bucket,
+        [(slot, req)])], each of one bucket and at most that bucket's
+        `_prefill_rows`, LARGEST group first (that one rides fused
+        inside _serve_step; the rest prefill standalone in this same
+        step, before it). A batch's width is a function of its bucket
+        alone, with dummy rows masked to the scratch page, so the
+        admission count never changes a traced shape."""
         if not len(self.queue):
             # steady-state fast path: nothing to admit, skip even the
             # admit span (most steps of a drained-queue run land here)
@@ -4364,7 +4394,12 @@ class ServingEngine:
         for slot, req in placed:
             Sb = bucket_length(req.context_len, self.buckets)
             by_bucket.setdefault(Sb, []).append((slot, req))
-        return sorted(by_bucket.items(), key=lambda kv: -len(kv[1]))
+        groups = []
+        for Sb, members in by_bucket.items():
+            rows = self._prefill_rows(Sb)
+            groups.extend((Sb, members[i:i + rows])
+                          for i in range(0, len(members), rows))
+        return sorted(groups, key=lambda kv: -len(kv[1]))
 
     def _place(self, slot, req, pages):
         """Arm a slot (host bookkeeping only; the batched prefill in
@@ -4402,21 +4437,44 @@ class ServingEngine:
             pages=len(pages), wait_ms=wait_ms, prompt_len=len(req.prompt),
             bucket=bucket_length(req.context_len, self.buckets))
 
-    def _fill(self, bucket, real_lens):
+    def _prefill_rows(self, Sb):
+        """Rows of an admission-prefill batch at bucket `Sb`: what the
+        token budget holds, at least one and never more than there are
+        slots. The ONE place that knows the width: `_admit` splits by
+        it, `_prefill_args` builds it, `_fill` reports it, and
+        `_cost_specs`/`_export_specs` restate it, so the warmed program
+        is the served one."""
+        return max(1, min(PREFILL_TOKENS // Sb, self.max_slots))
+
+    def _dummy(self, rows):
+        """All-dummy slot indices for a draft-side leg of `rows` rows
+        (uploaded once per width)."""
+        d = self._dummy_slots.get(rows)
+        if d is None:
+            d = self._dummy_slots[rows] = self._put(
+                np.full((rows,), self.max_slots, np.int32))
+        return d
+
+    def _fill(self, bucket, real_lens, padded_rows=None):
         """How full one fixed-width prefill batch is, as `serve.dispatch`
-        and `serve.prefill` report it: its rows' real tokens beside the
-        `max_slots * bucket` positions the batch is padded to."""
+        and `serve.prefill` report it: its `rows` real rows and their
+        real tokens beside the `padded_rows * bucket` positions the
+        batch holds. The width is the admission batch's unless given (a
+        chunk batch is `max_slots` wide); a bare window has none."""
+        if padded_rows is None:
+            padded_rows = self._prefill_rows(bucket) if bucket else 0
         return dict(bucket=bucket, rows=len(real_lens),
-                    real_tokens=sum(real_lens),
-                    padded_tokens=self.max_slots * bucket)
+                    padded_rows=padded_rows, real_tokens=sum(real_lens),
+                    padded_tokens=padded_rows * bucket)
 
     def _prefill_args(self, Sb, group):
         """Device args for one fixed-width admission-prefill batch
-        (all of `group` shares bucket Sb; at most max_slots members —
-        one per free slot). Rows beyond the group are dummies: real_len
-        0 (their K/V land on the scratch page) and slot index SLOTS
-        (their logits row is dropped by the OOB scatter)."""
-        K = self.max_slots
+        (all of `group` shares bucket Sb; at most `_prefill_rows(Sb)`
+        members — `_admit` splits a bucket's admissions so). Rows
+        beyond the group are dummies: real_len 0 (their K/V land on the
+        scratch page) and slot index SLOTS (their logits row is dropped
+        by the OOB scatter)."""
+        K = self._prefill_rows(Sb)
         ids = np.zeros((K, Sb), np.int32)
         real_len = np.zeros((K,), np.int32)
         btabs = np.zeros((K, self.max_blocks_per_seq), np.int32)
@@ -4433,12 +4491,13 @@ class ServingEngine:
 
     def _prefill_group(self, Sb, group):
         """Standalone prefill dispatch for an admission group that did
-        not fit the fused step (multi-bucket admission steps, or any
-        monolithic admission landing on a step whose fused dispatch is
-        the chunk group's). A speculative engine prefills the DRAFT's
-        pages too — the draft must hold every admitted row's prompt KV
-        or its proposals would be conditioned on zeros and the accept
-        rate would silently collapse."""
+        not fit the fused step (a second bucket, more of one bucket
+        than its batch has rows, or any monolithic admission landing on
+        a step whose fused dispatch is the chunk group's). A
+        speculative engine prefills the DRAFT's pages too — the draft
+        must hold every admitted row's prompt KV or its proposals would
+        be conditioned on zeros and the accept rate would silently
+        collapse."""
         with _obs_trace.span(
                 'serve.prefill', cat='scheduler',
                 **self._fill(Sb, [r.context_len for _s, r in group])):
@@ -4450,18 +4509,19 @@ class ServingEngine:
             if self.draft is not None:
                 self._dlogits, self._dpages = _paged_prefill(
                     self.draft, self._dpages, self._dlogits, ids, real_len,
-                    btabs, self._dummy_slots)
+                    btabs, self._dummy(slots.shape[0]))
 
     def _chunk_args(self, rows):
         """Device args for one fixed-width chunk-continuation batch
-        (the K-row discipline of `_prefill_args`: row i of the batch
-        is rows[i] = (slot, req, progress, take); everything past the
-        group is a dummy that lands on the scratch page and drops its
-        logits). Returns the arrays plus the static (chunk bucket,
-        context bucket) pair that keys the dispatch — row counts,
-        chunk lengths, and per-row progress all ride as device data,
-        so a whole long-prompt flood shares one compilation per
-        bucket pair."""
+        (the row discipline of `_prefill_args`, but `max_slots` wide
+        whatever the chunk bucket — the token budget does not size it
+        yet: row i of the batch is rows[i] = (slot, req, progress,
+        take); everything past the group is a dummy that lands on the
+        scratch page and drops its logits). Returns the arrays plus
+        the static (chunk bucket, context bucket) pair that keys the
+        dispatch — row counts, chunk lengths, and per-row progress all
+        ride as device data, so a whole long-prompt flood shares one
+        compilation per bucket pair."""
         K = self.max_slots
         Cb = bucket_length(max(t for _s, _r, _p, t in rows), self.buckets)
         Sb = bucket_length(max(p + t for _s, _r, p, t in rows),

@@ -49,8 +49,8 @@ WIRES = {
         'draft', 'draft_struct', 'engine', 'eos_token_id',
         'kv_cache_dtype', 'max_context_len', 'max_new_tokens',
         'max_slots', 'model', 'model_struct', 'num_blocks',
-        'num_draft_tokens', 'prefill_chunk', 'prefix_cache',
-        'temperature', 'top_k', 'top_p', 'tp'],
+        'num_draft_tokens', 'prefill_chunk', 'prefill_tokens',
+        'prefix_cache', 'temperature', 'top_k', 'top_p', 'tp'],
     'blob': [
         'block_size', 'config', 'draft_kv_len', 'draft_layers', 'kind',
         'kv_cache_dtype', 'kv_len', 'layers', 'request', 'schema',
