@@ -1,19 +1,22 @@
-"""What the drivers share: the run's environment, the model made from the
-seed, the profiler window and the result line."""
+"""What the drivers share: the run's environment, the lookup of a family's
+and a reader's files, the profiler window and the result line."""
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
 import os
 import shutil
+import sys
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, 'benchmark')
+FAMILIES = BENCH        # where `families/` and `reference/families/` stand
 
 
 def load(kind, name):
@@ -37,42 +40,38 @@ class Env:
     trace_dir: str = os.path.join(ROOT, '.bench_trace')
 
 
-def make_model(cfg, seed, max_positions):
-    """The program's model class at the configuration's sizes, every leaf
-    made on the device from the seed in one jitted call."""
-    import jax
+@functools.lru_cache(maxsize=None)
+def load_module(path):
+    """A file of the benchmark found by a name in its data, loaded by path
+    and once a process: a per-layer metric's reader, a family's two files.
+    It stands in `sys.modules` under its path, so it can name itself."""
+    name = f'benchmark:{path}'
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[name] = module
+    spec.loader.exec_module(module)
+    return module
 
-    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
-    from benchmark.harness import weights
-
-    if cfg['hidden_act'] != 'silu' or cfg['sliding_window'] is not None:
-        raise SystemExit('benchmark: models/llama.py runs silu and full '
-                         'attention only')
-    if cfg['head_dim'] * cfg['num_attention_heads'] != cfg['hidden_size']:
-        raise SystemExit('benchmark: LlamaConfig derives head_dim from '
-                         'hidden_size / heads')
-    lc = LlamaConfig(
-        vocab_size=cfg['vocab_size'], hidden_size=cfg['hidden_size'],
-        intermediate_size=cfg['intermediate_size'],
-        num_hidden_layers=cfg['num_hidden_layers'],
-        num_attention_heads=cfg['num_attention_heads'],
-        num_key_value_heads=cfg['num_key_value_heads'],
-        max_position_embeddings=max_positions,
-        rms_norm_eps=cfg['rms_norm_eps'], rope_theta=cfg['rope_theta'],
-        tie_word_embeddings=cfg['tie_word_embeddings'],
-        attention_bias=cfg['attention_bias'], dtype=cfg['torch_dtype'])
-    struct = jax.eval_shape(lambda: LlamaForCausalLM(lc))
-    model, shapes = weights.fill_model(struct, seed)
-    expect = {(-1, n): s for n, (s, _) in weights.global_shapes(cfg).items()}
-    for layer in range(cfg['num_hidden_layers']):
-        expect.update({(layer, n): s for n, (s, _) in
-                       weights.layer_shapes(cfg).items()})
-    if shapes != expect:
-        odd = set(shapes.items()) ^ set(expect.items())
-        raise SystemExit(f'benchmark: the model\'s leaves are not the '
-                         f'configuration\'s: {sorted(odd)[:6]}')
-    return model
+def family(cfg):
+    """What the configuration's `family` names (`benchmark/families`'s
+    docstring has the contract): `families/<family>.py`, with the plain
+    reference of `reference/families/<family>.py` as its `reference`. A
+    configuration that names none, or one with no files, ends the run."""
+    name = cfg.get('family')
+    if name is None:
+        raise SystemExit(f'benchmark: the configuration {cfg.get("name")!r} '
+                         f'names no family')
+    paths = [os.path.join(FAMILIES, *where, f'{name}.py')
+             for where in (('families',), ('reference', 'families'))]
+    missing = [p for p in paths if not os.path.exists(p)]
+    if missing:
+        raise SystemExit(f'benchmark: the family {name!r} of the '
+                         f'configuration {cfg.get("name")!r} has no '
+                         f'{" and no ".join(missing)}')
+    module = load_module(paths[0])
+    module.reference = load_module(paths[1])
+    return module
 
 
 @contextlib.contextmanager
@@ -122,13 +121,9 @@ def read_metrics(env, ctx):
     out = {}
     for name in env.per_layer:
         spec = load('metrics', name)
-        path = os.path.join(BENCH, 'metrics', 'readers',
-                            f'{spec["reader"]}.py')
-        module_spec = importlib.util.spec_from_file_location(
-            f'benchmark_reader_{spec["reader"]}', path)
-        module = importlib.util.module_from_spec(module_spec)
-        module_spec.loader.exec_module(module)
-        value = module.read(ctx, **spec.get('args', {}))
+        reader = load_module(os.path.join(BENCH, 'metrics', 'readers',
+                                          f'{spec["reader"]}.py'))
+        value = reader.read(ctx, **spec.get('args', {}))
         if value is not None:
             out[name] = {'value': float(value), 'unit': spec['unit']}
     return out
@@ -143,7 +138,7 @@ def traced_line(env, chips, profile, peak_bytes, ctx):
 
     trace = profile.load()
     ctx = dict(ctx, trace=trace, window_s=profile.window_s, peak=env.peak,
-               chips=chips, flops=model_flops)
+               chips=chips, flops=model_flops.Work(family(ctx['cfg'])))
     return (read_metrics(env, ctx),
             device_line(env, chips, peak_bytes,
                         trace_reduce.busy_seconds(trace), profile.window_s),

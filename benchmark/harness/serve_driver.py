@@ -33,18 +33,15 @@ class Record:
         return [t for t, _, n in self.stamps for _ in range(n)]
 
 
-def build_engine(cfg, geometry, seed, make_model):
-    """The engine at the cell's geometry over weights made on the device
-    from the seed."""
+def build_engine(fam, cfg, geometry, seed):
+    """The engine at the cell's geometry, every key of it the engine's own
+    option (`tp` only over 1), over the family's model with weights made
+    on the device from the seed."""
     from paddle_tpu.inference.serving import ServingEngine
 
-    model = make_model(cfg, seed, geometry['max_context_len'])
-    kwargs = {k: geometry[k] for k in (
-        'max_slots', 'block_size', 'max_context_len', 'decode_window',
-        'max_new_tokens') if k in geometry}
-    if geometry.get('tp', 1) > 1:
-        kwargs['tp'] = geometry['tp']
-    return ServingEngine(model, **kwargs)
+    model = fam.make_model(cfg, seed, geometry['max_context_len'])
+    return ServingEngine(model, **{k: v for k, v in geometry.items()
+                                   if k != 'tp' or v > 1})
 
 
 def warm(engine, buckets):
@@ -115,24 +112,30 @@ class ClosedSource:
 def drive(engine, source, seconds, drain_limit, annotate=None,
           on_window=None):
     """Runs lead-in, window and drain. Time 0 is the window's first
-    instant. `on_window(opening: bool)` is called at its two ends.
+    instant. `on_window(opening: bool)` is called at its two ends, off the
+    clock: what it takes (the profiler's start and stop) is neither the
+    window's nor the drain's.
     Returns (records, steps [(t0, t1)], lateness [s])."""
     annotate, clock = annotate or _no_span, time.perf_counter
     origin = clock() - source.first_due()
     records, live, steps, late = [], [], [], []
     opened = closed = False
+
+    def window_end(opening):
+        """`on_window` with the clock stopped; returns the time after."""
+        nonlocal origin
+        if on_window:
+            t = clock()
+            on_window(opening)
+            origin += clock() - t
+        return clock() - origin
+
     while True:
         now = clock() - origin
         if not opened and now >= 0.0:
-            opened = True
-            if on_window:
-                on_window(True)
-                origin += (clock() - origin) - now      # its cost is set-up
-                now = clock() - origin
+            opened, now = True, window_end(True)
         if opened and not closed and now >= seconds:
-            closed = True
-            if on_window:
-                on_window(False)
+            closed, now = True, window_end(False)
         if closed and now > seconds + drain_limit:
             break
         with annotate('bench.submit'):
@@ -279,9 +282,8 @@ def run_cell(cell, cfg, traffic, env, control=False):
 
     seconds = (min(env.seconds, cell['trace_seconds']) if env.trace
                else env.seconds)
-    geometry = cell['geometry']
-    vocab = cfg['vocab_size']
-    engine = build_engine(cfg, geometry, env.seed, common.make_model)
+    fam, vocab = common.family(cfg), cfg['vocab_size']
+    engine = build_engine(fam, cfg, cell['geometry'], env.seed)
     report = warm(engine, traffic['buckets'])
     print(f'warmed {report["geometries"]} geometries in '
           f'{report["seconds"]} s ({env.compiles.misses} compiled, '
@@ -343,8 +345,8 @@ def run_cell(cell, cfg, traffic, env, control=False):
     if sample:
         t_ref = time.perf_counter()
         got = serve_ref.served_gaps(
-            cfg, env.seed, [(p, o[len(p):]) for p, o, _ in sample], pad_to,
-            control=cell['control'] if control else None)
+            fam, cfg, env.seed, [(p, o[len(p):]) for p, o, _ in sample],
+            pad_to, control=cell['control'] if control else None)
         held.hold('served_logit_gap', got['served_gap'], limit)
         wrong = sum(len(o) != len(p) + n or not np.array_equal(o[:len(p)], p)
                     for p, o, n in sample)

@@ -18,12 +18,10 @@ from benchmark.harness import loadgen
 BETA1, BETA2, EPS = 0.9, 0.999, 1e-8      # AdamW's published defaults
 
 
-def _leaf_ids(tree):
+def _leaf_ids(fam, tree):
     import jax
 
-    from benchmark.harness import weights
-
-    return {jax.tree_util.keystr(p): weights.leaf_id(jax.tree_util.keystr(p))
+    return {jax.tree_util.keystr(p): fam.leaf_id(jax.tree_util.keystr(p))
             for p, _ in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
@@ -35,7 +33,7 @@ def _host(tree, ids):
     return {ids[jax.tree_util.keystr(p)]: np.asarray(v) for p, v in flat}
 
 
-def first_steps(engine, feed, seed, n=3):
+def first_steps(fam, engine, feed, seed, n=3):
     """Steps 1..n through the window's own call and feed. Returns the
     losses, the first gradient's norm per leaf as the optimizer got it
     (its first moment after one step is (1 - beta1) g) and the norm of
@@ -45,13 +43,13 @@ def first_steps(engine, feed, seed, n=3):
 
     from benchmark.harness import weights
 
-    ids = _leaf_ids(engine.model)
+    ids = _leaf_ids(fam, engine.model)
     norms = jax.jit(lambda t: jax.tree.map(
         lambda x: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))), t))
     change = jax.jit(lambda model, base: jax.tree_util.tree_map_with_path(
         lambda p, x: jnp.sqrt(jnp.sum(jnp.square(
             x.astype(jnp.float32) - weights.make_leaf(
-                base, *ids[jax.tree_util.keystr(p)], x.shape,
+                fam, base, *ids[jax.tree_util.keystr(p)], x.shape,
                 x.dtype).astype(jnp.float32)))), model))
     dots = jax.jit(lambda m, base: jax.tree_util.tree_map_with_path(
         lambda p, x: weights.probe_dots(
@@ -104,7 +102,8 @@ def run_cell(cell, cfg, traffic, env, control=False):
     seconds = (min(env.seconds, cell['trace_seconds']) if env.trace
                else env.seconds)
     opt = cell['optimizer']
-    model = common.make_model(cfg, env.seed, traffic['seq'])
+    fam = common.family(cfg)
+    model = fam.make_model(cfg, env.seed, traffic['seq'])
     engine = TrainEngine(
         model, AdamW(learning_rate=opt['learning_rate'],
                      weight_decay=opt['weight_decay'], beta1=BETA1,
@@ -120,7 +119,7 @@ def run_cell(cell, cfg, traffic, env, control=False):
             yield batch
 
     feed = iter(engine.prefetch(host_batches()))
-    got = first_steps(engine, feed, env.seed)
+    got = first_steps(fam, engine, feed, env.seed)
     for _ in range(cell['warm_steps']):
         engine.step((next(feed),))
     engine.sync()
@@ -170,7 +169,7 @@ def run_cell(cell, cfg, traffic, env, control=False):
     common.free_device()
     hp = (opt['learning_rate'], opt['weight_decay'], BETA1, BETA2, EPS)
     t_ref = time.perf_counter()
-    ref = train_ref.run(cfg, env.seed, kept, hp)
+    ref = train_ref.run(fam, cfg, env.seed, kept, hp)
     numbers = train_ref.compare(got, ref)
     held = verdict.Verdict()
     for name, limit in cell['limits'].items():
@@ -187,10 +186,10 @@ def run_cell(cell, cfg, traffic, env, control=False):
         # reference's own steps at the lower precision, or broken, held to
         # the cell's limits as the program's are
         readings = {
-            name: train_ref.compare(train_ref.run(cfg, env.seed, kept, hp,
-                                                  **how), ref)
+            name: train_ref.compare(train_ref.run(fam, cfg, env.seed, kept,
+                                                  hp, **how), ref)
             for name, how in [(cell['control'], {'quant': cell['control']})]
-            + [(f, {'fault': f}) for f in train_ref.faults(cfg)]}
+            + [(f, {'fault': f}) for f in fam.reference.faults(cfg)]}
         line['control'] = verdict.judged(readings, cell['limits'])
         line['leaves'] = {'program': numbers['leaves'],
                           **{n: r['leaves'] for n, r in readings.items()}}

@@ -1,14 +1,12 @@
-"""The decoder both configurations share, in plain `jax.numpy` float32.
+"""The library a family's plain reference is written with, in `jax.numpy`
+float32: `linear`, `rms_norm`, `rope`, and the static form of a
+configuration. The layers themselves are the families'
+(`benchmark/reference/families/`).
 
-Mistral-7B-v0.3 and Qwen2.5-3B as their model cards and `config.json`
-describe them: token embedding; per layer RMSNorm, grouped-query attention
-with rotate-half RoPE (biases on q, k, v where `attention_bias`), residual,
-RMSNorm, SwiGLU, residual; final RMSNorm; a head that is its own matrix or
-the embedding transposed. No cache, no pages, no kernels, no batching
-tricks. `quant` switches on the control of "How correct is decided": every
-linear layer's inputs and weights are rounded to int8 or to fp8 (e4m3),
-scaled per token and per output channel, before the product: the step
-below bfloat16 that would tempt a later PR.
+`quant` switches on the control of "How correct is decided": every linear
+layer's inputs and weights are rounded to int8 or to fp8 (e4m3), scaled
+per token and per output channel, before the product: the step below
+bfloat16 that would tempt a later PR.
 """
 from __future__ import annotations
 
@@ -65,49 +63,28 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def layer_forward(cfg, lp, x, quant=None):
-    """x (B, S, hidden) float32 -> the same, through one decoder layer."""
-    b, s, _ = x.shape
-    nq, nkv, d = (cfg['num_attention_heads'], cfg['num_key_value_heads'],
-                  cfg['head_dim'])
-    h = rms_norm(x, lp['input_layernorm.weight'], cfg['rms_norm_eps'])
-    q = linear(h, lp['self_attn.q_proj'], quant, lp.get('self_attn.q_bias'))
-    k = linear(h, lp['self_attn.k_proj'], quant, lp.get('self_attn.k_bias'))
-    v = linear(h, lp['self_attn.v_proj'], quant, lp.get('self_attn.v_bias'))
-    q = rope(q.reshape(b, s, nq, d), cfg['rope_theta'])
-    k = rope(k.reshape(b, s, nkv, d), cfg['rope_theta'])
-    v = v.reshape(b, s, nkv, d)
-    q = q.reshape(b, s, nkv, nq // nkv, d)
-    scores = jnp.einsum('bsngd,btnd->bngst', q, k,
-                        precision=HIGHEST) / (d ** 0.5)
-    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
-    probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), -1)
-    att = jnp.einsum('bngst,btnd->bsngd', probs, v, precision=HIGHEST)
-    x = x + linear(att.reshape(b, s, nq * d), lp['self_attn.o_proj'], quant)
-    h = rms_norm(x, lp['post_attention_layernorm.weight'],
-                 cfg['rms_norm_eps'])
-    gate = linear(h, lp['mlp.gate_proj'], quant)
-    up = linear(h, lp['mlp.up_proj'], quant)
-    return x + linear(jax.nn.silu(gate) * up, lp['mlp.down_proj'], quant)
-
-
-def embed(gp, ids):
-    return gp['embed_tokens'].astype(jnp.float32)[ids]
-
-
-def logits(cfg, gp, x, quant=None):
-    h = rms_norm(x, gp['norm.weight'], cfg['rms_norm_eps'])
-    w = (gp['embed_tokens'].T if cfg['tie_word_embeddings']
-         else gp['lm_head'])
-    return linear(h, w, quant)
-
-
 def frozen(cfg):
-    """The configuration as a hashable static argument."""
-    return tuple(sorted((k, v) for k, v in cfg.items()
-                        if isinstance(v, (int, float, bool, str, type(None)))))
+    """The configuration as a hashable static argument, lists and nested
+    groups (`layer_types`, `rope_scaling`) with it; `thawed` gives it
+    back."""
+    if isinstance(cfg, dict):
+        return ('dict', tuple(sorted((k, frozen(v)) for k, v in cfg.items())))
+    if isinstance(cfg, (list, tuple)):
+        return ('list', tuple(frozen(v) for v in cfg))
+    return cfg
 
 
-@functools.partial(jax.jit, static_argnames=('cfg_items', 'quant'))
-def layer_step(lp, x, *, cfg_items, quant=None):
-    return layer_forward(dict(cfg_items), lp, x, quant)
+def thawed(items):
+    if isinstance(items, tuple):
+        kind, values = items
+        return ({k: thawed(v) for k, v in values} if kind == 'dict'
+                else [thawed(v) for v in values])
+    return items
+
+
+@functools.partial(jax.jit, static_argnames=('forward', 'cfg_items', 'layer',
+                                             'quant'))
+def layer_step(lp, x, *, forward, cfg_items, layer, quant=None):
+    """A family's `layer_forward`, compiled once for each kind of layer
+    (`layer` is what the family's `layer_like` gives)."""
+    return forward(thawed(cfg_items), lp, x, layer, quant)

@@ -18,11 +18,10 @@ from benchmark.harness import weights
 from benchmark.reference import decoder
 
 
-@functools.partial(jax.jit, static_argnames=('cfg_items', 'quant'))
-def _read(gp, x, where, *, cfg_items, quant):
-    cfg = dict(cfg_items)
+@functools.partial(jax.jit, static_argnames=('logits', 'cfg_items', 'quant'))
+def _read(gp, x, where, *, logits, cfg_items, quant):
     rows = jnp.take_along_axis(x, where[:, :, None], axis=1)   # (R, O, H)
-    return decoder.logits(cfg, gp, rows, quant)
+    return logits(decoder.thawed(cfg_items), gp, rows, quant)
 
 
 @jax.jit
@@ -32,19 +31,25 @@ def _gaps(ref_logits, tokens):
     return best - got
 
 
-def _forward(cfg, seed, ids, where, quant):
-    items, base = decoder.frozen(cfg), weights.base_key(seed)
-    make_layer = jax.jit(lambda b, l: weights.make_layer(b, cfg, l))
-    gp = jax.jit(lambda b: weights.make_globals(b, cfg))(base)
-    x = decoder.embed(gp, ids)
+def _forward(fam, cfg, seed, ids, where, quant):
+    ref, items = fam.reference, decoder.frozen(cfg)
+    base = weights.base_key(seed)
+    make_layer = jax.jit(lambda b, l, like: weights.make_layer(
+        fam, b, cfg, l, like), static_argnums=2)
+    gp = jax.jit(lambda b: weights.make_globals(fam, b, cfg))(base)
+    x = ref.embed(gp, ids)
     for layer in range(cfg['num_hidden_layers']):
-        x = decoder.layer_step(make_layer(base, layer), x,
-                               cfg_items=items, quant=quant)
-    return _read(gp, x, where, cfg_items=items, quant=quant)
+        like = fam.layer_like(cfg, layer)
+        x = decoder.layer_step(make_layer(base, layer, like), x,
+                               forward=ref.layer_forward, cfg_items=items,
+                               layer=like, quant=quant)
+    return _read(gp, x, where, logits=ref.logits, cfg_items=items,
+                 quant=quant)
 
 
-def served_gaps(cfg, seed, requests, pad_to, control=None):
-    """`requests`: [(prompt ids, served output ids)]. Returns the widest
+def served_gaps(fam, cfg, seed, requests, pad_to, control=None):
+    """`fam`: the configuration's family (`common.family`); `requests`:
+    [(prompt ids, served output ids)]. Returns the widest
     gap of a served token, and with `control` ('int8', 'fp8') also the
     widest gap of the control's own first choices at the same positions."""
     n_out = max(len(o) for _, o in requests)
@@ -63,13 +68,14 @@ def served_gaps(cfg, seed, requests, pad_to, control=None):
         toks[r, :len(out)] = out
         real[r, :len(out)] = True
     with jax.default_matmul_precision('highest'):
-        ref = _forward(cfg, seed, jnp.asarray(ids), jnp.asarray(where), None)
+        ref = _forward(fam, cfg, seed, jnp.asarray(ids), jnp.asarray(where),
+                       None)
         served = np.asarray(_gaps(ref, jnp.asarray(toks)))
         result = {'served_gap': float(served[real].max()),
                   'served_tokens': int(real.sum())}
         if control:
-            low = _forward(cfg, seed, jnp.asarray(ids), jnp.asarray(where),
-                           control)
+            low = _forward(fam, cfg, seed, jnp.asarray(ids),
+                           jnp.asarray(where), control)
             first = jnp.argmax(low, -1).astype(jnp.int32)
             result['control_gap'] = float(
                 np.asarray(_gaps(ref, first))[real].max())

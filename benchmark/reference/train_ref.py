@@ -1,13 +1,15 @@
-"""Three AdamW steps of the decoder in plain float32, layer by layer so
-that parameters, two moments and one layer's gradient fit one chip.
+"""Three AdamW steps of a family's reference in plain float32, layer by
+layer so that parameters, two moments and one layer's gradient fit one
+chip.
 
 Loss is the mean next-token cross-entropy over all positions. AdamW as the
 paper and Paddle's `adamw` state it: decoupled decay `p -= lr * wd * p`,
 bias-corrected moments, `p -= lr * mhat / (sqrt(vhat) + eps)`, on every
 leaf. `fault` plants what a broken step would do (`half_batch`: the second
 half of the tokens left out, the mean taken over the rest; `frozen`: a
-step that returns its state unchanged; `no_bias_grad`: the q, k and v
-biases' gradients dropped); `quant` runs the control.
+step that returns its state unchanged; `no_bias_grad`: the gradients of
+the leaves named `*_bias` dropped); the family's `faults(cfg)` says which
+of them a configuration can have. `quant` runs the control.
 """
 from __future__ import annotations
 
@@ -21,28 +23,22 @@ from benchmark.harness import weights
 from benchmark.reference import decoder
 
 
-def faults(cfg):
-    """The planted faults a configuration can have."""
-    return ('half_batch', 'frozen') + (
-        ('no_bias_grad',) if cfg['attention_bias'] else ())
-
-
-@functools.partial(jax.jit, static_argnames=('cfg_items', 'quant'))
-def _layer_back(lp, x, dy, *, cfg_items, quant):
-    cfg = dict(cfg_items)
-    _, vjp = jax.vjp(lambda p, h: decoder.layer_forward(cfg, p, h, quant),
-                     lp, x)
+@functools.partial(jax.jit, static_argnames=('forward', 'cfg_items', 'layer',
+                                             'quant'))
+def _layer_back(lp, x, dy, *, forward, cfg_items, layer, quant):
+    cfg = decoder.thawed(cfg_items)
+    _, vjp = jax.vjp(lambda p, h: forward(cfg, p, h, layer, quant), lp, x)
     return vjp(dy)
 
 
-@functools.partial(jax.jit, static_argnames=('cfg_items', 'quant'))
-def _head_block(gp, x, labels, weight, *, cfg_items, quant):
+@functools.partial(jax.jit, static_argnames=('logits', 'cfg_items', 'quant'))
+def _head_block(gp, x, labels, weight, *, logits, cfg_items, quant):
     """Weighted sum of the block's token losses and its gradients with
     respect to the globals and the block's hidden rows."""
-    cfg = dict(cfg_items)
+    cfg = decoder.thawed(cfg_items)
 
     def loss(gp, x):
-        z = decoder.logits(cfg, gp, x, quant)
+        z = logits(cfg, gp, x, quant)
         nll = jax.nn.logsumexp(z, -1) - jnp.take_along_axis(
             z, labels[..., None], -1)[..., 0]
         return jnp.sum(nll * weight)
@@ -78,20 +74,27 @@ def _probes(tree, base, layer):
             for k, g in tree.items()}
 
 
-@jax.jit
-def _embed_back(ids, dx, table_grad):
-    return table_grad.at[ids].add(dx)
+@functools.partial(jax.jit, static_argnames='embed', donate_argnums=3)
+def _embed_back(gp, ids, dx, g_gp, *, embed):
+    """`g_gp` and what `dx` sends back through the family's `embed`."""
+    _, vjp = jax.vjp(lambda g: embed(g, ids), gp)
+    return jax.tree.map(jnp.add, g_gp, vjp(dx)[0])
 
 
-def run(cfg, seed, batches, hp, fault=None, quant=None, row_block=1024):
-    """`batches`: the first steps' (batch, seq + 1) id arrays. `hp`: (lr,
-    weight decay, beta1, beta2, eps). Returns losses, the first
-    gradient's norm per leaf and the norm of each leaf's change."""
-    items, base = decoder.frozen(cfg), weights.base_key(seed)
+def run(fam, cfg, seed, batches, hp, fault=None, quant=None, row_block=1024):
+    """`fam`: the configuration's family (`common.family`). `batches`: the
+    first steps' (batch, seq + 1) id arrays. `hp`: (lr, weight decay,
+    beta1, beta2, eps). Returns losses, the first gradient's norm per leaf
+    and the norm of each leaf's change."""
+    ref, items = fam.reference, decoder.frozen(cfg)
+    base = weights.base_key(seed)
     f32 = lambda t: jax.tree.map(lambda a: a.astype(jnp.float32), t)  # noqa: E731
-    make_layer = jax.jit(lambda b, l: f32(weights.make_layer(b, cfg, l)))
-    make_globals = jax.jit(lambda b: f32(weights.make_globals(b, cfg)))
     n_layers = cfg['num_hidden_layers']
+    likes = [fam.layer_like(cfg, l) for l in range(n_layers)]
+    make = jax.jit(lambda b, l, like: f32(weights.make_layer(
+        fam, b, cfg, l, like)), static_argnums=2)
+    make_layer = lambda b, l: make(b, l, likes[l])                  # noqa: E731
+    make_globals = jax.jit(lambda b: f32(weights.make_globals(fam, b, cfg)))
     zeros = lambda t: jax.tree.map(jnp.zeros_like, t)               # noqa: E731
     with jax.default_matmul_precision('highest'):
         gp = make_globals(base)
@@ -110,18 +113,20 @@ def run(cfg, seed, batches, hp, fault=None, quant=None, row_block=1024):
                 weight[n // 2:] = 0.0
                 weight[:n // 2] = 2.0 / n
                 weight = weight.reshape(b, s)
-            elif fault not in (None, *faults(cfg)):
+            elif fault not in (None, *ref.faults(cfg)):
                 raise ValueError(f'unknown fault {fault!r}')
-            xs = [decoder.embed(gp, ids)]
-            for lp in lps:
-                xs.append(decoder.layer_step(lp, xs[-1], cfg_items=items,
-                                             quant=quant))
+            xs = [ref.embed(gp, ids)]
+            for lp, like in zip(lps, likes):
+                xs.append(decoder.layer_step(
+                    lp, xs[-1], forward=ref.layer_forward, cfg_items=items,
+                    layer=like, quant=quant))
             loss, g_gp, dx = 0.0, zeros(gp), []
             for r0 in range(0, s, row_block):
                 sl = slice(r0, r0 + row_block)
                 part, (g_part, dx_part) = _head_block(
                     gp, xs[-1][:, sl], labels[:, sl],
-                    jnp.asarray(weight[:, sl]), cfg_items=items, quant=quant)
+                    jnp.asarray(weight[:, sl]), logits=ref.logits,
+                    cfg_items=items, quant=quant)
                 loss += float(part)
                 g_gp = jax.tree.map(jnp.add, g_gp, g_part)
                 dx.append(dx_part)
@@ -129,8 +134,9 @@ def run(cfg, seed, batches, hp, fault=None, quant=None, row_block=1024):
             losses.append(loss)
             tt = jnp.float32(t)
             for l in reversed(range(n_layers)):
-                g_lp, dx = _layer_back(lps[l], xs[l], dx, cfg_items=items,
-                                       quant=quant)
+                g_lp, dx = _layer_back(
+                    lps[l], xs[l], dx, forward=ref.layer_forward,
+                    cfg_items=items, layer=likes[l], quant=quant)
                 if fault == 'no_bias_grad':
                     g_lp = {k: jnp.zeros_like(g) if k.endswith('_bias')
                             else g for k, g in g_lp.items()}
@@ -143,7 +149,7 @@ def run(cfg, seed, batches, hp, fault=None, quant=None, row_block=1024):
                     lps[l], lms[l], lvs[l] = _adamw(lps[l], g_lp, lms[l],
                                                     lvs[l], tt, hp)
                 xs[l + 1] = None
-            g_gp['embed_tokens'] = _embed_back(ids, dx, g_gp['embed_tokens'])
+            g_gp = _embed_back(gp, ids, dx, g_gp, embed=ref.embed)
             if t == 1:
                 grad_norm.update({(-1, k): float(v) for k, v in
                                   _norms(g_gp).items()})

@@ -9,7 +9,9 @@ the comparison with the plain reference that decides `correct`. The last
 line of standard output is the result. Which driver runs, at which sizes,
 under which traffic and with which per-layer metrics is data:
 `benchmark/workloads/<cell>.json`, the configuration and the traffic mix it
-names, the entries of `BENCHMARK.json`, and `benchmark/metrics/<metric>.json`.
+names, the family's files that the configuration names
+(`benchmark/families`), the entries of `BENCHMARK.json`, and
+`benchmark/metrics/<metric>.json`.
 
 Exits non-zero with no result line when jax finds no TPU or fewer chips
 than the cell asks for, when the chip's kind has no published peak, or
@@ -54,8 +56,9 @@ def load_cell(bench, name):
     cell = common.load('workloads', name)
     cell['chips'] = entry['chips']
     cell['end_to_end'], per_layer = cell_metrics(bench, name)
-    return (cell, common.load('configs', entry['config']),
-            common.load('traffic', entry['traffic']), per_layer)
+    cfg = common.load('configs', entry['config'])
+    common.family(cfg)          # ends the run here where it has no files
+    return cell, cfg, common.load('traffic', entry['traffic']), per_layer
 
 
 def execute(cell, cfg, traffic, env, **kwargs):

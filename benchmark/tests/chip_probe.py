@@ -83,8 +83,8 @@ def main():
         from paddle_tpu.aot import geometry
 
         from benchmark.harness import serve_driver
-        engine = serve_driver.build_engine(cfg, cell['geometry'], args.seed,
-                                           common.make_model)
+        engine = serve_driver.build_engine(
+            common.family(cfg), cfg, cell['geometry'], args.seed)
         serve_driver.warm(engine, traffic['buckets'])
         table = []
         for g in geometry.for_serving_engine(

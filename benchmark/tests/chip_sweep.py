@@ -37,8 +37,8 @@ def main():
     seeds = [int(x) for x in args.seeds.split(',')]
     cell, cfg, traffic, _ = bench_run.open_run(
         args.workload, seeds[0], args.seconds, False, time.perf_counter())
-    engine = serve_driver.build_engine(cfg, cell['geometry'], seeds[0],
-                                       common.make_model)
+    engine = serve_driver.build_engine(
+        common.family(cfg), cfg, cell['geometry'], seeds[0])
     serve_driver.warm(engine, traffic['buckets'])
     runs = [(float(r), int(c), x) for r in args.rates.split(',')
             for c in args.schedules.split(',') for x in seeds]

@@ -13,6 +13,7 @@ import pytest
 import tiny
 
 from benchmark import run as bench_run
+from benchmark.harness import common
 
 # wide enough that rounding flips first choices; still seconds on a CPU.
 # At this size int8 reads only twice what bfloat16 does, so the test's
@@ -83,14 +84,18 @@ def test_a_step_that_returns_its_state_unchanged_is_not_correct(monkeypatch):
 
 
 def test_half_of_the_batch_left_out_is_not_correct(monkeypatch):
-    from paddle_tpu.models.llama import LlamaForCausalLM
+    fam = common.family(tiny.TINY_TRAIN_CFG)
+    make_model = fam.make_model
 
-    loss = LlamaForCausalLM.loss
+    def halved(cfg, seed, max_positions):
+        model = make_model(cfg, seed, max_positions)
+        loss = type(model).loss
+        monkeypatch.setattr(
+            type(model), 'loss', lambda self, input_ids, labels=None: loss(
+                self, input_ids[:, :input_ids.shape[1] // 2 + 1]))
+        return model
 
-    def half(self, input_ids, labels=None):
-        return loss(self, input_ids[:, :input_ids.shape[1] // 2 + 1])
-
-    monkeypatch.setattr(LlamaForCausalLM, 'loss', half)
+    monkeypatch.setattr(fam, 'make_model', halved)
     out = _train(seed=4)
     assert out['correct'] is False
     gap = out['compared']['grad_norm_gap']

@@ -208,3 +208,30 @@ def test_traced_run_reads_the_cells_own_metrics(name, monkeypatch):
     assert out['device']['busy_s'] > 0 and out['device']['window_s'] == 0.46
     assert set(out['breakdown']) == {'device_ops', 'idle_gaps'}
     assert all(len(n) < 120 for n, _ in out['breakdown']['device_ops'])
+
+
+def test_the_windows_two_ends_are_off_the_clock():
+    """What `on_window` takes (the profiler's start and stop: longer than
+    the drain limit in a traced chat run) is neither the window's nor the
+    drain's: the requests live at the close still finish and none counts
+    as failed."""
+    import time
+
+    from benchmark.harness import serve_driver
+
+    geometry = dict(tiny.SERVE_CELL['geometry'], max_new_tokens=9)
+    engine = serve_driver.build_engine(
+        common.family(tiny.TINY_SERVE_CFG), tiny.TINY_SERVE_CFG, geometry, 3)
+    serve_driver.warm(engine, tiny.CLOSED['buckets'])
+    seconds, drain = 1.0, 0.5
+    source = serve_driver.ClosedSource(
+        loadgen.closed_loop(tiny.CLOSED, 512, 3), tiny.CLOSED['clients'],
+        tiny.CLOSED['lead_in_s'], seconds)
+    records, steps, _ = serve_driver.drive(
+        engine, source, seconds, drain,
+        on_window=lambda opening: time.sleep(3 * drain))
+    e2e = serve_driver.end_to_end(
+        records, serve_driver.collect(engine, records), seconds, drain)
+    assert e2e['attempted'] > 0 and e2e['failed'] == 0
+    assert any(r.done > seconds for r in records if r.plan.measured)
+    assert steps[-1][1] < seconds + drain

@@ -2,7 +2,7 @@
 import time
 
 TINY_SERVE_CFG = {
-    'hidden_size': 64, 'intermediate_size': 128, 'num_attention_heads': 4,
+    'name': 'tiny', 'family': 'llama', 'hidden_size': 64, 'intermediate_size': 128, 'num_attention_heads': 4,
     'num_key_value_heads': 2, 'head_dim': 16, 'num_hidden_layers': 2,
     'vocab_size': 512, 'rope_theta': 1e6, 'rms_norm_eps': 1e-5,
     'tie_word_embeddings': False, 'attention_bias': False,
