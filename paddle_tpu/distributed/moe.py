@@ -13,6 +13,8 @@ shard_map — or plain GSPMD sharding of the expert axis under pjit
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -35,6 +37,22 @@ def _topk_gates(logits, k: int):
     ce = jax.nn.one_hot(expert_idx[:, 0], E).mean(axis=0)
     aux_loss = E * jnp.sum(me * ce)
     return probs, gate_vals, expert_idx, aux_loss
+
+
+def sigmoid_topk_gates(logits, bias, k: int, route_norm=True,
+                       route_scale=1.0):
+    """Sigmoid routing with a selection-only bias (the aux-loss-free
+    balancing of DeepSeek-V3 and AFMoE): scores s = sigmoid(logits) in
+    float32; the k experts are CHOSEN by s + bias and WEIGHED by s alone,
+    normalised over the chosen when `route_norm`, times `route_scale`.
+    logits (T, E), bias (E,). Returns (gate_vals (T, k) f32, expert_idx
+    (T, k))."""
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, expert_idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    gate_vals = jnp.take_along_axis(s, expert_idx, axis=-1)
+    if route_norm:
+        gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-20)
+    return gate_vals * route_scale, expert_idx
 
 
 def limit_by_capacity(topk_idx, num_expert, capacity):
@@ -78,7 +96,7 @@ def top_k_gating(logits, k: int, capacity: int, jitter_key=None):
 
 
 def ragged_expert_apply(tokens, expert_idx, gate_vals, w_gate, w_up, w_down,
-                        num_experts, act=F.silu):
+                        num_experts, act=F.silu, expert_offset=None):
     """Dropless expert compute: sort tokens by expert, run grouped GEMMs.
 
     ref: the reference's large-E MoE path (incubate/.../moe global_scatter
@@ -89,22 +107,45 @@ def ragged_expert_apply(tokens, expert_idx, gate_vals, w_gate, w_up, w_down,
     shape for E >= ~16 (DeepSeek-style).
 
     tokens (T, H); expert_idx/gate_vals (T, k). Returns (T, H).
+
+    With `expert_offset` the weights are ONE RANK'S SHARE of a wider
+    router: experts [expert_offset, expert_offset + num_experts) are
+    held here, `expert_idx` still counts over the router's whole width,
+    and the pairs routed elsewhere are dropped before the sort (they
+    take a last, empty-weighted group and add nothing). The products
+    then accumulate and combine in float32; the result is this rank's
+    part of the sum, in float32.
     """
     T, H = tokens.shape
     k = expert_idx.shape[1]
     flat_e = expert_idx.reshape(-1).astype(jnp.int32)         # (T·k,)
     flat_g = gate_vals.reshape(-1)
+    held = None
+    if expert_offset is not None:
+        flat_e = flat_e - expert_offset
+        held = (flat_e >= 0) & (flat_e < num_experts)
+        flat_e = jnp.where(held, flat_e, num_experts)         # sorts last
     order = jnp.argsort(flat_e, stable=True)
     tok_ids = order // k                                      # source token
     x = jnp.take(tokens, tok_ids, axis=0)                     # (T·k, H)
-    group_sizes = jnp.bincount(flat_e, length=num_experts).astype(jnp.int32)
+    group_sizes = jnp.bincount(
+        flat_e, length=num_experts + (held is not None))[:num_experts]
+    group_sizes = group_sizes.astype(jnp.int32)
     w_gate = _dense_expert(w_gate, x.dtype)
     w_up = _dense_expert(w_up, x.dtype)
     w_down = _dense_expert(w_down, x.dtype)
-    h = act(jax.lax.ragged_dot(x, w_gate, group_sizes))
-    h = h * jax.lax.ragged_dot(x, w_up, group_sizes)
-    y = jax.lax.ragged_dot(h, w_down, group_sizes)            # (T·k, H)
+    acc = None if held is None else jnp.float32
+    h = act(jax.lax.ragged_dot(x, w_gate, group_sizes,
+                               preferred_element_type=acc))
+    h = h * jax.lax.ragged_dot(x, w_up, group_sizes,
+                               preferred_element_type=acc)
+    y = jax.lax.ragged_dot(h.astype(x.dtype), w_down, group_sizes,
+                           preferred_element_type=acc)        # (T·k, H)
     y = y * jnp.take(flat_g, order)[:, None].astype(y.dtype)
+    if held is not None:
+        # rows past the held groups are whatever the grouped product
+        # left there: they are dropped, not weighed
+        y = jnp.where(jnp.take(held, order)[:, None], y, 0.0)
     return jnp.zeros((T, H), y.dtype).at[tok_ids].add(y)
 
 
@@ -384,3 +425,132 @@ class MoELayer(Layer):
         if self.return_aux:
             return out, aux
         return out
+
+
+# ---------------------------------------------------------------------------
+# One rank's share of an expert layer (expert-parallel serving)
+# ---------------------------------------------------------------------------
+
+ROUTING_FIELDS = ('picks_total', 'picks_local', 'experts_hit', 'load_max',
+                  'load_mean', 'layer_steps')
+_COUNTING = []          # the open `routing_counts()` collectors, innermost last
+
+
+class RoutingCounts:
+    """What the expert layers traced inside one `routing_counts()` block
+    routed, summed over those layers in ROUTING_FIELDS' order (float32):
+    (token, choice) pairs in all, those that chose an expert held here,
+    held experts with at least one pick, the fullest held expert's picks,
+    the mean over the held, and the layers counted. Only `rows` count."""
+
+    def __init__(self, rows):
+        self.rows = rows
+        self.layers = []
+
+    def total(self):
+        """(len(ROUTING_FIELDS),) float32, or None where no expert layer
+        ran inside the block."""
+        return sum(self.layers[1:], self.layers[0]) if self.layers else None
+
+
+@contextlib.contextmanager
+def routing_counts(rows=None):
+    """Collect the routing counts of every `ExpertShare` traced inside
+    the block. `rows` (bool, broadcastable to the layer's (B, S)) says
+    which tokens count (a serving batch carries frozen and empty rows).
+    The counts are values of the trace the block runs in: read
+    `.total()` inside that trace."""
+    counts = RoutingCounts(rows)
+    _COUNTING.append(counts)
+    try:
+        yield counts
+    finally:
+        _COUNTING.remove(counts)
+
+
+class ExpertShare(Layer):
+    """One rank's share of a sparse expert layer, as an expert-parallel
+    deployment holds it: the router over all `num_experts`, the
+    `experts_held` experts from `expert_offset` on, and the shared
+    expert whole. Routing is sigmoid top-k with a selection-only bias
+    (`sigmoid_topk_gates`) over the router's whole width; the output is
+    shared(x) plus the chosen experts' parts THAT ARE HELD HERE, each
+    with its weight normalised over all the chosen. The other ranks'
+    parts and the exchange that would add them are not here. With
+    `experts_held == num_experts` it is the whole layer.
+
+    x (B, S, H) -> (B, S, H). Expert compute is dropless
+    (`ragged_expert_apply`); the router's product runs in float32."""
+
+    no_quantize = ('router', 'expert_bias')
+    SCOPE = 'expert_share'      # the jax.named_scope its ops run under
+
+    def __init__(self, hidden, intermediate, num_experts, top_k,
+                 experts_held=None, expert_offset=0, shared_intermediate=0,
+                 route_norm=True, route_scale=1.0, dtype='float32',
+                 activation=F.silu):
+        super().__init__()
+        held = num_experts if experts_held is None else int(experts_held)
+        if not 0 <= expert_offset <= num_experts - held:
+            raise ValueError(
+                f'experts [{expert_offset}, {expert_offset + held}) are not '
+                f'among the router\'s {num_experts}')
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.experts_held, self.expert_offset = held, int(expert_offset)
+        self.route_norm, self.route_scale = bool(route_norm), float(route_scale)
+        self.act = activation
+        init = I.Normal(0.0, 0.02)
+        self.router = Parameter(init((hidden, num_experts), 'float32'))
+        self.expert_bias = Parameter(jnp.zeros((num_experts,), jnp.float32))
+        self.w_gate = Parameter(init((held, hidden, intermediate), dtype))
+        self.w_up = Parameter(init((held, hidden, intermediate), dtype))
+        self.w_down = Parameter(init((held, intermediate, hidden), dtype))
+        if shared_intermediate:
+            self.shared_gate = Parameter(
+                init((hidden, shared_intermediate), dtype))
+            self.shared_up = Parameter(
+                init((hidden, shared_intermediate), dtype))
+            self.shared_down = Parameter(
+                init((shared_intermediate, hidden), dtype))
+        else:
+            self.shared_gate = self.shared_up = self.shared_down = None
+
+    def route(self, tokens):
+        """(gate_vals (T, k) f32, expert_idx (T, k)) over all experts."""
+        logits = jnp.matmul(tokens.astype(jnp.float32), self.router,
+                            precision=jax.lax.Precision.HIGHEST)
+        return sigmoid_topk_gates(logits, self.expert_bias, self.top_k,
+                                  self.route_norm, self.route_scale)
+
+    def _count(self, expert_idx, shape):
+        rows = _COUNTING[-1].rows
+        rows = (jnp.ones(shape, bool) if rows is None
+                else jnp.broadcast_to(rows, shape)).reshape(-1, 1)
+        local = expert_idx - self.expert_offset
+        held = rows & (local >= 0) & (local < self.experts_held)
+        load = jnp.zeros((self.experts_held,), jnp.float32).at[
+            jnp.where(held, local, self.experts_held).reshape(-1)].add(
+                1.0, mode='drop')
+        local_picks = load.sum()
+        _COUNTING[-1].layers.append(jnp.stack([
+            rows.sum().astype(jnp.float32) * self.top_k, local_picks,
+            (load > 0).sum().astype(jnp.float32), load.max(),
+            local_picks / self.experts_held,
+            rows.any().astype(jnp.float32)]))
+
+    def forward(self, x):
+        B, S, H = x.shape
+        with jax.named_scope(self.SCOPE):
+            tokens = x.reshape(B * S, H)
+            gate_vals, expert_idx = self.route(tokens)
+            if _COUNTING:
+                self._count(expert_idx, (B, S))
+            out = ragged_expert_apply(
+                tokens, expert_idx, gate_vals, self.w_gate, self.w_up,
+                self.w_down, self.experts_held, act=self.act,
+                expert_offset=self.expert_offset)
+            if self.shared_gate is not None:
+                hid = (self.act(tokens @ self.shared_gate)
+                       * (tokens @ self.shared_up))
+                out = out + (hid @ self.shared_down).astype(out.dtype)
+            return out.reshape(B, S, H).astype(x.dtype)

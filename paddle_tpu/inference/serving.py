@@ -119,6 +119,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..distributed.moe import ROUTING_FIELDS
 from ..observability import journal as _journal
 from ..observability import metrics as _obs
 from ..observability import timeseries as _obs_ts
@@ -909,8 +910,13 @@ def _window_body(model, pages, last_logits, btab, ctx, live, budget,
     static-shape forward but write only to their frozen position / the
     scratch page and commit nothing — exactly how requests leave the
     batch without changing a traced shape. Returns (tokens (SLOTS,
-    window), last_logits, pages, ctx); the host reads the tokens ONCE
-    per window and does all bookkeeping there."""
+    window), last_logits, pages, ctx, routed); the host reads the tokens
+    ONCE per window and does all bookkeeping there. `routed` is what the
+    model's expert layers routed for the committing rows over the window
+    (`distributed.moe.ROUTING_FIELDS`, summed over layers and steps), or
+    None for a model with no such layer: it rides the same host read."""
+    from ..distributed.moe import routing_counts
+
     pad_tok = eos_token_id if eos_token_id is not None else 0
     plen = jnp.asarray(plen, jnp.int32)
 
@@ -931,15 +937,20 @@ def _window_body(model, pages, last_logits, btab, ctx, live, budget,
         commit = ~frozen
         if eos_token_id is not None:
             finished = finished | (commit & (tok == eos_token_id))
-        logits, pages = model(tok[:, None], caches=pages,
-                              kv_write_pos=ctx, block_tables=btab)
+        with routing_counts(rows=commit[:, None]) as routed:
+            logits, pages = model(tok[:, None], caches=pages,
+                                  kv_write_pos=ctx, block_tables=btab)
         ctx = ctx + commit.astype(jnp.int32)
-        return (logits[:, -1, :], pages, ctx, finished), tok
+        return (logits[:, -1, :], pages, ctx, finished), (tok,
+                                                          routed.total())
 
     state = (last_logits, pages, jnp.asarray(ctx, jnp.int32), ~live)
-    (last_logits, pages, ctx, _), toks = jax.lax.scan(
+    (last_logits, pages, ctx, _), (toks, routed) = jax.lax.scan(
         step, state, jnp.arange(window, dtype=jnp.int32))
-    return _pin(toks.T), _pin(last_logits), _pin_pages(pages), _pin(ctx)
+    if routed is not None:
+        routed = _pin(routed.sum(0))
+    return (_pin(toks.T), _pin(last_logits), _pin_pages(pages), _pin(ctx),
+            routed)
 
 
 @functools.partial(jax.jit, donate_argnames=('pages', 'last_logits'))
@@ -1348,7 +1359,11 @@ class ServingEngine:
     Greedy outputs per request are exactly `DecodeEngine.generate`'s
     batch-1 outputs (eos-padded to max_new_tokens, prompt echoed back).
     The model must accept `block_tables` in its cached forward (the
-    Llama family does) and must not use sliding-window attention.
+    Llama family and AFMoE do). Sliding-window layers, alone or mixed
+    with full ones, keep every page allocated: the kernel skips the
+    pages behind a window, the allocator does not free them. A model's
+    `distributed.moe.ExpertShare` layers report what they routed with
+    each window's one host read (`serve.routing`).
     """
 
     def __init__(self, model, max_slots=8, block_size=16, num_blocks=None,
@@ -1392,8 +1407,10 @@ class ServingEngine:
         if 'block_tables' not in params:
             raise NotImplementedError(
                 f'{type(model).__name__} lacks block_tables in its '
-                f'cached forward: paged serving needs the Llama-family '
-                f'cached_attention; use DecodeEngine for this model')
+                f'cached forward: paged serving needs a decoder whose '
+                f'layers go through models.llama.cached_attention '
+                f'(LlamaForCausalLM, AfmoeForCausalLM); use DecodeEngine '
+                f'for this model')
         # speculative serving (docs/serving.md#speculative-serving):
         # draft != None turns every non-chunk scheduler iteration into
         # a propose/verify window — the DecodeEngine's fused
@@ -1452,11 +1469,6 @@ class ServingEngine:
                 f"phase_role must be 'monolithic', 'prefill', or "
                 f"'decode', got {phase_role!r}")
         self.phase_role = phase_role
-        if getattr(getattr(model, 'config', None), 'sliding_window',
-                   None) is not None:
-            raise NotImplementedError(
-                'sliding-window models are not paged-servable yet: the '
-                'paged kernel has no window fast path — use DecodeEngine')
         # tensor-parallel serving (docs/serving.md#tp-sharded-serving):
         # the engine owns ONE mesh whose only >1 axis is 'tp'. Device
         # state shards kv-heads over it (page pools, via
@@ -2150,13 +2162,13 @@ class ServingEngine:
                 ids, real_len, btabs, slots = self._prefill_args(
                     p['bucket'], [])
                 self._note('serve_step', W, p['bucket'])
-                _, self._last_logits, self._pages, _ = _serve_step(
+                _, self._last_logits, self._pages, _, _ = _serve_step(
                     self.model, self._pages, self._last_logits, ids,
                     real_len, btabs, slots, dev['btab'], dev['ctx'],
                     dev['live'], budget, *sample_args, **common)
             elif g.kind == 'serve_window':
                 self._note('serve_window', W)
-                _, self._last_logits, self._pages, _ = _serve_window(
+                _, self._last_logits, self._pages, _, _ = _serve_window(
                     self.model, self._pages, self._last_logits,
                     dev['btab'], dev['ctx'], dev['live'], budget,
                     *sample_args, **common)
@@ -2189,7 +2201,7 @@ class ServingEngine:
                         self.draft, self._dpages, self._dlogits, ids,
                         z, z, btabs, slots, z, z, ctx_bucket=Sb)
                     self._warm_draft_catchup(Sb, z, btabs)
-                _, self._last_logits, self._pages, _ = _serve_chunk_step(
+                _, self._last_logits, self._pages, _, _ = _serve_chunk_step(
                     self.model, self._pages, self._last_logits, ids, z,
                     z, btabs, slots, z, z, dev['btab'], dev['ctx'],
                     dev['live'], budget, *sample_args, z, zb,
@@ -3798,7 +3810,7 @@ class ServingEngine:
                 self._update_gauges()
                 step_span.set(kind='idle')
                 return []
-        spec_out = None
+        spec_out = routed = None
         t_dispatch = time.perf_counter()
         if spec:
             k = self.spec_window
@@ -3894,7 +3906,7 @@ class ServingEngine:
                 ftok_d, forced_d = self._zero_ftok, self._zero_forced
             with dispatch(Cb, [t for _s, _r, _p, t in chunk_rows],
                           self.max_slots):
-                toks, self._last_logits, self._pages, ctx_out = \
+                toks, self._last_logits, self._pages, ctx_out, routed = \
                     _serve_chunk_step(
                         self.model, self._pages, self._last_logits, ids,
                         clen, cst, btabs, slots, cow_src, cow_dst,
@@ -3922,7 +3934,8 @@ class ServingEngine:
             hit = self._note('serve_step', W, Sb)
             dispatch_key = ('serve_step', W, Sb)
             with dispatch(Sb, [r.context_len for _s, r in group]):
-                toks, self._last_logits, self._pages, ctx_out = _serve_step(
+                (toks, self._last_logits, self._pages, ctx_out,
+                 routed) = _serve_step(
                     self.model, self._pages, self._last_logits, ids,
                     real_len, btabs, slots, dev['btab'], dev['ctx'],
                     dev['live'], budget, *sample_args, **common)
@@ -3933,7 +3946,7 @@ class ServingEngine:
             hit = self._note('serve_window', W)
             dispatch_key = ('serve_window', W)
             with dispatch():
-                toks, self._last_logits, self._pages, ctx_out = \
+                toks, self._last_logits, self._pages, ctx_out, routed = \
                     _serve_window(
                         self.model, self._pages, self._last_logits,
                         dev['btab'], dev['ctx'], dev['live'], budget,
@@ -3953,7 +3966,13 @@ class ServingEngine:
                                        np.asarray(nc_h), np.asarray(nxt_h))
                 tokens = None
             else:
-                tokens = np.asarray(jax.device_get(toks))
+                tokens, routed = jax.device_get((toks, routed))
+                tokens = np.asarray(tokens)
+        if routed is not None:
+            # what the window's expert layers routed, beside its dispatch
+            _obs_trace.instant(
+                'serve.routing', cat='scheduler', kind=kind,
+                **dict(zip(ROUTING_FIELDS, map(float, routed))))
         t_commit = time.perf_counter()
         commit = _obs_trace.span('serve.commit', cat='scheduler').begin()
         step_tokens = 0

@@ -376,7 +376,9 @@ def _paged_cached_attention(q, k, v, cache, kv_write_pos, block_tables,
                             window, kvalid, kv_start):
     """Single-token decode over a PagedKVCache: scatter the new row
     into its page, then attend over the row's pages masked by the
-    per-row valid length (kv_write_pos + 1). See cached_attention."""
+    per-row valid length (kv_write_pos + 1) and, with `window`, to its
+    last `window` positions (pages behind them stay allocated; the
+    kernel skips them). See cached_attention."""
     B, S, H, D = q.shape
     if kvalid is not None or kv_start is not None:
         # these are masking CONTRACTS on the other branches — dropping
@@ -397,11 +399,6 @@ def _paged_cached_attention(q, k, v, cache, kv_write_pos, block_tables,
         raise ValueError(
             'PagedKVCache needs kv_write_pos (per-row write positions) '
             'and block_tables (per-row page ids)')
-    if window is not None:
-        raise NotImplementedError(
-            'sliding-window attention over a paged cache is not '
-            'supported: serve SWA models through the contiguous '
-            'DecodeEngine path')
     from .generation import (QuantPagedKVCache, dequantize_kv_row,
                              quantize_kv_row)
 
@@ -453,7 +450,7 @@ def _paged_cached_attention(q, k, v, cache, kv_write_pos, block_tables,
                 return paged_decode_attention(
                     q_, kp_, vp_, tbl_, counts_,
                     k_scale=scales[0] if scales else None,
-                    v_scale=scales[1] if scales else None)
+                    v_scale=scales[1] if scales else None, window=window)
 
             # pools split their kv-head dim over tp (init_paged_cache's
             # placement); tables and lengths follow the batch
@@ -475,9 +472,12 @@ def _paged_cached_attention(q, k, v, cache, kv_write_pos, block_tables,
             gv = dequantize_kv_row(gv, vss[tbl], q.dtype)
         ck = jnp.swapaxes(gk, 2, 3).reshape(B, maxb * BS, Hkv, D)
         cv = jnp.swapaxes(gv, 2, 3).reshape(B, maxb * BS, Hkv, D)
-        mask = (jnp.arange(maxb * BS)[None, :]
-                < counts[:, None])[:, None, None, :]
-        out = F.scaled_dot_product_attention(q, ck, cv, attn_mask=mask)
+        kpos = jnp.arange(maxb * BS)[None, :]
+        mask = kpos < counts[:, None]
+        if window is not None:
+            mask = mask & (kpos >= counts[:, None] - window)
+        out = F.scaled_dot_product_attention(
+            q, ck, cv, attn_mask=mask[:, None, None, :])
     return out, new_cache
 
 

@@ -32,10 +32,13 @@ def _interpret():
 
 
 def _body(cl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc, m_scr,
-          l_scr, *, scale, nb, bs, hkv, group, rowscale=False):
+          l_scr, *, scale, nb, bs, hkv, group, rowscale=False, st_ref=None):
     """Shared head-major online-softmax pass. Column order: the (hkv, bs,
     D) block flattens to c = h*bs + s, so head(c) = c // bs and
-    position(c) = j*bs + c % bs."""
+    position(c) = j*bs + c % bs. With `st_ref` (a sliding window) row b
+    attends positions [st_ref[b], count) only, and a page that lies
+    wholly before the window's start is skipped: no compute here, and no
+    copy either (its index map names the first page inside the window)."""
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -45,49 +48,62 @@ def _body(cl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc, m_scr,
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    hq = group * hkv
-    cols = hkv * bs
-    D = q_ref.shape[-1]
-    q = q_ref[0, 0].astype(jnp.float32)                 # (Hq, D)
-    k = k_ref[0].astype(jnp.float32)                    # (hkv, bs, D)
-    v = v_ref[0].astype(jnp.float32)
-    if ks_ref is not None:
-        # int8 dequant rides the (hkv, bs, D) layout BEFORE the
-        # major-dim collapse (the Mosaic-proven pattern). Two scale
-        # layouts: (Hkv, D) global per-(head, dim) calibration
-        # (QuantKVCache), or (1, Hkv, BS) PER-ROW scales riding the
-        # page itself (QuantPagedKVCache — each token row carries its
-        # own amax, so quantization is write-order independent)
-        if rowscale:
-            k = k * ks_ref[0][:, :, None]
-            v = v * vs_ref[0][:, :, None]
+    def page():
+        hq = group * hkv
+        cols = hkv * bs
+        D = q_ref.shape[-1]
+        q = q_ref[0, 0].astype(jnp.float32)                 # (Hq, D)
+        k = k_ref[0].astype(jnp.float32)                    # (hkv, bs, D)
+        v = v_ref[0].astype(jnp.float32)
+        if ks_ref is not None:
+            # int8 dequant rides the (hkv, bs, D) layout BEFORE the
+            # major-dim collapse (the Mosaic-proven pattern). Two scale
+            # layouts: (Hkv, D) global per-(head, dim) calibration
+            # (QuantKVCache), or (1, Hkv, BS) PER-ROW scales riding the
+            # page itself (QuantPagedKVCache — each token row carries its
+            # own amax, so quantization is write-order independent)
+            if rowscale:
+                k = k * ks_ref[0][:, :, None]
+                v = v * vs_ref[0][:, :, None]
+            else:
+                k = k * ks_ref[...][:, None, :]
+                v = v * vs_ref[...][:, None, :]
+        k = k.reshape(cols, D)
+        v = v.reshape(cols, D)
+
+        count = cl_ref[b]
+        vpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (cols, D), 0) % bs
+        rowh = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 0) // group
+        colh = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 1) // bs
+        colp = j * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (hq, cols), 1) % bs
+        if st_ref is None:
+            v = jnp.where(vpos < count, v, 0.0)
+            keep = (rowh == colh) & (colp < count)
         else:
-            k = k * ks_ref[...][:, None, :]
-            v = v * vs_ref[...][:, None, :]
-    k = k.reshape(cols, D)
-    v = v.reshape(cols, D)
+            start = st_ref[b]
+            v = jnp.where((vpos < count) & (vpos >= start), v, 0.0)
+            keep = (rowh == colh) & (colp < count) & (colp >= start)
 
-    count = cl_ref[b]
-    vpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (cols, D), 0) % bs
-    v = jnp.where(vpos < count, v, 0.0)
-    rowh = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 0) // group
-    colh = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 1) // bs
-    colp = j * bs + jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 1) % bs
-    keep = (rowh == colh) & (colp < count)
+        s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(keep, s, NEG_INF)                     # (Hq, cols)
 
-    s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (Hq, cols)
-    s = jnp.where(keep, s, NEG_INF)
+        m_prev = m_scr[:, 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, 0] * alpha + jnp.sum(p, axis=-1)
+        acc[:] = acc[:] * alpha[:, None] + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
 
-    m_prev = m_scr[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_scr[:, 0] * alpha + jnp.sum(p, axis=-1)
-    acc[:] = acc[:] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+    if st_ref is None:
+        page()
+    else:
+        pl.when((j + 1) * bs > st_ref[b])(page)
 
     @pl.when(j == nb - 1)
     def _():
@@ -105,6 +121,18 @@ def _kernel_q8(cl_ref, tbl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
                acc, m_scr, l_scr, **kw):
     _body(cl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc, m_scr,
           l_scr, **kw)
+
+
+def _kernel_win(cl_ref, tbl_ref, st_ref, q_ref, k_ref, v_ref, o_ref, acc,
+                m_scr, l_scr, **kw):
+    _body(cl_ref, q_ref, k_ref, v_ref, None, None, o_ref, acc, m_scr,
+          l_scr, st_ref=st_ref, **kw)
+
+
+def _kernel_win_q8(cl_ref, tbl_ref, st_ref, q_ref, k_ref, v_ref, ks_ref,
+                   vs_ref, o_ref, acc, m_scr, l_scr, **kw):
+    _body(cl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc, m_scr,
+          l_scr, st_ref=st_ref, **kw)
 
 
 def _kernel_hm(cl_ref, q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr, **kw):
@@ -139,7 +167,7 @@ def _run(kernel, grid, in_specs, out_spec, args, out_sd, interp):
 
 def paged_decode_attention(q, key_cache, value_cache, block_tables,
                            context_lens, scale=None, k_scale=None,
-                           v_scale=None):
+                           v_scale=None, window=None):
     """One fused paged decode step.
 
     q: (B, 1, Hq, D); key_cache/value_cache: (NB, Hkv, BS, D) pages;
@@ -150,7 +178,10 @@ def paged_decode_attention(q, key_cache, value_cache, block_tables,
     per-(head, dim) calibration (QuantKVCache), or (NB, Hkv, BS) f32
     PER-ROW scales riding page-shaped pools (QuantPagedKVCache — the
     scale block is prefetched by the same block-table index map as its
-    page). Returns (B, 1, Hq, D).
+    page). `window` (static int): the query, at position
+    context_lens - 1, attends the last `window` positions only; pages
+    wholly behind them are neither copied nor computed (they stay
+    allocated: the table is the caller's). Returns (B, 1, Hq, D).
     """
     B, Sq, Hq, D = q.shape
     if Sq != 1:
@@ -169,37 +200,47 @@ def paged_decode_attention(q, key_cache, value_cache, block_tables,
         jnp.reshape(jnp.asarray(context_lens, jnp.int32), (-1,)), (B,)),
         nb * BS)
 
+    # scalar-prefetched: lengths, the table and, under a window, each
+    # row's first attended position. The prefetched block table IS the
+    # page index: grid step (b, j) DMAs page block_tables[b, j], or the
+    # window's first page while j is still behind it (the same block
+    # again, so nothing is copied)
+    prefetch = [cl, tbl]
+    if window is not None:
+        prefetch.append(jnp.maximum(cl - int(window), 0))
+
+    def page(b, j, cl, tbl, *st):
+        return tbl[b, jnp.maximum(j, st[0][b] // BS) if st else j]
+
+    def whole(b, j, *_):
+        return (b, 0, 0, 0)
+
     quant = k_scale is not None
     rowscale = quant and k_scale.ndim == 3
     in_specs = [
-        pl.BlockSpec((1, 1, Hq, D), lambda b, j, cl, tbl: (b, 0, 0, 0)),
-        # the prefetched block table IS the page index: grid step (b, j)
-        # DMAs page block_tables[b, j]
-        pl.BlockSpec((1, Hkv, BS, D),
-                     lambda b, j, cl, tbl: (tbl[b, j], 0, 0, 0)),
-        pl.BlockSpec((1, Hkv, BS, D),
-                     lambda b, j, cl, tbl: (tbl[b, j], 0, 0, 0)),
+        pl.BlockSpec((1, 1, Hq, D), whole),
+        pl.BlockSpec((1, Hkv, BS, D), lambda *a: (page(*a), 0, 0, 0)),
+        pl.BlockSpec((1, Hkv, BS, D), lambda *a: (page(*a), 0, 0, 0)),
     ]
-    args = [cl, tbl, q, key_cache, value_cache]
+    args = prefetch + [q, key_cache, value_cache]
     kw = dict(scale=scale, nb=nb, bs=BS, hkv=Hkv, group=group,
               rowscale=rowscale)
     if quant:
-        kernel = functools.partial(_kernel_q8, **kw)
+        kernel = _kernel_q8 if window is None else _kernel_win_q8
         if rowscale:
             # per-row scales live in page-shaped (NB, Hkv, BS) pools:
             # the scale block for grid step (b, j) is the same
             # prefetched page the K/V blocks DMA
             in_specs += [pl.BlockSpec(
-                (1, Hkv, BS), lambda b, j, cl, tbl: (tbl[b, j], 0, 0))] * 2
+                (1, Hkv, BS), lambda *a: (page(*a), 0, 0))] * 2
         else:
-            in_specs += [pl.BlockSpec((Hkv, D),
-                                      lambda b, j, cl, tbl: (0, 0))] * 2
+            in_specs += [pl.BlockSpec((Hkv, D), lambda *a: (0, 0))] * 2
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     else:
-        kernel = functools.partial(_kernel, **kw)
+        kernel = _kernel if window is None else _kernel_win
     return _run(
-        kernel, (2, (B, nb)), in_specs,
-        pl.BlockSpec((1, 1, Hq, D), lambda b, j, cl, tbl: (b, 0, 0, 0)),
+        functools.partial(kernel, **kw), (len(prefetch), (B, nb)), in_specs,
+        pl.BlockSpec((1, 1, Hq, D), whole),
         args, jax.ShapeDtypeStruct((B, 1, Hq, D), q.dtype), _interpret())
 
 

@@ -19,9 +19,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _block_rows(n_feat: int, n_rows: int) -> int:
     """Rows per block sized so the working set (~6 fp32 row-buffers:
-    x, g, gw, out + copies) stays well under the 16MB VMEM budget."""
+    x, g, gw, out + copies) stays well under the 16MB VMEM budget; a
+    multiple of 8, which Mosaic asks of a block that is not the whole
+    array (a feature dim of 3072 gave 170)."""
     target = (2 * 1024 * 1024) // max(4 * n_feat, 1)   # ~2MB per buffer
-    rows = max(8, min(256, target))
+    rows = max(8, min(256, target) // 8 * 8)
     return min(rows, n_rows)
 
 

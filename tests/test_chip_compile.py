@@ -154,6 +154,35 @@ def test_paged_decode_attention(chip_compile, cache_dtype):
     assert 'tpu_custom_call' in text
 
 
+@pytest.mark.parametrize('slots,ctx', [(64, 1024), (4, 8192)])
+def test_paged_decode_attention_windowed(chip_compile, slots, ctx):
+    """AFMoE's window layers at Trinity's widths (48 query over 8 kv heads,
+    a window of 4096): the benchmark cell's geometry and the
+    window-crossing one."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    maxb = ctx // PAGE
+    nb = slots * maxb + 1
+    text = chip_compile(
+        lambda q, k, v, t, n: paged_decode_attention(q, k, v, t, n,
+                                                     window=4096),
+        ((slots, 1, 48, HEAD_DIM), jnp.bfloat16),
+        ((nb, 8, PAGE, HEAD_DIM), jnp.bfloat16),
+        ((nb, 8, PAGE, HEAD_DIM), jnp.bfloat16),
+        ((slots, maxb), jnp.int32), ((slots,), jnp.int32))
+    assert 'tpu_custom_call' in text
+
+
+def test_rms_norm_at_a_width_that_is_no_power_of_two(chip_compile):
+    """3072 features: 2 MB of rows is 170 of them, and a block of rows has
+    to be a multiple of 8."""
+    from paddle_tpu.ops.pallas.rms_norm import rms_norm
+
+    text = chip_compile(rms_norm, ((1024, 3072), jnp.bfloat16),
+                        ((3072,), jnp.float32))
+    assert 'tpu_custom_call' in text
+
+
 def test_decode_attention_headmajor(chip_compile):
     from paddle_tpu.ops.pallas.paged_attention import (
         decode_attention_headmajor)

@@ -55,6 +55,39 @@ class TestPagedKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize('window', [5, 32, 40, 64, 500])
+    def test_window_matches_gather_reference(self, window):
+        """Only the last `window` positions count, whether the window
+        starts inside the first page, on a page's edge, or before the
+        row began; pages behind it are skipped."""
+        rng = np.random.default_rng(1)
+        B, NB, Hkv, BS, D, Hq, MAXB = 3, 16, 2, 32, 16, 4, 4
+        q = jnp.asarray(rng.normal(size=(B, 1, Hq, D)), jnp.float32)
+        kc = jnp.asarray(rng.normal(size=(NB, Hkv, BS, D)), jnp.float32)
+        vc = jnp.asarray(rng.normal(size=(NB, Hkv, BS, D)), jnp.float32)
+        tbl = jnp.asarray([[3, 7, 1, 12], [0, 5, 9, 2], [14, 6, -1, -1]],
+                          jnp.int32)
+        counts = jnp.asarray([100, 128, 40], jnp.int32)
+        got = paged_decode_attention(q, kc, vc, tbl, counts, window=window)
+        # the reference sees a window as keys before it zeroed out of the
+        # softmax: mask by moving the start
+        pos = jnp.arange(MAXB * BS)[None, :]
+        seen = (pos < counts[:, None]) & (pos >= counts[:, None] - window)
+        ck = kc[np.clip(np.asarray(tbl), 0, NB - 1)]
+        cv = vc[np.clip(np.asarray(tbl), 0, NB - 1)]
+        ck = jnp.swapaxes(ck, 2, 3).reshape(B, MAXB * BS, Hkv, D)
+        cv = jnp.swapaxes(cv, 2, 3).reshape(B, MAXB * BS, Hkv, D)
+        ck, cv = (jnp.repeat(x, Hq // Hkv, axis=2) for x in (ck, cv))
+        logits = jnp.einsum('bhd,bshd->bhs', q[:, 0], ck) / (D ** 0.5)
+        p = jax.nn.softmax(jnp.where(seen[:, None], logits, -1e30), -1)
+        want = jnp.einsum('bhs,bshd->bhd', p, cv)[:, None]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+        if window >= 128:
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(paged_decode_attention(
+                    q, kc, vc, tbl, counts)), rtol=1e-6, atol=1e-6)
+
     def test_int8_pages_dequantize(self):
         from paddle_tpu.models.generation import (calibrate_kv_scale,
                                                   quantize_kv_rows)

@@ -51,6 +51,14 @@ def _model():
                                        layers=2))
 
 
+def _model_like(cfg):
+    """The same seeded weights under full attention."""
+    import dataclasses
+
+    pt.seed(2)
+    return LlamaForCausalLM(dataclasses.replace(cfg, sliding_window=None))
+
+
 def _prompt(seed, n, lo=3, hi=96):
     return np.random.default_rng(seed).integers(lo, hi, (n,)).astype(np.int32)
 
@@ -365,10 +373,25 @@ class TestGuards:
         with pytest.raises(NotImplementedError, match='block_tables'):
             ServingEngine(NoPages())
 
-    def test_sliding_window_model_rejected(self):
+    def test_sliding_window_model_is_served(self):
+        """A window smaller than the context through the paged path:
+        greedy tokens are those of the model's own uncached forward."""
         pt.seed(2)
-        cfg = llama_tiny()
+        cfg = llama_tiny(vocab_size=96, hidden_size=64, layers=2)
         cfg.sliding_window = 8
         swa = LlamaForCausalLM(cfg)
-        with pytest.raises(NotImplementedError, match='sliding-window'):
-            ServingEngine(swa)
+        srv = ServingEngine(swa, max_slots=2, block_size=4,
+                            max_context_len=48, max_new_tokens=12,
+                            decode_window=4, buckets=(32,))
+        prompts = [_prompt(0, 21), _prompt(1, 13)]
+        outs = srv.serve(prompts)
+        for p, out in zip(prompts, outs):
+            out = np.asarray(out)
+            logits = np.asarray(swa(jnp.asarray(out[None, :-1])))[0]
+            np.testing.assert_array_equal(
+                logits.argmax(-1)[len(p) - 1:], out[len(p):])
+        full = ServingEngine(_model_like(cfg), max_slots=2, block_size=4,
+                             max_context_len=48, max_new_tokens=12,
+                             decode_window=4, buckets=(32,))
+        assert any(not np.array_equal(a, b)
+                   for a, b in zip(outs, full.serve(prompts)))
