@@ -104,6 +104,8 @@ class ScratchInfo:
     memory_space: str            # 'vmem' | 'smem' | ...
 
     def nbytes(self):
+        if self.memory_space == 'semaphore_mem':
+            return 0
         return math.prod(self.shape) * self.dtype.itemsize
 
 
@@ -184,6 +186,12 @@ def _normalize(eqn):
     blocks = []
     kinds = (['input'] * gm.num_inputs) + (['output'] * gm.num_outputs)
     for kind, bm in zip(kinds, gm.block_mappings):
+        if str(getattr(bm.block_aval, 'memory_space', None)) in ('any',
+                                                                 'hbm'):
+            # left in HBM (the paged kernel's pools): no block, no
+            # pipeline; what the kernel copies of it lands in scratch,
+            # which is counted there
+            continue
         blocks.append(BlockInfo(
             kind=kind,
             origin=str(bm.origin or ''),

@@ -143,9 +143,9 @@ def _build_paged(quant=False):
 
 def _build_paged_rowscale():
     """The QuantPagedKVCache variant: int8 pages whose PER-ROW scales
-    ride in page-shaped (NB, Hkv, BS) pools, the scale block prefetched
-    by the same block-table index map as its page — the serving
-    engine's kv_cache_dtype='int8' decode dispatch."""
+    ride in page-shaped (NB, Hkv, BS) pools, a page's scales fetched
+    with it by the same block-table entry — the serving engine's
+    kv_cache_dtype='int8' decode dispatch."""
     def build():
         from paddle_tpu.ops.pallas.paged_attention import (
             paged_decode_attention)
@@ -194,6 +194,21 @@ def _build_paged_serving(quant=False):
         return (paged_decode_attention, (q, cache, cache, tbl, lens), {})
 
     return build
+
+
+def _build_paged_window():
+    """AFMoE's window layers at Trinity's widths and the benchmark
+    cell's geometry: 64 slots, group 6 (48 query over 8 kv heads), a
+    64-entry table, window 4096."""
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention)
+
+    slots, Hkv, D, Hq, BS, maxb = 64, 8, 128, 48, 16, 64
+    cache = _sds((slots * maxb + 1, Hkv, BS, D), 'bfloat16')
+    return (lambda q, k, v, t, c: paged_decode_attention(
+                q, k, v, t, c, window=4096),
+            (_sds((slots, 1, Hq, D), 'bfloat16'), cache, cache,
+             _sds((slots, maxb), 'int32'), _sds((slots,), 'int32')), {})
 
 
 def _build_headmajor():
@@ -381,6 +396,45 @@ def _onchip_serve_decode():
     assert np.isfinite(out.astype(np.float32)).all()
 
 
+def _onchip_serve_decode_window():
+    """Rows of very different lengths behind a window of 100 positions,
+    two idle slots among them, against the gathered pages in float32:
+    the loop's bounds, the chunks in flight and the masks, on the chip."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    rng = np.random.default_rng(0)
+    slots, Hkv, BS, D, Hq, maxb, window = 8, 8, 16, 128, 48, 64, 100
+    NB = slots * maxb + 1
+    q = jnp.asarray(rng.normal(size=(slots, 1, Hq, D)), jnp.bfloat16)
+    kc = jnp.asarray(rng.normal(size=(NB, Hkv, BS, D)), jnp.bfloat16)
+    vc = jnp.asarray(rng.normal(size=(NB, Hkv, BS, D)), jnp.bfloat16)
+    tbl = jnp.asarray(rng.permutation(np.arange(1, NB)).reshape(slots, maxb),
+                      jnp.int32)
+    lens = jnp.asarray([1, 1024, 0, 513, 512, 17, 700, 128], jnp.int32)
+    for win in (None, window):
+        got = np.asarray(paged_decode_attention(q, kc, vc, tbl, lens,
+                                                window=win), np.float32)
+        k = jnp.swapaxes(kc[tbl], 2, 3).reshape(slots, maxb * BS, Hkv, D)
+        v = jnp.swapaxes(vc[tbl], 2, 3).reshape(slots, maxb * BS, Hkv, D)
+        qg = q[:, 0].astype(jnp.float32).reshape(slots, Hkv, Hq // Hkv, D)
+        s = jnp.einsum('bhgd,bshd->bhgs', qg, k.astype(jnp.float32),
+                       precision='highest') / D ** 0.5
+        pos = jnp.arange(maxb * BS)[None]
+        seen = (pos < lens[:, None]) & (
+            pos >= lens[:, None] - (win or maxb * BS))
+        p = jnp.where(seen[:, None, None], jax.nn.softmax(
+            jnp.where(seen[:, None, None], s, -1e30), -1), 0.0)
+        want = np.asarray(jnp.einsum(
+            'bhgs,bshd->bhgd', p, v.astype(jnp.float32),
+            precision='highest')).reshape(got.shape)
+        assert np.isfinite(got).all()
+        assert np.abs(got - want).max() < 2e-2, np.abs(got - want).max()
+
+
 def _onchip_headmajor():
     import jax.numpy as jnp
     import numpy as np
@@ -461,6 +515,8 @@ ENTRIES = (
           _build_paged_serving(quant=True)),
     Entry('paged_attention/serve_decode_int8_rowscale', _PAGED,
           _build_paged_rowscale()),
+    Entry('paged_attention/serve_decode_window', _PAGED, _build_paged_window,
+          onchip=_onchip_serve_decode_window),
     Entry('paged_attention/headmajor', _HEADMAJOR, _build_headmajor,
           onchip=_onchip_headmajor),
     Entry('quant_matmul/int8', _QMM, _build_quant_matmul('int8')),

@@ -209,6 +209,8 @@ _SERVING = ClassDecl(
                                    'continues past the snapshot'),
         'max_blocks_per_seq': derived(note='computed from '
                                            'max_context_len/block_size'),
+        '_layer_windows': derived(note="read off the model's attention "
+                                       'layers at construction'),
         # -- device-resident, re-derived by AOT attach / re-prefill ---
         '_pages': device(note='paged KV pool; re-prefill reconstructs'),
         '_dpages': device(note='draft KV pool'),

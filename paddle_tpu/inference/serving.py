@@ -106,6 +106,7 @@ uninterrupted run. Failure paths are exercised on purpose through the
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import hashlib
@@ -1675,6 +1676,11 @@ class ServingEngine:
         self._btab = np.zeros((self.max_slots, self.max_blocks_per_seq),
                               np.int32)
         self._ctx = np.zeros((self.max_slots,), np.int32)
+        # (window, layers) per distinct attention window, 0 = the whole
+        # context: what `_kernel_pages` counts the paged kernel's work by
+        self._layer_windows = sorted(collections.Counter(
+            m.sliding_window or 0 for m in model.sublayers()
+            if hasattr(m, 'sliding_window')).items())
         self._budget = np.zeros((self.max_slots,), np.int32)
         # per-slot sampling params — DATA, not statics (the traced
         # bodies take them as (SLOTS,) device args): a mixed
@@ -3740,8 +3746,7 @@ class ServingEngine:
             self._update_gauges()
             step_span.set(kind='idle')
             return []
-        live = sum(r is not None and self._pfill[i] is None
-                   for i, r in enumerate(self._slot_req))
+        live = sum(self._live_rows())
 
         def stage():
             # the host-to-device uploads a dispatch waits for
@@ -3752,12 +3757,13 @@ class ServingEngine:
             # the fill of its fused admission: zeros for a bare window
             return _obs_trace.span(
                 'serve.dispatch', cat='scheduler', kind=kind, live=live,
-                slots=self.max_slots,
+                slots=self.max_slots, **kernel_pages,
                 **self._fill(bucket, real_lens, padded_rows))
 
         with stage():
             dev = self._device_state()
             budget = self._put(self._budget)    # shrinks every window
+            kernel_pages = self._kernel_pages()
         common = dict(window=W, eos_token_id=self.eos_token_id)
         spec = self.draft is not None and not chunk_rows
         kind = ('spec' if spec else 'chunk' if chunk_rows
@@ -4205,8 +4211,7 @@ class ServingEngine:
         filling."""
         if self._dev is None:
             btab, ctx = self._btab, self._ctx
-            live = [r is not None and self._pfill[i] is None
-                    for i, r in enumerate(self._slot_req)]
+            live = self._live_rows()
             if any(p is not None for p in self._pfill):
                 btab = btab.copy()
                 ctx = ctx.copy()
@@ -4473,6 +4478,31 @@ class ServingEngine:
             d = self._dummy_slots[rows] = self._put(
                 np.full((rows,), self.max_slots, np.int32))
         return d
+
+    def _live_rows(self):
+        """Per slot, whether its row decodes in the window: it holds a
+        request and is not mid chunked prefill."""
+        return [r is not None and self._pfill[i] is None
+                for i, r in enumerate(self._slot_req)]
+
+    def _kernel_pages(self):
+        """The paged kernel's work in one token-step, as `serve.dispatch`
+        reports it: `pages_needed`, the pages its calls (one an attention
+        layer) walk at the live rows' contexts — ceil(ctx / block_size)
+        less the pages wholly behind the layer's window — beside
+        `pages_table`, the table entries those calls are handed. Their
+        ratio is what the kernel's time follows, and how far
+        `max_context_len` is over-provisioned."""
+        ctx = self._ctx[self._live_rows()].astype(np.int64)
+        bs = self.block_size
+        last = -(-ctx // bs)
+        needed = sum(
+            n * int((last - (np.maximum(ctx - w, 0) // bs if w else 0)).sum())
+            for w, n in self._layer_windows)
+        layers = sum(n for _, n in self._layer_windows)
+        return dict(pages_needed=needed,
+                    pages_table=layers * self.max_slots
+                    * self.max_blocks_per_seq)
 
     def _fill(self, bucket, real_lens, padded_rows=None):
         """How full one fixed-width prefill batch is, as `serve.dispatch`

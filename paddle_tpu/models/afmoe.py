@@ -109,7 +109,7 @@ class AfmoeAttention(Layer):
     def __init__(self, config: AfmoeConfig, layer_idx: int):
         super().__init__()
         self.sliding = config.layer_types[layer_idx] == SLIDING
-        self.window = config.sliding_window if self.sliding else None
+        self.sliding_window = config.sliding_window if self.sliding else None
         self.num_heads = config.num_attention_heads
         self.num_kv_heads = config.num_key_value_heads
         self.head_dim = config.head_dim
@@ -141,13 +141,13 @@ class AfmoeAttention(Layer):
             q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
         if cache is None:
             out = F.scaled_dot_product_attention(
-                q, k, v, is_causal=True, window_size=self.window)
+                q, k, v, is_causal=True, window_size=self.sliding_window)
             new_cache = None
         else:
             out, new_cache = cached_attention(
                 q, k, v, cache, cache_index, kvalid=kvalid,
                 kv_start=kv_start, kv_write_pos=kv_write_pos,
-                window=self.window, block_tables=block_tables)
+                window=self.sliding_window, block_tables=block_tables)
         out = out.reshape(B, S, self.num_heads * self.head_dim)
         return _gated(out, gate) @ self.o_proj, new_cache
 

@@ -91,7 +91,7 @@ def rope_on_the_full_layer(model):
 
 def window_off_by_one(model):
     for layer in model.layers[:2]:
-        layer.self_attn.window += 1
+        layer.self_attn.sliding_window += 1
 
 
 def no_mup_scale(model):

@@ -130,46 +130,59 @@ def test_decode_attention(chip_compile, cache_dtype, kv_heads):
     assert 'tpu_custom_call' in text
 
 
-@pytest.mark.parametrize('cache_dtype', [jnp.bfloat16, jnp.int8])
-def test_paged_decode_attention(chip_compile, cache_dtype):
-    """The ServingEngine decode dispatch's kernel: full-coverage pool plus
-    the scratch page, default page size; int8 pools carry per-row scales
-    in page-shaped pools (QuantPagedKVCache)."""
+def _paged_specs(slots, heads, kv_heads, ctx, cache_dtype, page=PAGE):
+    """A full-coverage pool plus the scratch page, as ServingEngine sizes
+    it: q, the two pools, the block table, the lengths."""
+    maxb = ctx // page
+    nb = slots * maxb + 1
+    pool = ((nb, kv_heads, page, HEAD_DIM), cache_dtype)
+    return [((slots, 1, heads, HEAD_DIM), jnp.bfloat16), pool, pool,
+            ((slots, maxb), jnp.int32), ((slots,), jnp.int32)]
+
+
+@pytest.mark.parametrize('slots,heads,kv_heads,ctx', [
+    (SLOTS, HEADS, HEADS, CTX),         # Llama-2-7B, the engine's defaults
+    (16, 32, 8, 2048),                  # the Mistral cells: group 4
+    (16, 8, 2, 2048),                   # one of their tp=4 shards
+])
+def test_paged_decode_attention(chip_compile, slots, heads, kv_heads, ctx):
+    """The ServingEngine decode dispatch's kernel, default page size."""
     from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
 
-    maxb = CTX // PAGE
-    nb = SLOTS * maxb + 1
-    q = ((SLOTS, 1, HEADS, HEAD_DIM), jnp.bfloat16)
-    pool = ((nb, HEADS, PAGE, HEAD_DIM), cache_dtype)
-    tbl = ((SLOTS, maxb), jnp.int32)
-    lens = ((SLOTS,), jnp.int32)
-    if cache_dtype == jnp.int8:
-        sc = ((nb, HEADS, PAGE), jnp.float32)
-        text = chip_compile(
-            lambda q, k, v, t, n, ks, vs: paged_decode_attention(
-                q, k, v, t, n, k_scale=ks, v_scale=vs),
-            q, pool, pool, tbl, lens, sc, sc)
-    else:
-        text = chip_compile(paged_decode_attention, q, pool, pool, tbl, lens)
+    text = chip_compile(paged_decode_attention, *_paged_specs(
+        slots, heads, kv_heads, ctx, jnp.bfloat16))
+    assert 'tpu_custom_call' in text
+
+
+@pytest.mark.parametrize('rowscale', [True, False])
+@pytest.mark.parametrize('kv_heads,page', [(HEADS, PAGE), (8, 32)])
+def test_paged_decode_attention_int8(chip_compile, kv_heads, page, rowscale):
+    """int8 pools in both scale layouts: per-row scales in page-shaped
+    pools (QuantPagedKVCache), global per-(head, dim) ones (QuantKVCache);
+    at the default page and at int8's own sublane count."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    specs = _paged_specs(SLOTS, HEADS, kv_heads, CTX, jnp.int8, page)
+    nb = specs[1][0][0]
+    sc = (((nb, kv_heads, page) if rowscale else (kv_heads, HEAD_DIM)),
+          jnp.float32)
+    text = chip_compile(
+        lambda q, k, v, t, n, ks, vs: paged_decode_attention(
+            q, k, v, t, n, k_scale=ks, v_scale=vs), *specs, sc, sc)
     assert 'tpu_custom_call' in text
 
 
 @pytest.mark.parametrize('slots,ctx', [(64, 1024), (4, 8192)])
 def test_paged_decode_attention_windowed(chip_compile, slots, ctx):
-    """AFMoE's window layers at Trinity's widths (48 query over 8 kv heads,
-    a window of 4096): the benchmark cell's geometry and the
-    window-crossing one."""
+    """AFMoE's window layers at Trinity's widths (48 query over 8 kv heads:
+    group 6, a window of 4096): the benchmark cell's geometry (64 rows, a
+    64-wide table) and the window-crossing one."""
     from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
 
-    maxb = ctx // PAGE
-    nb = slots * maxb + 1
     text = chip_compile(
         lambda q, k, v, t, n: paged_decode_attention(q, k, v, t, n,
                                                      window=4096),
-        ((slots, 1, 48, HEAD_DIM), jnp.bfloat16),
-        ((nb, 8, PAGE, HEAD_DIM), jnp.bfloat16),
-        ((nb, 8, PAGE, HEAD_DIM), jnp.bfloat16),
-        ((slots, maxb), jnp.int32), ((slots,), jnp.int32))
+        *_paged_specs(slots, 48, 8, ctx, jnp.bfloat16))
     assert 'tpu_custom_call' in text
 
 
@@ -183,15 +196,21 @@ def test_rms_norm_at_a_width_that_is_no_power_of_two(chip_compile):
     assert 'tpu_custom_call' in text
 
 
-def test_decode_attention_headmajor(chip_compile):
+@pytest.mark.parametrize('cache_dtype', [jnp.bfloat16, jnp.int8])
+def test_decode_attention_headmajor(chip_compile, cache_dtype):
     from paddle_tpu.ops.pallas.paged_attention import (
         decode_attention_headmajor)
 
-    text = chip_compile(decode_attention_headmajor,
-                        ((SLOTS, 1, HEADS, HEAD_DIM), jnp.bfloat16),
-                        ((SLOTS, HEADS, CTX, HEAD_DIM), jnp.bfloat16),
-                        ((SLOTS, HEADS, CTX, HEAD_DIM), jnp.bfloat16),
-                        ((SLOTS,), jnp.int32))
+    q = ((SLOTS, 1, HEADS, HEAD_DIM), jnp.bfloat16)
+    kv = ((SLOTS, HEADS, CTX, HEAD_DIM), cache_dtype)
+    vl = ((SLOTS,), jnp.int32)
+    if cache_dtype == jnp.int8:
+        sc = ((HEADS, HEAD_DIM), jnp.float32)
+        text = chip_compile(
+            lambda q, k, v, n, ks, vs: decode_attention_headmajor(
+                q, k, v, n, k_scale=ks, v_scale=vs), q, kv, kv, vl, sc, sc)
+    else:
+        text = chip_compile(decode_attention_headmajor, q, kv, kv, vl)
     assert 'tpu_custom_call' in text
 
 

@@ -875,6 +875,31 @@ class TestSpansOnTheProfilersClock:
             assert a['prompt_len'] == len(req.prompt)
             assert a['bucket'] == (32 if len(req.prompt) > 16 else 16)
 
+    def test_dispatch_counts_the_paged_kernels_pages(self):
+        """`pages_needed`: per attention layer and live row, the pages up
+        to the row's context less those wholly behind the layer's window;
+        `pages_table`: the table entries the layers' calls are handed.
+        One window layer (8 positions) and one full layer, pages of 4."""
+        from paddle_tpu.inference.serving import ServingEngine
+        from paddle_tpu.models import afmoe
+
+        pt.seed(0)
+        model = afmoe.AfmoeForCausalLM(afmoe.afmoe_tiny(
+            num_hidden_layers=2, layer_types=[afmoe.SLIDING, afmoe.FULL],
+            sliding_window=8))
+        srv = ServingEngine(model, max_slots=4, block_size=4,
+                            max_context_len=64, max_new_tokens=8,
+                            decode_window=4)
+        obs.TRACER.clear()
+        self._run(srv, [_prompt(51, 6), _prompt(52, 21)])
+        got = [(e['args']['kind'], e['args']['pages_needed'],
+                e['args']['pages_table']) for e in _ring('serve.dispatch')]
+        # contexts 6 and 21, then 10 and 25. Full layer: 2 + 6 pages, then
+        # 3 + 7. Window layer: positions from 0 and 13 on, then 2 and 17:
+        # 2 - 0 and 6 - 3 pages, then 3 - 0 and 7 - 4
+        table = 2 * 4 * (64 // 4)
+        assert got == [('step', 8 + 5, table), ('window', 10 + 6, table)]
+
     def test_last_deliveries_is_what_the_step_delivered(self):
         srv = self._engine(max_slots=4)
         reqs, steps, delivered = self._run(srv, [_prompt(21, 6),

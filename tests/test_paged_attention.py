@@ -1,12 +1,13 @@
 """Paged (block-table) serving attention.
 
 ref: python/paddle/incubate/nn/functional/block_multihead_attention.py:30
-and masked_multihead_attention.py:74. The pallas kernel's block table is
-scalar-prefetched and drives the BlockSpec index map; these tests verify
-it against a gather-then-mask reference (interpret mode on CPU), then the
-API wrappers end-to-end: prefill writes pages, decode reads them, int8
-pages dequantize, and a multi-step loop matches contiguous-cache
-generation.
+and masked_multihead_attention.py:74. The pallas kernel walks each row's
+own pages, named by the scalar-prefetched block table, several a loop
+step; these tests verify it against a gather-then-mask reference
+(interpret mode on CPU), with chunks made small enough that tiny rows
+cross them, then the API wrappers end-to-end: prefill writes pages,
+decode reads them, int8 pages dequantize, and a multi-step loop matches
+contiguous-cache generation.
 """
 import jax
 import jax.numpy as jnp
@@ -19,24 +20,31 @@ from paddle_tpu.incubate.nn.functional import (block_multihead_attention,
 from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
 
 
-def _gather_ref(q, kc, vc, tbl, counts):
-    """Reference: gather pages to contiguous, masked softmax."""
-    B = q.shape[0]
-    NB, Hkv, BS, D = kc.shape
+def _reference(q, kc, vc, tbl, counts, window=None):
+    """Gather pages to contiguous, mask to [count - window, count), softmax
+    in float32; a row of count 0 attends nothing and reads zeros."""
+    B, _, Hq, D = q.shape
+    NB, Hkv, BS, _ = kc.shape
     maxb = tbl.shape[1]
-    ck = kc[np.clip(np.asarray(tbl), 0, NB - 1)]         # (B,MAXB,Hkv,BS,D)
-    cv = vc[np.clip(np.asarray(tbl), 0, NB - 1)]
-    ck = jnp.swapaxes(jnp.asarray(ck), 2, 3).reshape(B, maxb * BS, Hkv, D)
-    cv = jnp.swapaxes(jnp.asarray(cv), 2, 3).reshape(B, maxb * BS, Hkv, D)
-    Hq = q.shape[2]
-    rep = Hq // Hkv
-    ckr = jnp.repeat(ck.astype(jnp.float32), rep, axis=2)
-    cvr = jnp.repeat(cv.astype(jnp.float32), rep, axis=2)
-    logits = jnp.einsum('bhd,bshd->bhs', q[:, 0].astype(jnp.float32),
-                        ckr) / (q.shape[-1] ** 0.5)
-    msk = jnp.arange(maxb * BS)[None, None, :] < counts[:, None, None]
-    p = jax.nn.softmax(jnp.where(msk, logits, -1e30), axis=-1)
-    return jnp.einsum('bhs,bshd->bhd', p, cvr)[:, None].astype(q.dtype)
+    t = np.clip(np.asarray(tbl), 0, NB - 1)
+    ck = jnp.swapaxes(jnp.asarray(kc, jnp.float32)[t], 2, 3)
+    cv = jnp.swapaxes(jnp.asarray(vc, jnp.float32)[t], 2, 3)
+    ck = ck.reshape(B, maxb * BS, Hkv, D)
+    cv = cv.reshape(B, maxb * BS, Hkv, D)
+    qg = q[:, 0].astype(jnp.float32).reshape(B, Hkv, Hq // Hkv, D)
+    logits = jnp.einsum('bhgd,bshd->bhgs', qg, ck) / (D ** 0.5)
+    pos = jnp.arange(maxb * BS)[None, :]
+    seen = pos < counts[:, None]
+    if window is not None:
+        seen &= pos >= counts[:, None] - window
+    p = jax.nn.softmax(jnp.where(seen[:, None, None], logits, -1e30), -1)
+    p = jnp.where(seen[:, None, None], p, 0.0)
+    out = jnp.einsum('bhgs,bshd->bhgd', p, cv).reshape(B, 1, Hq, D)
+    return out.astype(q.dtype)
+
+
+def _gather_ref(q, kc, vc, tbl, counts):
+    return _reference(q, kc, vc, jnp.asarray(tbl), jnp.asarray(counts))
 
 
 class TestPagedKernel:
@@ -109,6 +117,184 @@ class TestPagedKernel:
                                      k_scale=ks, v_scale=vs)
         want = paged_decode_attention(q, kf, vf, tbl, counts)
         assert np.max(np.abs(np.asarray(got) - np.asarray(want))) < 1e-2
+
+
+@pytest.fixture
+def small_chunks(monkeypatch):
+    """Chunks of 4 pages of 16 tokens, masked pieces of 2: rows of a few
+    dozen tokens cross pages, pieces and chunks."""
+    from paddle_tpu.ops.pallas import paged_attention as kmod
+
+    monkeypatch.setattr(kmod, 'CHUNK_KEYS', 64)
+    monkeypatch.setattr(kmod, 'EDGE_KEYS', 32)
+    assert kmod._pick_pages(16, 2, 16, 4, 16) == (4, 2)
+
+
+class TestPagedKernelLoopBounds:
+    """What a loop over a row's own pages can get wrong. 16 table entries
+    a row, pages of 16 tokens, chunks of 4 pages (64 keys)."""
+
+    BS, MAXB, D = 16, 16, 16
+
+    def _pools(self, seed, B, Hq, Hkv, dtype=jnp.float32):
+        rng = np.random.default_rng(seed)
+        NB = B * self.MAXB + 1
+        shape = (NB, Hkv, self.BS, self.D)
+        q = jnp.asarray(rng.normal(size=(B, 1, Hq, self.D)), dtype)
+        kc = jnp.asarray(rng.normal(size=shape), dtype)
+        vc = jnp.asarray(rng.normal(size=shape), dtype)
+        tbl = rng.permutation(np.arange(1, NB)).reshape(B, self.MAXB)
+        return rng, q, kc, vc, jnp.asarray(tbl, jnp.int32)
+
+    def _check(self, q, kc, vc, tbl, counts, tol=2e-4, **kw):
+        counts = jnp.asarray(counts, jnp.int32)
+        got = paged_decode_attention(q, kc, vc, tbl, counts, **kw)
+        want = _reference(q, kc, vc, tbl, counts, kw.get('window'))
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
+        return got
+
+    @pytest.mark.parametrize('counts', [
+        [0, 1, 0], [1, 1, 1],            # idle slots: nothing, one key
+        [16, 32, 48],                    # ends on a page, on a piece
+        [64, 128, 256],                  # ends on a chunk; the table's end
+        [15, 17, 63], [65, 127, 129],    # one off either side of each
+        [1, 250, 17], [200, 0, 64],      # very different rows in one call
+    ], ids=str)
+    def test_counts(self, small_chunks, counts):
+        _, q, kc, vc, tbl = self._pools(10, 3, 8, 2)
+        got = self._check(q, kc, vc, tbl, counts)
+        for b, n in enumerate(counts):
+            if n == 0:
+                assert not np.asarray(got[b]).any()
+
+    @pytest.mark.parametrize('fill', [-1, 10 ** 6, 'nan_page'])
+    def test_table_entries_past_the_rows_end_are_never_read(
+            self, small_chunks, fill):
+        """-1, an id far out of range, or a page full of NaN after each
+        row's last page: no such entry may reach a product."""
+        _, q, kc, vc, tbl = self._pools(11, 3, 8, 2)
+        counts = [40, 64, 1]
+        if fill == 'nan_page':
+            kc = kc.at[0].set(jnp.nan)
+            vc = vc.at[0].set(jnp.nan)
+            fill = 0
+        tbl = np.asarray(tbl).copy()
+        for b, n in enumerate(counts):
+            tbl[b, -(-n // self.BS):] = fill
+        want_tbl = np.where(tbl == fill, 1, tbl)       # any finite page
+        counts = jnp.asarray(counts, jnp.int32)
+        got = paged_decode_attention(q, kc, vc, jnp.asarray(tbl), counts)
+        want = _reference(q, kc.at[0].set(0.0), vc.at[0].set(0.0),
+                          want_tbl, counts)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+
+    def test_stale_keys_behind_the_count_do_not_leak(self, small_chunks):
+        """The last page's tail holds another tenant's NaNs."""
+        _, q, kc, vc, tbl = self._pools(12, 2, 8, 2)
+        counts = [21, 70]
+        clean = self._check(q, kc, vc, tbl, counts)
+        for b, n in enumerate(counts):
+            page = int(tbl[b, n // self.BS])
+            kc = kc.at[page, :, n % self.BS:].set(jnp.nan)
+            vc = vc.at[page, :, n % self.BS:].set(jnp.inf)
+        got = paged_decode_attention(q, kc, vc, tbl,
+                                     jnp.asarray(counts, jnp.int32))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(clean),
+                                   rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize('window', [
+        5,       # the last page alone, or its neighbour
+        40,      # starts inside a page
+        100,     # starts inside a chunk, pages behind it skipped
+        64,      # on a chunk's edge for the rows that end on one
+        1000,    # beyond every context: masks nothing
+    ])
+    def test_window(self, small_chunks, window):
+        _, q, kc, vc, tbl = self._pools(13, 4, 8, 2)
+        counts = [200, 128, 37, 1]
+        got = self._check(q, kc, vc, tbl, counts, window=window)
+        if window >= 256:
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(paged_decode_attention(
+                    q, kc, vc, tbl, jnp.asarray(counts, jnp.int32))),
+                rtol=1e-6, atol=1e-6)
+
+    def test_pages_behind_a_window_are_never_read(self, small_chunks):
+        _, q, kc, vc, tbl = self._pools(14, 2, 8, 2)
+        counts, window = [200, 130], 70
+        for b, n in enumerate(counts):
+            for j in range((n - window) // self.BS):
+                kc = kc.at[int(tbl[b, j])].set(jnp.nan)
+                vc = vc.at[int(tbl[b, j])].set(jnp.nan)
+        got = paged_decode_attention(q, kc, vc, tbl,
+                                     jnp.asarray(counts, jnp.int32),
+                                     window=window)
+        want = _reference(q, jnp.nan_to_num(kc), jnp.nan_to_num(vc), tbl,
+                          jnp.asarray(counts, jnp.int32), window)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize('heads,kv_heads', [
+        (32, 8), (48, 8), (8, 2), (12, 2), (4, 4)],
+        ids=lambda v: str(v))
+    @pytest.mark.parametrize('window', [None, 50])
+    def test_groups_and_kv_heads(self, small_chunks, heads, kv_heads,
+                                 window):
+        """Group 4 (Mistral) and 6 (Trinity) over 8 kv heads and over the
+        2 a tp shard holds; no grouping at all."""
+        _, q, kc, vc, tbl = self._pools(15, 2, heads, kv_heads)
+        self._check(q, kc, vc, tbl, [150, 33], window=window)
+
+    def test_bfloat16_pools(self, small_chunks):
+        _, q, kc, vc, tbl = self._pools(16, 3, 8, 2, jnp.bfloat16)
+        self._check(q, kc, vc, tbl, [250, 64, 7], tol=2e-2)
+
+    @pytest.mark.parametrize('window', [None, 50])
+    @pytest.mark.parametrize('layout', ['rowscale', 'global'])
+    def test_int8_scale_layouts(self, small_chunks, layout, window):
+        """Per-row scales in page-shaped pools, and global per-(head, dim)
+        ones: both against the float reference over the dequantized
+        pools."""
+        from paddle_tpu.models.generation import (calibrate_kv_scale,
+                                                  quantize_kv_row,
+                                                  quantize_kv_rows)
+
+        _, q, kf, vf, tbl = self._pools(17, 3, 8, 2)
+        rows = lambda x: jnp.swapaxes(x, 1, 2)      # noqa: E731 (N,S,H,D)
+        if layout == 'rowscale':
+            (k8, ks), (v8, vs) = (quantize_kv_row(rows(x)) for x in (kf, vf))
+            deq = [rows(x.astype(jnp.float32) * s[..., None])
+                   for x, s in ((k8, ks), (v8, vs))]
+            ks, vs = rows(ks), rows(vs)             # (NB, Hkv, BS)
+        else:
+            ks, vs = (calibrate_kv_scale(rows(x)) for x in (kf, vf))
+            k8, v8 = (quantize_kv_rows(rows(x), s)
+                      for x, s in ((kf, ks), (vf, vs)))
+            deq = [rows(x.astype(jnp.float32) * s[None, None])
+                   for x, s in ((k8, ks), (v8, vs))]
+        counts = jnp.asarray([250, 64, 7], jnp.int32)
+        got = paged_decode_attention(q, rows(k8), rows(v8), tbl, counts,
+                                     k_scale=ks, v_scale=vs, window=window)
+        want = _reference(q, deq[0], deq[1], tbl, counts, window)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+
+    def test_chunk_follows_the_shapes(self):
+        """(pages a chunk, pages a masked piece): 512 and 128 keys where
+        VMEM allows, whole pieces, never more than the table holds."""
+        from paddle_tpu.ops.pallas.paged_attention import _pick_pages
+
+        assert _pick_pages(16, 8, 128, 2, 128) == (32, 8)    # the cells
+        assert _pick_pages(16, 2, 128, 2, 128) == (32, 8)    # a tp shard
+        assert _pick_pages(16, 8, 128, 1, 128) == (32, 8)    # int8 as bf16
+        assert _pick_pages(16, 8, 128, 2, 4) == (4, 4)       # a narrow table
+        assert _pick_pages(128, 8, 128, 2, 64) == (4, 1)     # a large page
+        pages, piece = _pick_pages(16, 32, 128, 2, 128)      # 32 kv heads
+        assert pages % piece == 0 and 4 * pages * 32 * 16 * 128 * 2 <= 8 << 20
 
 
 class TestMaskedMHA:
