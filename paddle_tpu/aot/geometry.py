@@ -165,25 +165,9 @@ def _registry_key(engine, g):
         return engine.registry_key_speculative(
             p['batch'], p['prompt_len'], p['max_new_tokens'],
             p['num_draft_tokens'])
-    if g.kind == 'serve_step':
-        return engine.registry_key('serve_step', p['window'], p['bucket'])
-    if g.kind == 'serve_window':
-        return engine.registry_key('serve_window', p['window'])
-    if g.kind == 'serve_prefill':
-        return engine.registry_key('serve_prefill', p['bucket'])
-    if g.kind == 'serve_chunk_step':
-        return engine.registry_key('serve_chunk_step', p['window'],
-                                   p['chunk'], p['bucket'])
-    if g.kind == 'serve_spec_step':
-        return engine.registry_key('serve_spec_step', p['spec'],
-                                   p['bucket'], p['ctx'])
-    if g.kind == 'serve_spec_window':
-        return engine.registry_key('serve_spec_window', p['spec'],
-                                   p['ctx'])
-    if g.kind == 'serve_export':
-        return engine.registry_key('serve_export', p['ctx'])
-    if g.kind == 'serve_import':
-        return engine.registry_key('serve_import', p['ctx'])
+    if g.kind.startswith('serve_'):
+        # the tag is the engine's to state: its table of dispatches
+        return engine.registry_key(*engine._geometry_cost_tag(g))
     if g.kind == 'train_step':
         return engine.registry_key(p['input_shapes'][0],
                                    p['input_dtypes'][0])

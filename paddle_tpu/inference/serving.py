@@ -1338,6 +1338,35 @@ def _kv_import(pages, blob, pflat, sflat, *, ctx_bucket):
     return _pin_pages(out)
 
 
+# kind -> (its jitted function, the geometry params its registry tag
+# holds after the kind, in order). A kind's arguments, statics and
+# outputs are `ServingEngine._dispatch`'s to state.
+_SERVE_DISPATCHES = {
+    'serve_prefill': (_paged_prefill, ('bucket',)),
+    'serve_window': (_serve_window, ('window',)),
+    'serve_step': (_serve_step, ('window', 'bucket')),
+    'serve_chunk_step': (_serve_chunk_step, ('window', 'chunk', 'bucket')),
+    'serve_spec_window': (_serve_spec_window, ('spec', 'ctx')),
+    'serve_spec_step': (_serve_spec_step, ('spec', 'bucket', 'ctx')),
+    'serve_draft_chunk': (_draft_chunk, ('chunk', 'bucket')),
+    'serve_export': (_kv_export, ('ctx',)),
+    'serve_import': (_kv_import, ('ctx',)),
+}
+
+# One jitted call as `ServingEngine._dispatch` states it:
+# `fn(*models, *args, **statics)`; `tag` keys the registry note of a
+# `primary` call (a draft-side leg notes nothing); `rebind` holds the
+# (engine field, output index) pairs that take the donated pools back
+# (index None: the output whole).
+_Dispatch = collections.namedtuple(
+    '_Dispatch', 'fn models args statics tag rebind primary')
+
+
+def _avals(tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+
 def _ceil_div(a, b):
     return -(-a // b)
 
@@ -1912,12 +1941,8 @@ class ServingEngine:
     def registry_key(self, *tag):
         """The EXACT CompileCache key `_note(*tag)` records (the shared
         recipe: pool shape + POOL dtype + sampling config + `tag` +
-        geometry). Tags are the dispatch kinds step() uses:
-        ('serve_step', W, Sb), ('serve_window', W),
-        ('serve_prefill', Sb), ('serve_chunk_step', W, Cb, Sb),
-        ('serve_spec_step', k, Sb, Cx), ('serve_spec_window', k, Cx),
-        plus the migration pair export_kv/import_kv dispatch:
-        ('serve_export', Cx), ('serve_import', Cx).
+        geometry). A tag is a dispatch's kind and then the buckets
+        `_SERVE_DISPATCHES` lists for it, e.g. ('serve_step', W, Sb).
         The pool dtype (int8 vs the model's cache dtype) keys here, so
         a quantized and an unquantized engine over one model never
         collide. Exposed so aot.GeometrySet enumeration and the live
@@ -2127,26 +2152,175 @@ class ServingEngine:
         """The module-level jitted steps this engine's geometries
         dispatch — what `aot.build` cache-evicts (per FUNCTION, not
         process-wide) to force real persisting compiles."""
-        return (_paged_prefill, _serve_window, _serve_step,
-                _serve_chunk_step, _serve_spec_window, _serve_spec_step,
-                _draft_chunk, _kv_export, _kv_import)
+        return tuple(fn for fn, _ in _SERVE_DISPATCHES.values())
+
+    # -- the serve dispatches' calling convention --------------------------
+
+    def _window_tail(self, dev, budget):
+        """The per-slot arguments every window-bearing dispatch ends
+        its positional list with (but for `carried`), in call order."""
+        return (dev['btab'], dev['ctx'], dev['live'], budget, dev['temp'],
+                dev['topk'], dev['topp'], dev['seed'], dev['plen'])
+
+    def _dispatch(self, kind, *key, batch=(), carried=(), tail=(),
+                  draft=False):
+        """THE calling convention of the nine jitted serve dispatches:
+        the one place, besides a function's own `def`, that orders its
+        arguments, names its statics and says which outputs are the
+        donated pools coming back. `step()`, the migration pair and
+        `_dispatches` (warm-up, cost specs, export) all call through
+        it, so they cannot disagree. `(kind, *key)` is the registry
+        tag; `batch` is the admission batch (`_prefill_args`), the
+        chunk batch (`_chunk_args` less its buckets) or the migration
+        payload; `carried` the (forced_tok, forced) pair; `tail` is
+        `_window_tail`'s. `draft=True` asks for the draft-side leg of
+        a prefill, export or import (`serve_draft_chunk` is always
+        one): the draft's pools, all-dummy slots, `primary` False.
+        Builds a record and calls nothing: `_run` makes the call."""
+        fn, tag = _SERVE_DISPATCHES[kind][0], (kind, *key)
+        models, statics, primary = (self.model,), {}, not draft
+        eos = self.eos_token_id
+        if kind in ('serve_export', 'serve_import'):        # (Cx)
+            field = '_dpages' if draft else '_pages'
+            models, args = (), (getattr(self, field),) + batch
+            statics = dict(ctx_bucket=key[0])
+            rebind = ((field, None),) if kind == 'serve_import' else ()
+        elif draft or kind == 'serve_draft_chunk':          # (Sb) | (Cb, Sb)
+            # ids, lengths[, starts], block tables, SLOTS[, CoW pairs]:
+            # the leg commits no logits (`_dlogits` only donates and
+            # comes back), so its slots are all dummies
+            at = 3 if kind == 'serve_prefill' else 4
+            models, primary = (self.draft,), False
+            args = ((self._dpages, self._dlogits) + batch[:at]
+                    + (self._dummy(batch[0].shape[0]),) + batch[at + 1:])
+            if kind == 'serve_draft_chunk':
+                statics = dict(ctx_bucket=key[1])
+            rebind = (('_dlogits', 0), ('_dpages', 1))
+        elif kind == 'serve_prefill':                       # (Sb)
+            args = (self._pages, self._last_logits) + batch
+            rebind = (('_last_logits', 0), ('_pages', 1))
+        elif kind in ('serve_spec_step', 'serve_spec_window'):
+            # (k[, Sb], Cx) -> cand, ncommit, next_tok, last_logits,
+            # pages, dpages, ctx
+            models = (self.model, self.draft)
+            args = ((self._pages, self._dpages, self._last_logits) + batch
+                    + carried + tail)
+            statics = dict(k=key[0], ctx_bucket=key[-1], eos_token_id=eos)
+            rebind = (('_last_logits', 3), ('_pages', 4), ('_dpages', 5))
+        else:
+            # (W[, Sb] | W, Cb, Sb) -> toks, last_logits, pages, ctx, routed
+            args = (self._pages, self._last_logits) + batch + tail
+            statics = dict(window=key[0], eos_token_id=eos)
+            if kind == 'serve_chunk_step':
+                args += carried
+                statics['ctx_bucket'] = key[2]
+            rebind = (('_last_logits', 1), ('_pages', 2))
+        return _Dispatch(fn, models, args, statics, tag, rebind, primary)
+
+    def _run(self, d):
+        """Make the call a `_dispatch` record states, re-assign the
+        donated pools from its outputs, and return the outputs."""
+        out = d.fn(*d.models, *d.args, **d.statics)
+        for field, i in d.rebind:
+            setattr(self, field, out if i is None else out[i])
+        return out
+
+    def _dispatches(self, g):
+        """The jitted calls ONE enumerated geometry stands for, as
+        `_dispatch` records over an all-dummy batch: real_len 0 rows
+        land on the scratch page, slot indices max_slots drop their
+        logits on the OOB scatter, live=False freezes every row, a
+        zero export length reads and all-zero import targets write
+        only the scratch page. The primary call comes first, then the
+        draft-side legs the live path runs beside it. The arguments
+        come from the builders step() uses (`_prefill_args`,
+        `_device_state`, `_blob_device_entries`), so the avals are the
+        live ones by construction. Lazy, and nothing is called here:
+        a record reads the pools as they are when it is asked for (a
+        consumer that runs one re-binds them before the next), and no
+        consumer keeps one (it would keep the pools alive)."""
+        kind, *key = self._geometry_cost_tag(g)
+        K, z = self.max_slots, self._zero_ftok
+
+        def rows(n, width=None, fill=0):
+            return self._put(np.full(
+                (n,) if width is None else (n, width), fill, np.int32))
+
+        def chunk_batch(Cb):
+            return (rows(K, Cb), z, z, rows(K, self.max_blocks_per_seq),
+                    rows(K, fill=K), z, z)
+
+        batch = tail = ()
+        if kind in ('serve_step', 'serve_spec_step', 'serve_prefill'):
+            batch = self._prefill_args(g.params['bucket'], [])
+        elif kind == 'serve_chunk_step':
+            batch = chunk_batch(key[1])
+        elif kind == 'serve_export':
+            batch = (rows(1, self.max_blocks_per_seq), rows(1))
+        elif kind == 'serve_import':
+            batch = (self._blob_device_entries(self._pages, key[0]),
+                     rows(key[0]), rows(key[0]))
+        if kind not in ('serve_prefill', 'serve_export', 'serve_import'):
+            tail = self._window_tail(self._device_state(),
+                                     self._put(self._budget))
+        yield self._dispatch(kind, *key, batch=batch, tail=tail,
+                             carried=(z, self._zero_forced))
+        if self.draft is None:
+            return
+        catch_up = None
+        if kind in ('serve_prefill', 'serve_export'):
+            yield self._dispatch(kind, *key, batch=batch, draft=True)
+        elif kind == 'serve_import':
+            yield self._dispatch(
+                kind, *key, draft=True, batch=(self._blob_device_entries(
+                    self._dpages, key[0]),) + batch[1:])
+        elif kind == 'serve_chunk_step':
+            yield self._dispatch('serve_draft_chunk', *key[1:], batch=batch)
+            catch_up = key[2]
+        elif (self.prefill_chunk is not None or self.prefix_cache
+                or self.phase_role == 'decode'):
+            # chunk steps can commit window tokens past the draft; the
+            # catch-up `_draft_chunk` shapes a live spec step can then
+            # dispatch (hole bucket x THIS geometry's ctx bucket) must
+            # be warm too, or a warm-attached engine would compile
+            # mid-serve (decode-role pools re-enter through the
+            # one-token continuation chunk, which opens the same hole)
+            catch_up = key[-1]
+        # holes are bounded by one decode window per step, so their
+        # chunk buckets are the ladder entries at or below
+        # bucket(decode_window); a shape already dispatched is warm
+        v = 1
+        while catch_up is not None and v <= self.decode_window:
+            cb = bucket_length(v, self.buckets)
+            v = cb + 1
+            if (cb, catch_up) not in self._draft_shapes:
+                yield self._dispatch('serve_draft_chunk', cb, catch_up,
+                                     batch=chunk_batch(cb))
+
+    def _primary(self, g):
+        return next(d for d in self._dispatches(g) if d.primary)
 
     def _warm_geometry(self, g, draft=None):
         """Drive ONE enumerated geometry through the SAME module-level
-        jitted steps the scheduler dispatches, with an all-dummy slot
-        batch: real_len 0 rows land on the scratch page, slot indices
-        max_slots drop their logits on the OOB scatter, and live=False
-        freezes every row — so warming an IDLE engine (enforced below)
+        jitted steps the scheduler dispatches, with `_dispatches`'
+        all-dummy batch — so warming an IDLE engine (enforced below)
         mutates no scheduler state beyond the (donated, re-assigned)
-        device pools. The args come from the same builders step() uses
-        (`_prefill_args`, `_device_state`), so the traced avals are the
-        live ones by construction."""
+        device pools."""
         p = g.params
         W = self.decode_window
         if p.get('window', W) != W:
             raise ValueError(
                 f'geometry {g.label()} was enumerated for decode_window '
                 f"{p['window']}, engine has {W}")
+        if 'spec' in p and self.draft is None:
+            raise ValueError(
+                f'geometry {g.label()} needs a speculative engine '
+                f'(construct with draft=...)')
+        if 'spec' in p and int(p['spec']) != self.spec_window:
+            raise ValueError(
+                f"geometry {g.label()} was enumerated for "
+                f"num_draft_tokens {p['spec']}, engine has "
+                f'{self.spec_window}')
         if self.in_flight():
             # the dummy batch is only inert when every slot is empty: a
             # LIVE row would really decode through the dummy window
@@ -2158,161 +2332,12 @@ class ServingEngine:
                 f'request(s) in flight: drain the batch (run()) before '
                 f'warmup/aot.build')
         with self._use_mesh():
-            dev = self._device_state()
-            budget = self._put(self._budget)
-            common = dict(window=W, eos_token_id=self.eos_token_id)
-            sample_args = (dev['temp'], dev['topk'], dev['topp'],
-                           dev['seed'], dev['plen'])
-            K = self.max_slots
-            if g.kind == 'serve_step':
-                ids, real_len, btabs, slots = self._prefill_args(
-                    p['bucket'], [])
-                self._note('serve_step', W, p['bucket'])
-                _, self._last_logits, self._pages, _, _ = _serve_step(
-                    self.model, self._pages, self._last_logits, ids,
-                    real_len, btabs, slots, dev['btab'], dev['ctx'],
-                    dev['live'], budget, *sample_args, **common)
-            elif g.kind == 'serve_window':
-                self._note('serve_window', W)
-                _, self._last_logits, self._pages, _, _ = _serve_window(
-                    self.model, self._pages, self._last_logits,
-                    dev['btab'], dev['ctx'], dev['live'], budget,
-                    *sample_args, **common)
-            elif g.kind == 'serve_prefill':
-                ids, real_len, btabs, slots = self._prefill_args(
-                    p['bucket'], [])
-                self._note('serve_prefill', p['bucket'])
-                self._last_logits, self._pages = _paged_prefill(
-                    self.model, self._pages, self._last_logits, ids,
-                    real_len, btabs, slots)
-                if self.draft is not None:
-                    # the live standalone prefill runs a draft leg too
-                    self._dlogits, self._dpages = _paged_prefill(
-                        self.draft, self._dpages, self._dlogits, ids,
-                        real_len, btabs, self._dummy(slots.shape[0]))
-            elif g.kind == 'serve_chunk_step':
-                Cb, Sb = int(p['chunk']), int(p['bucket'])
-                ids = self._put(np.zeros((K, Cb), np.int32))
-                z = self._put(np.zeros((K,), np.int32))
-                btabs = self._put(
-                    np.zeros((K, self.max_blocks_per_seq), np.int32))
-                slots = self._put(
-                    np.full((K,), K, np.int32))   # all dummies: drop
-                self._note('serve_chunk_step', W, Cb, Sb)
-                zb = self._put(np.zeros((K,), bool))
-                if self.draft is not None:
-                    # the live chunk step runs a draft leg too
-                    self._draft_shapes.add((Cb, Sb))
-                    self._dlogits, self._dpages = _draft_chunk(
-                        self.draft, self._dpages, self._dlogits, ids,
-                        z, z, btabs, slots, z, z, ctx_bucket=Sb)
-                    self._warm_draft_catchup(Sb, z, btabs)
-                _, self._last_logits, self._pages, _, _ = _serve_chunk_step(
-                    self.model, self._pages, self._last_logits, ids, z,
-                    z, btabs, slots, z, z, dev['btab'], dev['ctx'],
-                    dev['live'], budget, *sample_args, z, zb,
-                    ctx_bucket=Sb, **common)
-            elif g.kind in ('serve_spec_step', 'serve_spec_window'):
-                if self.draft is None:
-                    raise ValueError(
-                        f'geometry {g.label()} needs a speculative '
-                        f'engine (construct with draft=...)')
-                k = int(p['spec'])
-                if k != self.spec_window:
-                    raise ValueError(
-                        f'geometry {g.label()} was enumerated for '
-                        f'num_draft_tokens {k}, engine has '
-                        f'{self.spec_window}')
-                Cx = int(p['ctx'])
-                z = self._put(np.zeros((K,), np.int32))
-                forced = self._put(np.zeros((K,), bool))
-                scommon = dict(k=k, ctx_bucket=Cx,
-                               eos_token_id=self.eos_token_id)
-                if (self.prefill_chunk is not None or self.prefix_cache
-                        or self.phase_role == 'decode'):
-                    # chunk steps can commit window tokens past the
-                    # draft; the catch-up `_draft_chunk` shapes a live
-                    # spec step can then dispatch (hole bucket x THIS
-                    # geometry's ctx bucket) must be warm too, or a
-                    # warm-attached engine would compile mid-serve
-                    # (decode-role pools re-enter through the one-token
-                    # continuation chunk, which opens the same hole)
-                    self._warm_draft_catchup(
-                        Cx, z,
-                        self._put(np.zeros(
-                            (K, self.max_blocks_per_seq), np.int32)))
-                if g.kind == 'serve_spec_step':
-                    ids, real_len, btabs, slots = self._prefill_args(
-                        p['bucket'], [])
-                    self._note('serve_spec_step', k, p['bucket'], Cx)
-                    (_, _, _, self._last_logits, self._pages,
-                     self._dpages, _) = _serve_spec_step(
-                        self.model, self.draft, self._pages,
-                        self._dpages, self._last_logits, ids, real_len,
-                        btabs, slots, z, forced, dev['btab'],
-                        dev['ctx'], dev['live'], budget, *sample_args,
-                        **scommon)
-                else:
-                    self._note('serve_spec_window', k, Cx)
-                    (_, _, _, self._last_logits, self._pages,
-                     self._dpages, _) = _serve_spec_window(
-                        self.model, self.draft, self._pages,
-                        self._dpages, self._last_logits, z, forced,
-                        dev['btab'], dev['ctx'], dev['live'], budget,
-                        *sample_args, **scommon)
-            elif g.kind == 'serve_export':
-                # the migration gather at K=1: a zero start length
-                # reads only the scratch page, so warming is inert
-                # beyond the jit cache (no donation — pools untouched)
-                Cx = int(p['ctx'])
-                self._note('serve_export', Cx)
-                btabs1 = self._put(
-                    np.zeros((1, self.max_blocks_per_seq), np.int32))
-                st1 = self._put(np.zeros((1,), np.int32))
-                _kv_export(self._pages, btabs1, st1, ctx_bucket=Cx)
-                if self.draft is not None:
-                    # the live export ships the draft's pages too
-                    _kv_export(self._dpages, btabs1, st1, ctx_bucket=Cx)
-            elif g.kind == 'serve_import':
-                # the migration scatter: all-zero targets write only
-                # the reserved scratch page (donated pools come back
-                # re-assigned, nothing live is touched). The zero blob
-                # rides the SAME `_blob_device_entries` upload the live
-                # import uses, so the warmed avals are the live ones
-                # by construction.
-                Cx = int(p['ctx'])
-                self._note('serve_import', Cx)
-                zi = self._put(np.zeros((Cx,), np.int32))
-                ents = self._blob_device_entries(self._pages, Cx)
-                self._pages = _kv_import(self._pages, ents, zi, zi,
-                                         ctx_bucket=Cx)
-                if self.draft is not None:
-                    dents = self._blob_device_entries(self._dpages, Cx)
-                    self._dpages = _kv_import(self._dpages, dents, zi,
-                                              zi, ctx_bucket=Cx)
-            else:
-                raise ValueError(
-                    f'unknown serving geometry kind {g.kind!r}')
-
-    def _warm_draft_catchup(self, Sb, z, btabs):
-        """Warm the draft catch-up `_draft_chunk` shapes reachable at
-        context bucket `Sb`: holes are bounded by one decode window
-        per step, so their chunk buckets are the ladder entries at or
-        below bucket(decode_window)."""
-        K = self.max_slots
-        cbs, v = [], 1
-        while v <= self.decode_window:
-            b = bucket_length(v, self.buckets)
-            cbs.append(b)
-            v = b + 1
-        for cb in cbs:
-            if (cb, Sb) in self._draft_shapes:
-                continue
-            self._draft_shapes.add((cb, Sb))
-            ids = self._put(np.zeros((K, cb), np.int32))
-            self._dlogits, self._dpages = _draft_chunk(
-                self.draft, self._dpages, self._dlogits, ids, z, z,
-                btabs, self._dummy(K), z, z, ctx_bucket=Sb)
+            for d in self._dispatches(g):
+                if d.primary:
+                    self._note(*d.tag)
+                elif d.fn is _draft_chunk:
+                    self._draft_shapes.add(d.tag[1:])
+                self._run(d)
 
     def warmup(self, artifact=None, geometries=None, draft=None):
         """Pre-populate the module-level jit caches (and the
@@ -2327,206 +2352,42 @@ class ServingEngine:
                            draft=draft)
 
     def _export_specs(self, g, draft=None):
-        """(suffix, jitted_fn, args) for `aot.build(...,
-        export_stablehlo=True)`. The model is closed over (the jit.save
-        idiom — a Layer in the calling convention would refuse to
-        serialize); the page pools stay ARGS, as ShapeDtypeStruct avals
-        of the engine's live pools (they are state, not weights — the
-        exported module must take them, and PagedKVCache is a
+        """[(suffix, jitted_fn, args)] for `aot.build(...,
+        export_stablehlo=True)`: the geometry's primary dispatch with
+        the model(s) closed over (the jit.save idiom — a Layer in the
+        calling convention would refuse to serialize) and the rest as
+        avals; the page pools stay ARGS (they are state, not weights —
+        the exported module must take them, and PagedKVCache is a
         registered serializable container)."""
-        p = g.params
-        W = self.decode_window
-        K = self.max_slots
-
-        def sds(x):
-            return jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), x)
-
-        pages = sds(self._pages)
-        logits = sds(self._last_logits)
-        btab = jax.ShapeDtypeStruct((K, self.max_blocks_per_seq),
-                                    jnp.int32)
-        ctx = jax.ShapeDtypeStruct((K,), jnp.int32)
-        live = jax.ShapeDtypeStruct((K,), jnp.bool_)
-        budget = jax.ShapeDtypeStruct((K,), jnp.int32)
-        fvec = jax.ShapeDtypeStruct((K,), jnp.float32)
-        svec = jax.ShapeDtypeStruct((K,), jnp.uint32)
-        ivec = jax.ShapeDtypeStruct((K,), jnp.int32)
-        samp = (fvec, ivec, fvec, svec, ivec)   # temp/topk/topp/seed/plen
-        common = dict(window=W, eos_token_id=self.eos_token_id)
-        if g.kind in ('serve_step', 'serve_prefill', 'serve_spec_step'):
-            # the admission batch: `_prefill_args`'s shapes, not K rows
-            R = self._prefill_rows(int(p['bucket']))
-            ids = jax.ShapeDtypeStruct((R, int(p['bucket'])), jnp.int32)
-            rl = jax.ShapeDtypeStruct((R,), jnp.int32)
-            btabs = jax.ShapeDtypeStruct((R, self.max_blocks_per_seq),
-                                         jnp.int32)
-            slots = jax.ShapeDtypeStruct((R,), jnp.int32)
-        elif g.kind == 'serve_chunk_step':
-            ids = jax.ShapeDtypeStruct((K, int(p['chunk'])), jnp.int32)
-            rl = jax.ShapeDtypeStruct((K,), jnp.int32)
-            btabs = jax.ShapeDtypeStruct((K, self.max_blocks_per_seq),
-                                         jnp.int32)
-            slots = jax.ShapeDtypeStruct((K,), jnp.int32)
-
-        def wrap(base, *extra_models, **statics):
-            # tracelint: disable=TL001 - one-shot export wrapper (model
-            # and statics baked into the closure; never a hot path)
-            return jax.jit(functools.partial(
-                getattr(base, '__wrapped__', base), self.model,
-                *extra_models, **statics))
-
-        if g.kind == 'serve_step':
-            yield ('', wrap(_serve_step, **common),
-                   (pages, logits, ids, rl, btabs, slots, btab, ctx,
-                    live, budget) + samp)
-        elif g.kind == 'serve_window':
-            yield ('', wrap(_serve_window, **common),
-                   (pages, logits, btab, ctx, live, budget) + samp)
-        elif g.kind == 'serve_prefill':
-            yield ('', wrap(_paged_prefill),
-                   (pages, logits, ids, rl, btabs, slots))
-        elif g.kind == 'serve_chunk_step':
-            fbool = jax.ShapeDtypeStruct((K,), jnp.bool_)
-            yield ('', wrap(_serve_chunk_step,
-                            ctx_bucket=int(p['bucket']), **common),
-                   (pages, logits, ids, rl, rl, btabs, slots, rl, rl,
-                    btab, ctx, live, budget) + samp + (ivec, fbool))
-        elif g.kind == 'serve_spec_step':
-            dpages = sds(self._dpages)
-            fbool = jax.ShapeDtypeStruct((K,), jnp.bool_)
-            yield ('', wrap(_serve_spec_step, self.draft,
-                            k=int(p['spec']), ctx_bucket=int(p['ctx']),
-                            eos_token_id=self.eos_token_id),
-                   (pages, dpages, logits, ids, rl, btabs, slots, ivec,
-                    fbool, btab, ctx, live, budget) + samp)
-        elif g.kind == 'serve_spec_window':
-            dpages = sds(self._dpages)
-            fbool = jax.ShapeDtypeStruct((K,), jnp.bool_)
-            yield ('', wrap(_serve_spec_window, self.draft,
-                            k=int(p['spec']), ctx_bucket=int(p['ctx']),
-                            eos_token_id=self.eos_token_id),
-                   (pages, dpages, logits, ivec, fbool, btab, ctx,
-                    live, budget) + samp)
-        else:
+        if g.kind in ('serve_export', 'serve_import'):
             raise NotImplementedError(
                 f'no StableHLO export for geometry kind {g.kind!r}')
+        d = self._primary(g)
+        # tracelint: disable=TL001 - one-shot export wrapper (model
+        # and statics baked into the closure; never a hot path)
+        fn = jax.jit(functools.partial(d.fn.__wrapped__, *d.models,
+                                       **d.statics))
+        return [('', fn, _avals(d.args))]
 
     def _cost_specs(self, g, draft=None):
-        """(jitted_fn, args, static_kwargs) triples for
-        `observability.costs.geometry_cost`: the SAME module-level
-        jitted steps the scheduler dispatches, over ShapeDtypeStruct
-        avals with the live model as the first argument — so the
+        """[(jitted_fn, args, static_kwargs)] for
+        `observability.costs.geometry_cost`: the geometry's primary
+        dispatch, the SAME module-level jitted step the scheduler
+        calls, over avals with the live model(s) leading — so the
         lowered HLO (and its cost analysis) is exactly the served
         executable's, not a weights-as-constants export variant."""
-        p = g.params
-        W = self.decode_window
-        K = self.max_slots
-
-        def sds(x):
-            return jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), x)
-
-        pages = sds(self._pages)
-        logits = sds(self._last_logits)
-        btab = jax.ShapeDtypeStruct((K, self.max_blocks_per_seq),
-                                    jnp.int32)
-        vec = jax.ShapeDtypeStruct((K,), jnp.int32)
-        live = jax.ShapeDtypeStruct((K,), jnp.bool_)
-        fvec = jax.ShapeDtypeStruct((K,), jnp.float32)
-        svec = jax.ShapeDtypeStruct((K,), jnp.uint32)
-        samp = (fvec, vec, fvec, svec, vec)
-        common = dict(window=W, eos_token_id=self.eos_token_id)
-        if g.kind in ('serve_step', 'serve_prefill', 'serve_spec_step'):
-            # the admission batch: `_prefill_args`'s shapes (`rvec` is
-            # per ROW of it, `vec` per SLOT)
-            R = self._prefill_rows(int(p['bucket']))
-            ids = jax.ShapeDtypeStruct((R, int(p['bucket'])), jnp.int32)
-            btabs = jax.ShapeDtypeStruct((R, self.max_blocks_per_seq),
-                                         jnp.int32)
-            rvec = jax.ShapeDtypeStruct((R,), jnp.int32)
-        if g.kind == 'serve_step':
-            yield (_serve_step,
-                   (self.model, pages, logits, ids, rvec, btabs, rvec,
-                    btab, vec, live, vec) + samp, common)
-        elif g.kind == 'serve_window':
-            yield (_serve_window,
-                   (self.model, pages, logits, btab, vec, live, vec)
-                   + samp, common)
-        elif g.kind == 'serve_prefill':
-            yield (_paged_prefill,
-                   (self.model, pages, logits, ids, rvec, btabs, rvec), {})
-        elif g.kind == 'serve_chunk_step':
-            ids = jax.ShapeDtypeStruct((K, int(p['chunk'])), jnp.int32)
-            btabs = jax.ShapeDtypeStruct((K, self.max_blocks_per_seq),
-                                         jnp.int32)
-            fbool = jax.ShapeDtypeStruct((K,), jnp.bool_)
-            yield (_serve_chunk_step,
-                   (self.model, pages, logits, ids, vec, vec, btabs,
-                    vec, vec, vec, btab, vec, live, vec) + samp
-                   + (vec, fbool),
-                   dict(ctx_bucket=int(p['bucket']), **common))
-        elif g.kind == 'serve_spec_step':
-            dpages = sds(self._dpages)
-            fbool = jax.ShapeDtypeStruct((K,), jnp.bool_)
-            yield (_serve_spec_step,
-                   (self.model, self.draft, pages, dpages, logits, ids,
-                    rvec, btabs, rvec, vec, fbool, btab, vec, live, vec)
-                   + samp,
-                   dict(k=int(p['spec']), ctx_bucket=int(p['ctx']),
-                        eos_token_id=self.eos_token_id))
-        elif g.kind == 'serve_spec_window':
-            dpages = sds(self._dpages)
-            fbool = jax.ShapeDtypeStruct((K,), jnp.bool_)
-            yield (_serve_spec_window,
-                   (self.model, self.draft, pages, dpages, logits, vec,
-                    fbool, btab, vec, live, vec) + samp,
-                   dict(k=int(p['spec']), ctx_bucket=int(p['ctx']),
-                        eos_token_id=self.eos_token_id))
-        elif g.kind == 'serve_export':
-            btabs1 = jax.ShapeDtypeStruct((1, self.max_blocks_per_seq),
-                                          jnp.int32)
-            st = jax.ShapeDtypeStruct((1,), jnp.int32)
-            yield (_kv_export, (pages, btabs1, st),
-                   dict(ctx_bucket=int(p['ctx'])))
-        elif g.kind == 'serve_import':
-            Cx = int(p['ctx'])
-            blob = sds(self._blob_aval_entries(Cx))
-            pflat = jax.ShapeDtypeStruct((Cx,), jnp.int32)
-            yield (_kv_import, (pages, blob, pflat, pflat),
-                   dict(ctx_bucket=Cx))
-        else:
-            raise NotImplementedError(
-                f'no cost specs for geometry kind {g.kind!r}')
-
-    def _blob_aval_entries(self, Cx):
-        """Zero-filled `_blob_device_entries` payload at the `Cx`
-        bucket — the aval source for `serve_export`/`serve_import`
-        cost/lint specs, so the analyzed scatter is shape-identical to
-        the live `import_kv` dispatch by construction."""
-        return self._blob_device_entries(self._pages, Cx)
+        d = self._primary(g)
+        return [(d.fn, d.models + _avals(d.args), d.statics)]
 
     def _geometry_cost_tag(self, g):
         """The dispatch tag `step()` keys its registry notes with, for
         one enumerated geometry — the join key between the manifest's
-        per-geometry costs and the live window-commit MFU math."""
-        p = g.params
-        W = int(p.get('window', self.decode_window))
-        if g.kind == 'serve_step':
-            return ('serve_step', W, int(p['bucket']))
-        if g.kind == 'serve_window':
-            return ('serve_window', W)
-        if g.kind == 'serve_prefill':
-            return ('serve_prefill', int(p['bucket']))
-        if g.kind == 'serve_chunk_step':
-            return ('serve_chunk_step', W, int(p['chunk']),
-                    int(p['bucket']))
-        if g.kind == 'serve_spec_step':
-            return ('serve_spec_step', int(p['spec']), int(p['bucket']),
-                    int(p['ctx']))
-        if g.kind == 'serve_spec_window':
-            return ('serve_spec_window', int(p['spec']), int(p['ctx']))
-        return None
+        per-geometry costs and the live window-commit MFU math, and
+        what `aot.GeometrySet.registry_keys` hands `registry_key`."""
+        if g.kind not in _SERVE_DISPATCHES:
+            raise ValueError(f'unknown serving geometry kind {g.kind!r}')
+        return (g.kind, *(int(g.params[n])
+                          for n in _SERVE_DISPATCHES[g.kind][1]))
 
     def _note_geometry_cost(self, g, cost):
         """Bind one geometry's static flops/bytes (an aot manifest's
@@ -2534,11 +2395,9 @@ class ServingEngine:
         tag. From then on every all-hit window commit derives
         `serve.mfu_est` / roofline gauges from host data alone — the
         static flops and the wall clock the commit already reads."""
-        tag = self._geometry_cost_tag(g)
-        if tag is None or not isinstance(cost, dict) \
-                or not cost.get('flops'):
+        if not isinstance(cost, dict) or not cost.get('flops'):
             return
-        self._dispatch_costs[tag] = cost
+        self._dispatch_costs[self._geometry_cost_tag(g)] = cost
         if self._peak_flops is None:
             from ..observability import costs as _costs
 
@@ -3272,14 +3131,14 @@ class ServingEngine:
             raise RuntimeError(
                 f'request {rid} has no committed KV to export '
                 f'(context_len {req.context_len})')
-        Cx = bucket_length(kvlen, self.buckets)
+        tag = ('serve_export', bucket_length(kvlen, self.buckets))
         dkvlen = None
         with self._use_mesh():
-            hit = self._note('serve_export', Cx)
+            hit = self._note(*tag)
             t_dispatch = time.perf_counter()
             btabs = self._put(self._btab[slot:slot + 1])
             st = self._put(np.asarray([kvlen], np.int32))
-            out = _kv_export(self._pages, btabs, st, ctx_bucket=Cx)
+            out = self._run(self._dispatch(*tag, batch=(btabs, st)))
             dout = None
             if self.draft is not None:
                 # the draft pool's coverage can trail the target's
@@ -3287,17 +3146,18 @@ class ServingEngine:
                 # has; the importer's catch-up machinery fills the rest
                 dkvlen = min(int(self._dctx[slot]), kvlen)
                 dst = self._put(np.asarray([dkvlen], np.int32))
-                dout = _kv_export(self._dpages, btabs, dst, ctx_bucket=Cx)
+                dout = self._run(self._dispatch(
+                    *tag, batch=(btabs, dst), draft=True))
             host = jax.device_get(out)
             dhost = jax.device_get(dout) if dout is not None else None
         t_commit = time.perf_counter()
         if not hit:
             _obs_trace.compile_event(
-                'compile:serve_export', key=('serve_export', Cx),
+                'compile:serve_export', key=tag,
                 dur_s=t_commit - t_dispatch,
                 geometry=str(self._geometry()))
             self._record('compile', dispatch='serve_export',
-                         key=str(('serve_export', Cx)),
+                         key=str(tag),
                          dur_ms=round((t_commit - t_dispatch) * 1e3, 3))
 
         def crop(tmp, n):
@@ -3473,12 +3333,13 @@ class ServingEngine:
         finally:
             a.phase = None
         Cx = bucket_length(kvlen, self.buckets)
+        tag = ('serve_import', Cx)
         dkvlen = None
         if self.draft is not None:
             dkvlen = min(int(blob.get('draft_kv_len') or 0), kvlen)
         try:
             with self._use_mesh():
-                reg_hit = self._note('serve_import', Cx)
+                reg_hit = self._note(*tag)
                 t_dispatch = time.perf_counter()
                 pages_np = np.asarray(pages, np.int32)
                 i = np.arange(Cx)
@@ -3493,8 +3354,8 @@ class ServingEngine:
                     .astype(np.int32))
                 ents = self._blob_device_entries(self._pages, Cx,
                                                  blob['layers'])
-                self._pages = _kv_import(self._pages, ents, pflat,
-                                         sflat, ctx_bucket=Cx)
+                self._run(self._dispatch(
+                    *tag, batch=(ents, pflat, sflat)))
                 if self.draft is not None:
                     drows = (i < dkvlen) & (i >= len(shared) * bs)
                     dpflat = self._put(
@@ -3502,9 +3363,8 @@ class ServingEngine:
                         .astype(np.int32))
                     dents = self._blob_device_entries(
                         self._dpages, Cx, blob['draft_layers'])
-                    self._dpages = _kv_import(self._dpages, dents,
-                                              dpflat, sflat,
-                                              ctx_bucket=Cx)
+                    self._run(self._dispatch(
+                        *tag, batch=(dents, dpflat, sflat), draft=True))
         except Exception:
             a.free(pages)
             self.migration_counts['import_failed'] += 1
@@ -3513,11 +3373,11 @@ class ServingEngine:
         t_commit = time.perf_counter()
         if not reg_hit:
             _obs_trace.compile_event(
-                'compile:serve_import', key=('serve_import', Cx),
+                'compile:serve_import', key=tag,
                 dur_s=t_commit - t_dispatch,
                 geometry=str(self._geometry()))
             self._record('compile', dispatch='serve_import',
-                         key=str(('serve_import', Cx)),
+                         key=str(tag),
                          dur_ms=round((t_commit - t_dispatch) * 1e3, 3))
         # ONE trail follows the request across engines: re-register
         # the source's events FIRST (the journal bumps its seq past
@@ -3764,12 +3624,10 @@ class ServingEngine:
             dev = self._device_state()
             budget = self._put(self._budget)    # shrinks every window
             kernel_pages = self._kernel_pages()
-        common = dict(window=W, eos_token_id=self.eos_token_id)
+        tail = self._window_tail(dev, budget)
         spec = self.draft is not None and not chunk_rows
         kind = ('spec' if spec else 'chunk' if chunk_rows
                 else 'step' if fused is not None else 'window')
-        sample_args = (dev['temp'], dev['topk'], dev['topp'],
-                       dev['seed'], dev['plen'])
         # a fault scripted at kind='window' models the whole worker
         # dying mid-serve and PROPAGATES out of step() by design, so a
         # supervisor snapshots and restores — the crash path
@@ -3825,7 +3683,7 @@ class ServingEngine:
                           if r is not None and self._pfill[s] is None)
             Sb_ctx = bucket_length(max_ctx + k + 1, self.buckets)
             with stage():
-                ftok_d, forced_d = self._forced_state()
+                carried = self._forced_state()
             # draft catch-up first (rows whose commits bypassed the
             # draft on a chunk step): the spec window's proposals must
             # run against complete draft KV. Sb_ctx covers every
@@ -3833,38 +3691,29 @@ class ServingEngine:
             catchup = self._draft_catchup_rows()
             fresh_draft = bool(catchup) and self._draft_advance(
                 catchup, Sb_ctx)
-            scommon = dict(k=k, ctx_bucket=Sb_ctx,
-                           eos_token_id=self.eos_token_id)
             if fused is not None:
                 Sb, group = fused
                 for _s, r in group:
                     r.mark('prefill_dispatch', bucket=Sb, fused=True)
                 with stage():
-                    ids, real_len, btabs, slots = self._prefill_args(
-                        Sb, group)
-                hit = self._note('serve_spec_step', k, Sb, Sb_ctx)
+                    batch = self._prefill_args(Sb, group)
                 dispatch_key = ('serve_spec_step', k, Sb, Sb_ctx)
+                hit = self._note(*dispatch_key)
                 with dispatch(Sb, [r.context_len for _s, r in group]):
-                    (cand, nc, nxt, self._last_logits, self._pages,
-                     self._dpages, ctx_out) = _serve_spec_step(
-                        self.model, self.draft, self._pages, self._dpages,
-                        self._last_logits, ids, real_len, btabs, slots,
-                        ftok_d, forced_d, dev['btab'], dev['ctx'],
-                        dev['live'], budget, *sample_args, **scommon)
+                    cand, nc, nxt, _, _, _, ctx_out = self._run(
+                        self._dispatch(*dispatch_key, batch=batch,
+                                       carried=carried, tail=tail))
                 if self.prefix_cache:
                     for slot, r in group:
                         self._register_prefix_pages(slot, r, 0,
                                                     r.context_len)
             else:
-                hit = self._note('serve_spec_window', k, Sb_ctx)
                 dispatch_key = ('serve_spec_window', k, Sb_ctx)
+                hit = self._note(*dispatch_key)
                 with dispatch():
-                    (cand, nc, nxt, self._last_logits, self._pages,
-                     self._dpages, ctx_out) = _serve_spec_window(
-                        self.model, self.draft, self._pages, self._dpages,
-                        self._last_logits, ftok_d, forced_d, dev['btab'],
-                        dev['ctx'], dev['live'], budget, *sample_args,
-                        **scommon)
+                    cand, nc, nxt, _, _, _, ctx_out = self._run(
+                        self._dispatch(*dispatch_key, carried=carried,
+                                       tail=tail))
             spec_out = (cand, nc, nxt)
             # a fresh draft catch-up shape paid its compile inside
             # this step's wall: count the window as a MISS so the
@@ -3873,12 +3722,11 @@ class ServingEngine:
             self.spec_counts['windows'] += 1
         elif chunk_rows:
             with stage():
-                (ids, clen, cst, btabs, slots, cow_src, cow_dst, Cb,
-                 Sb) = self._chunk_args(chunk_rows)
+                batch, Cb, Sb = self._chunk_args(chunk_rows)
             for _s, r, _p, _t in chunk_rows:
                 r.mark('prefill_dispatch', chunk=True, start=_p, take=_t)
-            hit = self._note('serve_chunk_step', W, Cb, Sb)
             dispatch_key = ('serve_chunk_step', W, Cb, Sb)
+            hit = self._note(*dispatch_key)
             if self.draft is not None:
                 # keep the DRAFT's pages current through the chunk
                 # path: same chunk/CoW args, logits commit dropped —
@@ -3887,10 +3735,8 @@ class ServingEngine:
                 if (Cb, Sb) not in self._draft_shapes:
                     self._draft_shapes.add((Cb, Sb))
                     hit = False          # this step pays its compile
-                self._dlogits, self._dpages = _draft_chunk(
-                    self.draft, self._dpages, self._dlogits, ids, clen,
-                    cst, btabs, self._dummy(self.max_slots), cow_src,
-                    cow_dst, ctx_bucket=Sb)
+                self._run(self._dispatch('serve_draft_chunk', Cb, Sb,
+                                         batch=batch))
                 for s, _r, p, t in chunk_rows:
                     self._dctx[s] = p + t
                 # decoding rows' draft holes (the PREVIOUS chunk-step
@@ -3905,20 +3751,16 @@ class ServingEngine:
                 # decoding rows may carry a pending verify-chosen next
                 # token (spec_next): the chunk window consumes it as
                 # each row's first token
-                ftok_d, forced_d = self._forced_state()
+                carried = self._forced_state()
             else:
                 # non-speculative engines can never have forced rows —
                 # the constant zero uploads skip the per-step scan
-                ftok_d, forced_d = self._zero_ftok, self._zero_forced
+                carried = self._zero_ftok, self._zero_forced
             with dispatch(Cb, [t for _s, _r, _p, t in chunk_rows],
                           self.max_slots):
-                toks, self._last_logits, self._pages, ctx_out, routed = \
-                    _serve_chunk_step(
-                        self.model, self._pages, self._last_logits, ids,
-                        clen, cst, btabs, slots, cow_src, cow_dst,
-                        dev['btab'], dev['ctx'], dev['live'], budget,
-                        *sample_args, ftok_d, forced_d, ctx_bucket=Sb,
-                        **common)
+                toks, _, _, ctx_out, routed = self._run(self._dispatch(
+                    *dispatch_key, batch=batch, carried=carried,
+                    tail=tail))
             self.prefix_counts['chunk_steps'] += 1
             self._inc('serve.chunk_steps')
             if self._cow_release:
@@ -3936,27 +3778,21 @@ class ServingEngine:
             for _s, r in group:
                 r.mark('prefill_dispatch', bucket=Sb, fused=True)
             with stage():
-                ids, real_len, btabs, slots = self._prefill_args(Sb, group)
-            hit = self._note('serve_step', W, Sb)
+                batch = self._prefill_args(Sb, group)
             dispatch_key = ('serve_step', W, Sb)
+            hit = self._note(*dispatch_key)
             with dispatch(Sb, [r.context_len for _s, r in group]):
-                (toks, self._last_logits, self._pages, ctx_out,
-                 routed) = _serve_step(
-                    self.model, self._pages, self._last_logits, ids,
-                    real_len, btabs, slots, dev['btab'], dev['ctx'],
-                    dev['live'], budget, *sample_args, **common)
+                toks, _, _, ctx_out, routed = self._run(self._dispatch(
+                    *dispatch_key, batch=batch, tail=tail))
             if self.prefix_cache:
                 for slot, r in group:
                     self._register_prefix_pages(slot, r, 0, r.context_len)
         else:
-            hit = self._note('serve_window', W)
             dispatch_key = ('serve_window', W)
+            hit = self._note(*dispatch_key)
             with dispatch():
-                toks, self._last_logits, self._pages, ctx_out, routed = \
-                    _serve_window(
-                        self.model, self._pages, self._last_logits,
-                        dev['btab'], dev['ctx'], dev['live'], budget,
-                        *sample_args, **common)
+                toks, _, _, ctx_out, routed = self._run(
+                    self._dispatch(*dispatch_key, tail=tail))
         # the returned ctx equals the host's post-commit view whenever
         # no slot is retired below (retiring invalidates the mirror)
         dev['ctx'] = ctx_out
@@ -4179,10 +4015,9 @@ class ServingEngine:
             start[i] = p
             btabs[i] = self._btab[slot]
         z = self._put(np.zeros((K,), np.int32))
-        self._dlogits, self._dpages = _draft_chunk(
-            self.draft, self._dpages, self._dlogits, self._put(ids),
-            self._put(clen), self._put(start), self._put(btabs),
-            self._dummy(K), z, z, ctx_bucket=Sb)
+        self._run(self._dispatch('serve_draft_chunk', Cb, Sb, batch=(
+            self._put(ids), self._put(clen), self._put(start),
+            self._put(btabs), self._dummy(K), z, z)))
         for slot, req, p, take in rows:
             self._dctx[slot] = p + take
         return fresh
@@ -4465,9 +4300,9 @@ class ServingEngine:
         """Rows of an admission-prefill batch at bucket `Sb`: what the
         token budget holds, at least one and never more than there are
         slots. The ONE place that knows the width: `_admit` splits by
-        it, `_prefill_args` builds it, `_fill` reports it, and
-        `_cost_specs`/`_export_specs` restate it, so the warmed program
-        is the served one."""
+        it, `_prefill_args` builds it (for `step()` and, through
+        `_dispatches`, for warm-up and the specs) and `_fill` reports
+        it."""
         return max(1, min(PREFILL_TOKENS // Sb, self.max_slots))
 
     def _dummy(self, rows):
@@ -4550,15 +4385,12 @@ class ServingEngine:
         with _obs_trace.span(
                 'serve.prefill', cat='scheduler',
                 **self._fill(Sb, [r.context_len for _s, r in group])):
-            ids, real_len, btabs, slots = self._prefill_args(Sb, group)
+            batch = self._prefill_args(Sb, group)
             self._note('serve_prefill', Sb)
-            self._last_logits, self._pages = _paged_prefill(
-                self.model, self._pages, self._last_logits, ids, real_len,
-                btabs, slots)
+            self._run(self._dispatch('serve_prefill', Sb, batch=batch))
             if self.draft is not None:
-                self._dlogits, self._dpages = _paged_prefill(
-                    self.draft, self._dpages, self._dlogits, ids, real_len,
-                    btabs, self._dummy(slots.shape[0]))
+                self._run(self._dispatch('serve_prefill', Sb, batch=batch,
+                                         draft=True))
 
     def _chunk_args(self, rows):
         """Device args for one fixed-width chunk-continuation batch
@@ -4603,7 +4435,7 @@ class ServingEngine:
                 self._cow_release.append(pair[0])
         return (self._put(ids), self._put(clen), self._put(start),
                 self._put(btabs), self._put(slots),
-                self._put(cow_src), self._put(cow_dst), Cb, Sb)
+                self._put(cow_src), self._put(cow_dst)), Cb, Sb
 
     def _chunk_seam_ok(self, rows):
         """Fire the per-dispatch fault seam for the chunk group

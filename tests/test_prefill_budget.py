@@ -220,36 +220,83 @@ def _avals(x):
 
 
 class TestOneFunctionOwnsTheShape:
-    @pytest.mark.parametrize('draft', [False, True], ids=['plain', 'spec'])
-    def test_warmup_then_burst_compiles_nothing(self, small_budget, draft):
+    # engine -> (constructor arguments, workload): between them every
+    # kind `for_serving_engine` enumerates
+    ENGINES = {
+        'plain': ({}, {}),
+        'spec': (dict(num_draft_tokens=2), {}),
+        'chunk': (dict(prefill_chunk=16, prefix_cache=True),
+                  dict(migration=True)),
+        'decode': (dict(phase_role='decode'), {}),
+    }
+    KINDS = {
+        'plain': {'serve_step', 'serve_window', 'serve_prefill'},
+        'spec': {'serve_spec_step', 'serve_spec_window', 'serve_prefill'},
+        'chunk': {'serve_step', 'serve_window', 'serve_prefill',
+                  'serve_chunk_step', 'serve_export', 'serve_import'},
+        'decode': {'serve_import', 'serve_chunk_step', 'serve_window'},
+    }
+
+    @pytest.mark.parametrize('case', list(ENGINES))
+    def test_warmup_then_burst_compiles_nothing(self, small_budget, case):
         # a width no other test uses, so nothing is warm by accident
-        kw = dict(model=_model(hidden_size=48, intermediate_size=80))
-        if draft:
-            kw.update(draft=_model(1, hidden_size=48,
-                                   intermediate_size=80),
-                      num_draft_tokens=2)
+        # (nor the decode pool's programs by the chunked engine's)
+        width = dict(hidden_size=48,
+                     intermediate_size=96 if case == 'decode' else 80)
+        kw, workload = self.ENGINES[case]
+        kw = dict(kw, model=_model(**width))
+        if case == 'spec':
+            kw.update(draft=_model(1, **width))
+        blobs = []
+        if case == 'decode':
+            # what the pool will import, exported before anything is
+            # counted: the source engine compiles programs of its own
+            src = _engine(model=kw['model'])
+            for n in (10, 20, 40):
+                rid = src.submit(_prompt(600 + n, n))
+                src.step()
+                blobs.append((rid, src.export_kv(rid)))
         srv = _engine(**kw)
-        gs = aot.for_serving_engine(srv)
+        gs = aot.for_serving_engine(srv, **workload)
+        assert {g.kind for g in gs} == self.KINDS[case]
         rep = srv.warmup(geometries=gs)
         assert rep['traces'] > 0
         t0, m0 = total_traces(), COMPILE_CACHE.misses
-        # the specs restate the warmed programs: lowering them traces
-        # nothing, and the export avals are the cost avals
+        # the specs ARE the warmed programs: lowering them traces
+        # nothing, the export avals are the cost avals less the
+        # model(s), and the tag is the one inside the registry key
         step_kinds = ('serve_step', 'serve_prefill', 'serve_spec_step')
         for g in gs:
             spec = g.kind.startswith('serve_spec')
-            skip = 2 if spec else 1           # the model(s) lead the args
             costs = list(srv._cost_specs(g))
             for fn, args, statics in costs:
                 fn.lower(*args, **statics)
-            if g.kind in step_kinds:
+            (_, args, _), = costs
+            skip = sum(isinstance(a, pt.nn.Layer) for a in args)
+            assert skip == (0 if g.kind in ('serve_export', 'serve_import')
+                            else 2 if spec else 1)  # the model(s) lead
+            key, = aot.GeometrySet([g]).registry_keys(srv)
+            assert key == srv.registry_key(*srv._geometry_cost_tag(g))
+            assert srv._geometry_cost_tag(g)[0] == g.kind
+            if skip:
                 (_, _, exported), = srv._export_specs(g)
-                (_, args, _), = costs
                 assert _avals(exported) == _avals(args[skip:])
+            else:
+                with pytest.raises(NotImplementedError):
+                    list(srv._export_specs(g))
+            if g.kind in step_kinds:
                 rows = srv._prefill_rows(g.params['bucket'])
                 ids = args[skip + (3 if spec else 2)]   # after the pools
                 assert ids.shape == (rows, g.params['bucket'])
         assert total_traces() - t0 == 0
+        if case == 'decode':
+            for rid, blob in blobs:
+                srv.import_kv(rid, blob)
+            srv.run()
+            assert all(srv.result(rid) is not None for rid, _ in blobs)
+            assert total_traces() - t0 == 0
+            assert COMPILE_CACHE.misses - m0 == 0
+            return
         # fused and standalone dispatches of every bucket
         bursts = [[40, 41],                   # 64: rows 1 -> fused + alone
                   [20, 21, 22, 23],           # 32: rows 2 -> fused + alone
@@ -265,7 +312,10 @@ class TestOneFunctionOwnsTheShape:
                 for e in obs.TRACER.events()
                 if e['name'] in ('serve.dispatch', 'serve.prefill')
                 and e['args'].get('rows')}
-        assert seen == {(name, b) for b in BUCKETS
+        # past `prefill_chunk` an admission rides the chunk step: only
+        # the 16 bucket is left to the fused and the standalone prefill
+        assert seen == {(name, b)
+                        for b in (BUCKETS[:1] if case == 'chunk' else BUCKETS)
                         for name in ('serve.dispatch', 'serve.prefill')}
         assert total_traces() - t0 == 0
         assert COMPILE_CACHE.misses - m0 == 0
