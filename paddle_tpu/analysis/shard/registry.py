@@ -609,14 +609,19 @@ ENTRIES = (
     # regression this suite exists to catch before a real pod does.
     # PR 15 moved the sampling params from jit statics to replicated
     # per-slot DEVICE data: the batched top-k/top-p filter and the
-    # per-row sampler run over the vocab-parallel logits in every
+    # per-row sampler work over the vocab-parallel logits of every
     # window body. As jax 0.9.0 partitions them (re-recorded in PR 21)
     # that is 5 all-reduces per body (reduce_max, two reduce_sums, two
     # take_along_axis gathers), sub-KB all-gather pins on the sort and
     # sampling outputs, and 1 byte-scale collective-permute (the rev of
-    # the descending sort) — all flat in batch and model size. Counts
-    # stay exact; byte ceilings carry ~25% headroom over the measured
-    # payload.
+    # the descending sort) — all flat in batch and model size. Since
+    # PR 31 the filter's and the draw's collectives sit in the branch
+    # computations of the sampler's `lax.cond`s (the predicates are
+    # replicated scalars: every device takes the same branch, so they
+    # stay matched) and run only when a live row asks; the census
+    # counts call sites in the whole module and reads as before.
+    # Counts stay exact; byte ceilings carry ~25% headroom over the
+    # measured payload.
     Entry('serving/serve_step_tp', _SRV, _build_serving_serve_step,
           budget={'all-reduce': {'count': 15, 'bytes': 112 * KB},
                   'all-gather': {'count': 9, 'bytes': 13 * KB},

@@ -809,42 +809,92 @@ def _row_keys(seed, gen, sub):
                          jnp.asarray(gen, jnp.int32))
 
 
-def _sample_rows(logits, temp, topk, topp, keys):
+# What a batch's rows ask the sampler for (`_row_asks`): the per-row
+# params with the rows that only take an argmax neutralised, and the
+# three scalars the sampler's `lax.cond`s branch on.
+_RowAsks = collections.namedtuple(
+    '_RowAsks', 'temp topk topp any_sampled any_top_k any_top_p')
+
+
+def _row_asks(temp, topk, topp, live):
+    """The sampler does the work the LIVE rows ask for. A greedy row
+    (temp == 0) or an empty slot asks for no filter, whatever top_k /
+    top_p it carries (`submit(prompt, top_k=50)` at temperature 0 is
+    legal): its params are neutralised here, its filtered dist is never
+    consumed. The scalars say whether any row samples, any asks for the
+    top-k filter, any for the nucleus: replicated device data that does
+    not change inside a window, so a caller computes them ONCE, outside
+    its scan, and the scan closes over them."""
+    sampled = (temp > 0) & live
+    temp = jnp.where(sampled, temp, 0.0)
+    topk = jnp.where(sampled, jnp.asarray(topk, jnp.int32), 0)
+    topp = jnp.where(sampled, jnp.asarray(topp, jnp.float32), 1.0)
+    return _RowAsks(temp, topk, topp, jnp.any(sampled),
+                    jnp.any(topk > 0), jnp.any(topp < 1.0))
+
+
+def _filtered_logits(lg, asks):
+    """Per-row tempered and filtered float32 logits (rows with
+    temp == 0 use temp 1). The filters the batch does not ask for are
+    skipped on the device (`filter_logits_batched`)."""
+    from ..models.generation import filter_logits_batched
+
+    safe_t = jnp.where(asks.temp > 0, asks.temp, 1.0)
+    return filter_logits_batched(
+        lg / safe_t[:, None], asks.topk, asks.topp,
+        any_top_k=asks.any_top_k, any_top_p=asks.any_top_p)
+
+
+def _draw_rows(f, greedy, asks, seed, gen):
+    """Sampled rows draw from their filtered logits `f` under their own
+    stateless key; greedy rows keep their argmax."""
+    keys = _row_keys(seed, gen, _SUB_PROPOSE)
+    sampled = jax.vmap(jax.random.categorical)(keys, f).astype(jnp.int32)
+    return jnp.where(asks.temp > 0, sampled, greedy)
+
+
+def _sample_rows(logits, asks, seed, gen):
     """Per-row next-token choice over one batch of logits: greedy
     argmax where temp == 0, categorical over the row's filtered /
-    tempered distribution elsewhere — all branches live in ONE trace,
-    so a batch mixing greedy and sampled rows (the per-request
-    sampling contract) never retraces as the mix changes. (The unused
-    dist output is dead code XLA eliminates — one sampling body, no
-    drift between decode windows and draft proposals.)"""
-    return _sample_rows_dist(logits, temp, topk, topp, keys)[0]
+    tempered distribution elsewhere. A batch pays for what its live
+    rows ask for: the per-row keys, the filter and the categorical draw
+    run under a `lax.cond` on "any row samples", so an all-greedy batch
+    takes an argmax and nothing else, and inside it each filter runs
+    only if a row asks for it. Both sides of every `cond` live in ONE
+    trace, so a batch mixing greedy and sampled rows (the per-request
+    sampling contract) never retraces as the mix changes; a sampled
+    row's token is the same whichever rows sit beside it."""
+    lg = logits.astype(jnp.float32)
+    greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+    return jax.lax.cond(
+        asks.any_sampled,
+        lambda: _draw_rows(_filtered_logits(lg, asks), greedy, asks, seed,
+                           gen),
+        lambda: greedy)
 
 
-def _filtered_dist(logits, temp, topk, topp):
+def _filtered_dist(logits, asks):
     """Per-row filtered/tempered probability dist over (K, V) logits
     (rows with temp == 0 use temp 1 — their dist is never consumed;
     the greedy rule takes argmax instead)."""
-    from ..models.generation import filter_logits_batched
-
-    lg = logits.astype(jnp.float32)
-    safe_t = jnp.where(temp > 0, temp, 1.0)
     return jax.nn.softmax(
-        filter_logits_batched(lg / safe_t[:, None], topk, topp), -1)
+        _filtered_logits(logits.astype(jnp.float32), asks), -1)
 
 
-def _sample_rows_dist(logits, temp, topk, topp, keys):
+def _sample_rows_dist(logits, asks, seed, gen):
     """`_sample_rows` + the row's filtered dist from ONE shared filter
     pass (the speculative draft loop needs both per proposal — two
     separate calls would double the full-vocab sorts in the hottest
-    scan of the spec window)."""
-    from ..models.generation import filter_logits_batched
-
+    scan of the spec window). The dist has to exist, so the filter pass
+    stands outside the "any row samples" `cond`; the draw is inside."""
     lg = logits.astype(jnp.float32)
     greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-    safe_t = jnp.where(temp > 0, temp, 1.0)
-    f = filter_logits_batched(lg / safe_t[:, None], topk, topp)
-    sampled = jax.vmap(jax.random.categorical)(keys, f).astype(jnp.int32)
-    return jnp.where(temp > 0, sampled, greedy), jax.nn.softmax(f, -1)
+    f = _filtered_logits(lg, asks)
+    tok = jax.lax.cond(
+        asks.any_sampled,
+        lambda: _draw_rows(f, greedy, asks, seed, gen),
+        lambda: greedy)
+    return tok, jax.nn.softmax(f, -1)
 
 
 def _prefill_kv(model, pages, ids, real_len, btabs):
@@ -900,8 +950,9 @@ def _window_body(model, pages, last_logits, btab, ctx, live, budget,
     `_serve_step`): per step, choose every slot's next token from the
     carried logits under ITS OWN sampling params (temp/topk/topp/seed
     ride as (SLOTS,) device data — a batch mixing greedy and sampled
-    requests shares this one trace, and changing the mix never
-    retraces), step the model over the paged caches (per-row write
+    requests shares this one trace, changing the mix never retraces,
+    and the sampler does only what the live rows ask for: `_row_asks`),
+    step the model over the paged caches (per-row write
     positions = ctx, attention through the block tables), advance the
     committed length of live rows. Sampled rows draw their key
     statelessly from (request seed, generated-token index), so a
@@ -920,11 +971,11 @@ def _window_body(model, pages, last_logits, btab, ctx, live, budget,
 
     pad_tok = eos_token_id if eos_token_id is not None else 0
     plen = jnp.asarray(plen, jnp.int32)
+    asks = _row_asks(temp, topk, topp, live)     # once, outside the scan
 
     def step(carry, t):
         last_logits, pages, ctx, finished = carry
-        keys = _row_keys(seed, ctx - plen, _SUB_PROPOSE)
-        tok = _sample_rows(last_logits, temp, topk, topp, keys)
+        tok = _sample_rows(last_logits, asks, seed, ctx - plen)
         if forced is not None:
             # a speculative engine's chunk step: rows carrying a
             # pending verify-chosen next-token (incl. the rejection
@@ -1043,18 +1094,17 @@ def _spec_window_impl(target, draft, pages, dpages, last_logits,
     plen = jnp.asarray(plen, jnp.int32)
     budget = jnp.asarray(budget, jnp.int32)
     gen0 = ctx - plen
-    sampled_row = temp > 0
-    keys0 = _row_keys(seed, gen0, _SUB_PROPOSE)
+    asks = _row_asks(temp, topk, topp, live)     # once, outside the scan
+    sampled_row = asks.temp > 0
     cand0 = jnp.where(forced, jnp.asarray(forced_tok, jnp.int32),
-                      _sample_rows(last_logits, temp, topk, topp, keys0))
+                      _sample_rows(last_logits, asks, seed, gen0))
 
     def dstep(carry, i):
         tok, dpages = carry
         dlogits, dpages = draft(tok[:, None], caches=dpages,
                                 kv_write_pos=ctx + i, block_tables=btab)
-        gkeys = _row_keys(seed, gen0 + i + 1, _SUB_PROPOSE)
-        nxt, pd = _sample_rows_dist(dlogits[:, -1, :], temp, topk,
-                                    topp, gkeys)
+        nxt, pd = _sample_rows_dist(dlogits[:, -1, :], asks, seed,
+                                    gen0 + i + 1)
         return (nxt, dpages), (nxt, pd)
 
     (_, dpages), (toks, pds) = jax.lax.scan(
@@ -1069,10 +1119,11 @@ def _spec_window_impl(target, draft, pages, dpages, last_logits,
     tlg = tlogits.astype(jnp.float32)                      # (K, k+1, V)
     tchoice = jnp.argmax(tlg, axis=-1).astype(jnp.int32)   # (K, k+1)
     # per-row filtered target dists at every window position
-    flat = tlg.reshape(K * (k + 1), V)
     rep = lambda x: jnp.repeat(x, k + 1, axis=0)  # noqa: E731
-    pt = _filtered_dist(flat, rep(temp), rep(topk),
-                        rep(topp)).reshape(K, k + 1, V)
+    pt = _filtered_dist(
+        tlg.reshape(K * (k + 1), V),
+        asks._replace(temp=rep(asks.temp), topk=rep(asks.topk),
+                      topp=rep(asks.topp))).reshape(K, k + 1, V)
     # accept rule per draft position
     greedy_acc = drafts == tchoice[:, :k]
     px_t = jnp.take_along_axis(pt[:, :k, :], drafts[:, :, None],
@@ -3617,13 +3668,14 @@ class ServingEngine:
             # the fill of its fused admission: zeros for a bare window
             return _obs_trace.span(
                 'serve.dispatch', cat='scheduler', kind=kind, live=live,
-                slots=self.max_slots, **kernel_pages,
+                slots=self.max_slots, **sampler_asks, **kernel_pages,
                 **self._fill(bucket, real_lens, padded_rows))
 
         with stage():
             dev = self._device_state()
             budget = self._put(self._budget)    # shrinks every window
             kernel_pages = self._kernel_pages()
+            sampler_asks = self._sampler_asks()
         tail = self._window_tail(dev, budget)
         spec = self.draft is not None and not chunk_rows
         kind = ('spec' if spec else 'chunk' if chunk_rows
@@ -4319,6 +4371,17 @@ class ServingEngine:
         request and is not mid chunked prefill."""
         return [r is not None and self._pfill[i] is None
                 for i, r in enumerate(self._slot_req)]
+
+    def _sampler_asks(self):
+        """What the live rows ask the sampler for, as `serve.dispatch`
+        reports it (the host's mirror of `_row_asks`): `sampled`, the
+        live rows with a temperature, and `filtered`, those of them with
+        a top-k or a nucleus. At 0 the dispatch takes an argmax a row
+        and sorts nothing."""
+        sampled = (self._temp > 0) & np.asarray(self._live_rows())
+        filtered = sampled & ((self._topk > 0) | (self._topp < 1.0))
+        return {'sampled': int(sampled.sum()),
+                'filtered': int(filtered.sum())}
 
     def _kernel_pages(self):
         """The paged kernel's work in one token-step, as `serve.dispatch`

@@ -185,7 +185,8 @@ def dequantize_kv_row(q, scale, dtype):
     return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
-def filter_logits_batched(logits, top_k, top_p):
+def filter_logits_batched(logits, top_k, top_p, *, any_top_k=None,
+                          any_top_p=None):
     """Per-ROW top-k / nucleus filtering: `top_k` (B,) int32 and
     `top_p` (B,) f32 ride as DEVICE data, so a batch can mix greedy,
     top-k, and nucleus rows in one trace (the serving engine's
@@ -193,21 +194,42 @@ def filter_logits_batched(logits, top_k, top_p):
     per row match `filter_logits` exactly: top_k <= 0 keeps all,
     top_k > V clamps to keep-all, top_p == 1.0 is a no-op (masked, not
     skipped — the cumsum's float roundoff must not drop valid tokens
-    for keep-all rows)."""
+    for keep-all rows).
+
+    A batch pays for the filters its rows ask for: the top-k sort runs
+    under a `lax.cond` on "any row has top_k > 0", the nucleus sort,
+    softmax and cumsum under one on "any row has top_p < 1". A row that
+    keeps everything is masked out of either, so skipping them changes
+    no row of any mix. `any_top_k` / `any_top_p` are those scalars when
+    the caller already holds them (a scan closes over them: they do not
+    change inside a window). Never call this under `vmap`: a batched
+    predicate lowers to a select and both sides run."""
     V = logits.shape[-1]
-    k = jnp.clip(jnp.asarray(top_k, jnp.int32), 1, V)
-    srt = jnp.sort(logits, axis=-1)
-    kth = jnp.take_along_axis(srt, (V - k)[:, None], axis=-1)
-    logits = jnp.where((jnp.asarray(top_k, jnp.int32) > 0)[:, None],
-                       jnp.where(logits < kth, -jnp.inf, logits), logits)
+    top_k = jnp.asarray(top_k, jnp.int32)
     tp = jnp.asarray(top_p, jnp.float32)
-    sorted_desc = jnp.flip(jnp.sort(logits, axis=-1), -1)
-    probs = jax.nn.softmax(sorted_desc, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    cutoff_idx = jnp.sum(cum < tp[:, None], axis=-1, keepdims=True)
-    cutoff = jnp.take_along_axis(sorted_desc, cutoff_idx, axis=-1)
-    nucleus = jnp.where(logits < cutoff, -jnp.inf, logits)
-    return jnp.where((tp < 1.0)[:, None], nucleus, logits)
+    if any_top_k is None:
+        any_top_k = jnp.any(top_k > 0)
+    if any_top_p is None:
+        any_top_p = jnp.any(tp < 1.0)
+
+    def keep_top_k(logits):
+        k = jnp.clip(top_k, 1, V)
+        srt = jnp.sort(logits, axis=-1)
+        kth = jnp.take_along_axis(srt, (V - k)[:, None], axis=-1)
+        return jnp.where((top_k > 0)[:, None],
+                         jnp.where(logits < kth, -jnp.inf, logits), logits)
+
+    def keep_nucleus(logits):
+        sorted_desc = jnp.flip(jnp.sort(logits, axis=-1), -1)
+        probs = jax.nn.softmax(sorted_desc, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        cutoff_idx = jnp.sum(cum < tp[:, None], axis=-1, keepdims=True)
+        cutoff = jnp.take_along_axis(sorted_desc, cutoff_idx, axis=-1)
+        nucleus = jnp.where(logits < cutoff, -jnp.inf, logits)
+        return jnp.where((tp < 1.0)[:, None], nucleus, logits)
+
+    logits = jax.lax.cond(any_top_k, keep_top_k, lambda lg: lg, logits)
+    return jax.lax.cond(any_top_p, keep_nucleus, lambda lg: lg, logits)
 
 
 class GenerationMixin:
