@@ -190,6 +190,23 @@ _SERVING = ClassDecl(
                                   'preempted and re-place'),
         '_slot_pages': derived(note='per-slot page lists; re-placement'),
         '_btab': derived(note='block tables; re-placement'),
+        # a two-kind model's recycled kind of page (its description is
+        # the model's, which rides the refusal set): twins of the above
+        '_kinds': derived(note="the model's page_kinds(); rebuilt from "
+                               'the model at construction'),
+        '_ring': derived(note='index of the recycled kind in _kinds'),
+        'win_allocator': derived(
+            note='recycled-kind page maps rebuild by re-placement; its '
+                 'pool size follows max_slots, decode_window and the '
+                 "kind's window"),
+        'win_pages_per_slot': derived(note='the bound a slot holds of '
+                                           'the recycled kind'),
+        '_slot_wpages': derived(note='per-slot recycled-kind page '
+                                     'lists; re-placement'),
+        '_wfirst': derived(note='first logical page a slot holds of '
+                                'the recycled kind; re-placement'),
+        '_wtab': derived(note='recycled-kind block tables; '
+                              're-placement'),
         '_ctx': derived(note='per-slot context lengths; re-prefill'),
         '_dctx': derived(note='draft-pool context lengths; catch-up'),
         '_plen': derived(note='per-slot prompt lengths'),

@@ -121,6 +121,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..distributed.moe import ROUTING_FIELDS
+from ..models.generation import layer_kinds, pad_lanes, pool_rows_set
 from ..observability import journal as _journal
 from ..observability import metrics as _obs
 from ..observability import timeseries as _obs_ts
@@ -203,6 +204,13 @@ class RequestCancelled(RequestError):
     queue by a higher-priority arrival (`reason` says which)."""
 
     state = 'cancelled'
+
+
+class PageKindsUnsupported(NotImplementedError):
+    """A model that keeps more than one kind of KV page
+    (`GenerationMixin.page_kinds`) was asked to serve with an option the
+    second kind has no path through yet. Raised at construction, naming
+    the option, so that nothing is half-run."""
 
 
 class BlockAllocator:
@@ -754,10 +762,14 @@ def _pool_scatter(pc, tmp_entry, pflat, sflat, take=None):
         idx4 = take[:, :, None, None]
         k = jnp.take_along_axis(k, idx4, axis=1)
         v = jnp.take_along_axis(v, idx4, axis=1)
-    rows = (pflat.shape[0],) + k.shape[2:]
-    return type(pc)(
-        pc.kp.at[pflat, :, sflat, :].set(k.reshape(rows).astype(pc.kp.dtype)),
-        pc.vp.at[pflat, :, sflat, :].set(v.reshape(rows).astype(pc.vp.dtype)))
+    def rows(x, pool):
+        """x's (K, S) rows flat, at the pool's width (a row wider than a
+        lane tile is kept in whole tiles: `generation.lane_padded`)."""
+        return pad_lanes(x.reshape((pflat.shape[0],) + x.shape[2:]).astype(
+            pool.dtype), pool.shape[-1])
+
+    return type(pc)(pool_rows_set(pc.kp, pflat, sflat, rows(k, pc.kp)),
+                    pool_rows_set(pc.vp, pflat, sflat, rows(v, pc.vp)))
 
 
 def _pool_gather(pages, btabs, st, Sb):
@@ -910,8 +922,10 @@ def _prefill_kv(model, pages, ids, real_len, btabs):
     width K is read off `ids`: the engine fixes ONE width per bucket
     (`ServingEngine._prefill_rows`, a token budget) and real lengths
     ride as device data, so one compilation per bucket serves every
-    admission count and every prompt length in the bucket. Returns
-    (per-row last-token logits (K, V), pages)."""
+    admission count and every prompt length in the bucket. `btabs` is
+    one table a kind of page where the model keeps several
+    (`GenerationMixin.page_kinds`), and a layer's rows go by its kind's.
+    Returns (per-row last-token logits (K, V), pages)."""
     K, Sb = ids.shape
     tmp = _tmp_cache(model, pages, K, Sb)
     logits, tmp = model(ids, caches=tmp, cache_index=0)
@@ -919,16 +933,24 @@ def _prefill_kv(model, pages, ids, real_len, btabs):
     last = jnp.take_along_axis(
         logits, jnp.maximum(rl - 1, 0)[:, None, None], axis=1)[:, 0]
     bs = pages[0].kp.shape[2]
-    maxb = btabs.shape[1]
     s = jnp.arange(Sb)
-    blk = jnp.minimum(s // bs, maxb - 1)
-    page = jnp.where(s[None, :] < rl[:, None],
-                     jnp.take_along_axis(btabs, blk[None, :], axis=1),
-                     0)                                       # (K, Sb)
-    pflat = page.reshape(-1)
+
+    def targets(tab):
+        """Flat page ids of the (K, Sb) positions in one kind's table: an
+        entry of 0 (a pad, a dummy row, a page recycled behind the
+        kind's window) sends the row to the scratch page."""
+        blk = jnp.minimum(s // bs, tab.shape[1] - 1)
+        page = jnp.where(s[None, :] < rl[:, None],
+                         jnp.take_along_axis(tab, blk[None, :], axis=1),
+                         0)                                   # (K, Sb)
+        return page.reshape(-1)
+
+    kinds = model.page_kinds()
+    pflat = [targets(t) for t in (btabs if len(kinds) > 1 else (btabs,))]
     sflat = jnp.broadcast_to(s % bs, (K, Sb)).reshape(-1)
-    out_pages = [_pool_scatter(pc, t, pflat, sflat)
-                 for t, pc in zip(tmp, pages)]
+    out_pages = [_pool_scatter(pc, t, pflat[i], sflat)
+                 for t, pc, i in zip(tmp, pages,
+                                     layer_kinds(kinds, len(pages)))]
     return last, out_pages
 
 
@@ -1440,9 +1462,15 @@ class ServingEngine:
     Greedy outputs per request are exactly `DecodeEngine.generate`'s
     batch-1 outputs (eos-padded to max_new_tokens, prompt echoed back).
     The model must accept `block_tables` in its cached forward (the
-    Llama family and AFMoE do). Sliding-window layers, alone or mixed
-    with full ones, keep every page allocated: the kernel skips the
-    pages behind a window, the allocator does not free them. A model's
+    Llama family, AFMoE and MiMo-V2 do). A model states its kinds of KV
+    page (`GenerationMixin.page_kinds`); each kind has its own pools,
+    allocator and block table. A kind with no window keeps every page
+    of a context (the sliding-window layers of a one-kind model too: the
+    kernel skips the pages behind a window, the allocator does not free
+    them); a kind WITH a window holds at most `ceil((window +
+    decode_window) / block_size) + 1` pages a slot, the pages wholly
+    behind the window going back to its free list as the row decodes
+    (docs/serving.md#kinds-of-page). A model's
     `distributed.moe.ExpertShare` layers report what they routed with
     each window's one host read (`serve.routing`).
     """
@@ -1595,14 +1623,27 @@ class ServingEngine:
                 mesh = None          # degree 1 IS single-device serving
         self.mesh = mesh
         self.tp = int(mesh.shape['tp']) if mesh is not None else 1
+        # the model's kinds of KV page (docs/serving.md#kinds-of-page):
+        # the one description pools, allocators, tables and the page
+        # counts of `serve.dispatch` read. `_ring` is the index of the
+        # kind whose pages are recycled behind its window, None for a
+        # model of one kind (every path below is then the one it was).
+        self._kinds = tuple(model.page_kinds())
+        self._ring = None
+        if len(self._kinds) > 1:
+            self._ring = self._two_kinds(
+                model, prefix_cache=prefix_cache,
+                prefill_chunk=prefill_chunk, draft=draft, tp=self.tp,
+                kv_cache_dtype=('int8' if self.kv_cache_dtype == jnp.int8
+                                else None),      # bfloat16 quantizes nothing
+                phase_role=phase_role)
         self._rep = None             # replicated NamedSharding, lazy below
         if self.mesh is not None:
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
 
             self._rep = NamedSharding(self.mesh, P())
-            kvh = (getattr(model.config, 'num_key_value_heads', None)
-                   or model.config.num_attention_heads)
+            kvh = self._kinds[0].kv_heads
             if kvh % self.tp != 0:
                 import warnings
 
@@ -1647,8 +1688,20 @@ class ServingEngine:
             # actually exercise preemption
             num_blocks = self.max_slots * self.max_blocks_per_seq + 1
         self.allocator = BlockAllocator(num_blocks, self.block_size)
+        # the recycled kind's own allocator, sized so that every slot
+        # can hold its bound: its pool never runs dry of itself
+        self.win_allocator = None
+        if self._ring is not None:
+            self.win_pages_per_slot = _ceil_div(
+                self._kinds[self._ring].window + self.decode_window,
+                self.block_size) + 1
+            self.win_allocator = BlockAllocator(
+                self.max_slots * self.win_pages_per_slot + 1,
+                self.block_size)
         if self._jr is not _journal.JOURNAL:
             self.allocator.journal = self._jr
+            if self.win_allocator is not None:
+                self.win_allocator.journal = self._jr
         self.queue = RequestQueue()
         # admission control / load shedding (docs/serving.md#resilience):
         # max_queue bounds what submit() will hold (QueueFull past it —
@@ -1692,7 +1745,9 @@ class ServingEngine:
         # dispatch sees the same input shardings the first one did.
         with self._use_mesh():
             self._pages = model.init_paged_cache(
-                num_blocks, self.block_size, dtype=self.kv_cache_dtype)
+                self._by_kind(num_blocks, self.win_allocator
+                              and self.win_allocator.num_blocks),
+                self.block_size, dtype=self.kv_cache_dtype)
             self._dpages = None
             if self.draft is not None:
                 # the draft's pools share the target's page-id space:
@@ -1745,9 +1800,20 @@ class ServingEngine:
                 int(np.prod(f.shape[1:])) * f.dtype.itemsize
                 for pc in pages for f in pc))
 
-        self.allocator.bytes_per_page = (
-            _pool_page_bytes(self._pages)
-            + (_pool_page_bytes(self._dpages) if self._dpages else 0))
+        if self._ring is None:
+            self.allocator.bytes_per_page = (
+                _pool_page_bytes(self._pages)
+                + (_pool_page_bytes(self._dpages) if self._dpages else 0))
+        else:
+            # a page id of a kind stands for a page in each of THAT
+            # kind's layers
+            by_kind = [[pc for pc, k in zip(self._pages, layer_kinds(
+                self._kinds, len(self._pages))) if k == i]
+                for i in range(2)]
+            self.win_allocator.bytes_per_page = _pool_page_bytes(
+                by_kind[self._ring])
+            self.allocator.bytes_per_page = _pool_page_bytes(
+                by_kind[1 - self._ring])
 
         # host-authoritative per-slot state (device copies ride in as
         # small int32/bool args each window)
@@ -1755,6 +1821,14 @@ class ServingEngine:
         self._slot_pages: list = [[] for _ in range(self.max_slots)]
         self._btab = np.zeros((self.max_slots, self.max_blocks_per_seq),
                               np.int32)
+        # the recycled kind's twin of (_slot_pages, _btab): a slot holds
+        # the pages of logical indices [_wfirst, _wfirst + len) and the
+        # table's other entries are 0 (never read behind the window; a
+        # prefill's rows for them land on the scratch page)
+        self._slot_wpages: list = [[] for _ in range(self.max_slots)]
+        self._wfirst = [0] * self.max_slots
+        self._wtab = (np.zeros_like(self._btab) if self._ring is not None
+                      else None)
         self._ctx = np.zeros((self.max_slots,), np.int32)
         # (window, layers) per distinct attention window, 0 = the whole
         # context: what `_kernel_pages` counts the paged kernel's work by
@@ -1932,6 +2006,51 @@ class ServingEngine:
 
     # -- bookkeeping -------------------------------------------------------
 
+    def _two_kinds(self, model, **options):
+        """The index of the recycled kind of a two-kind model, after
+        refusing what a second kind of page has no path through yet
+        (ROADMAP M2 queues each)."""
+        name = type(model).__name__
+        ring = [i for i, k in enumerate(self._kinds) if k.window is not None]
+        if len(self._kinds) != 2 or len(ring) != 1:
+            raise PageKindsUnsupported(
+                f'{name} states {len(self._kinds)} kinds of KV page, '
+                f'{len(ring)} with a window: the engine runs one kind, or '
+                f'one that keeps every page beside one recycled behind '
+                f'its window')
+        why = {
+            'prefix_cache': 'the prefix index shares pages of one kind',
+            'prefill_chunk': 'a chunk continuation gathers its prefix '
+                             'from one table',
+            'draft': "a draft's pools mirror one table",
+            'tp': 'the pools of two kinds have no sharding rule',
+            'kv_cache_dtype': 'int8 pools have one shape',
+            'phase_role': 'a migration blob holds one kind of page'}
+        plain = {'prefix_cache': False, 'prefill_chunk': None,
+                 'draft': None, 'tp': 1, 'kv_cache_dtype': None,
+                 'phase_role': 'monolithic'}
+        for option, value in options.items():
+            if value != plain[option]:
+                raise PageKindsUnsupported(
+                    f'{name} keeps two kinds of KV page '
+                    f'({", ".join(k.name for k in self._kinds)}): '
+                    f'ServingEngine({option}={value!r}) is not carried for '
+                    f'it ({why[option]})')
+        return ring[0]
+
+    def _one_kind(self, what):
+        if self._ring is not None:
+            raise PageKindsUnsupported(
+                f'{type(self.model).__name__} keeps two kinds of KV page: '
+                f'{what} moves one kind (a migration blob holds one table)')
+
+    def _by_kind(self, kept, recycled):
+        """One value a kind of page in the model's own order, or `kept`
+        alone for a model of one kind."""
+        if self._ring is None:
+            return kept
+        return (recycled, kept) if self._ring == 0 else (kept, recycled)
+
     @contextlib.contextmanager
     def _use_mesh(self):
         """Pin the process-global mesh to THIS engine's mesh (None
@@ -2068,12 +2187,22 @@ class ServingEngine:
         if not _obs.enabled():
             return
         m = self._metrics()
-        a = self.allocator
+        a, wa = self.allocator, self.win_allocator
         occ = (self.in_flight(), len(self.queue), a.in_use(),
                a.cached(), a.shared(), a.cow_count)
+        if wa is not None:
+            occ += (wa.in_use(),)
         if occ == self._last_occ:
             return
         self._last_occ = occ
+        if wa is not None:
+            # the recycled kind's pool beside the other's `pool.*`
+            self._set_gauge('pool.window.pages_in_use', occ[6])
+            self._set_gauge('pool.window.utilization', wa.utilization())
+            self._set_gauge('pool.window.bytes_in_use',
+                            occ[6] * wa.bytes_per_page)
+            self._set_gauge('pool.window.bytes_total',
+                            wa.num_blocks * wa.bytes_per_page)
         m['in_flight'].set(occ[0])
         m['queue_depth'].set(occ[1])
         m['pages_in_use'].set(occ[2])
@@ -2142,6 +2271,9 @@ class ServingEngine:
             'phase_role': self.phase_role,
             'migration': dict(self.migration_counts),
             'blocks': self.allocator.stats(),
+            # the recycled kind's pool of a two-kind model, else None
+            'blocks_window': (self.win_allocator.stats()
+                              if self.win_allocator is not None else None),
             'geometry': {'kind': 'paged', 'max_slots': self.max_slots,
                          'block_size': self.block_size,
                          'num_blocks': self.allocator.num_blocks,
@@ -3162,6 +3294,7 @@ class ServingEngine:
         to the source engine's own. Read-only: the request keeps
         serving here until its owner retires it (PrefillEngine's
         handoff sweep, or `cancel()`)."""
+        self._one_kind('export_kv')
         t0 = time.perf_counter()
         req = self._live.get(rid)
         if req is None or req.state != 'running':
@@ -3277,6 +3410,7 @@ class ServingEngine:
         (OutOfBlocks), schema/config/dtype mismatch (ValueError) —
         rolls back every page and refcount taken and leaves the engine
         exactly as before the call. Returns the slot index."""
+        self._one_kind('import_kv')
         t0 = time.perf_counter()
         rid = int(rid)
         if (blob.get('schema') != SNAPSHOT_SCHEMA
@@ -4090,24 +4224,24 @@ class ServingEngine:
 
     def _device_state(self):
         """Device copies of the per-slot scheduler state, cached until
-        a slot mutation invalidates them (self._dev = None). Slots mid
+        a slot mutation invalidates them (self._dev = None), or the
+        tables alone where a live slot only took or gave back pages
+        (`_tables_changed`). Slots mid
         chunked prefill ride the decode window FROZEN on the scratch
         page: their real block tables stay host-side (the chunk
         dispatch gets them as explicit args), so the window's clamped
         frozen-row write can never touch a page a chunk is still
         filling."""
         if self._dev is None:
-            btab, ctx = self._btab, self._ctx
+            ctx = self._ctx
             live = self._live_rows()
             if any(p is not None for p in self._pfill):
-                btab = btab.copy()
                 ctx = ctx.copy()
                 for i, p in enumerate(self._pfill):
                     if p is not None:
-                        btab[i] = 0
                         ctx[i] = 0
             self._dev = {
-                'btab': self._put(btab),
+                'btab': self._put_tables(),
                 'ctx': self._put(ctx),
                 'live': self._put(np.asarray(live)),
                 # per-slot sampling params ride the same slot-mutation
@@ -4119,7 +4253,29 @@ class ServingEngine:
                 'seed': self._put(self._seed),
                 'plen': self._put(self._plen),
             }
+        elif self._dev['btab'] is None:
+            self._dev['btab'] = self._put_tables()
         return self._dev
+
+    def _put_tables(self):
+        """The block tables' device copy, one a kind of page: the frozen
+        rows of `_device_state` ride with an empty table."""
+        btab = self._btab
+        if any(p is not None for p in self._pfill):
+            btab = btab.copy()
+            for i, p in enumerate(self._pfill):
+                if p is not None:
+                    btab[i] = 0
+        return jax.tree.map(self._put, self._by_kind(btab, self._wtab))
+
+    def _tables_changed(self):
+        """A live slot took or gave back pages and nothing else of it
+        changed: the tables' device copy alone is stale, so the next
+        dispatch uploads one array a kind and not the slots' whole state
+        (a row crosses a page every `block_size / decode_window`
+        windows, so most steps of a busy engine come through here)."""
+        if self._dev is not None:
+            self._dev['btab'] = None
 
     def _admit(self):
         """Fill free slots from the queue head (priority order — a head
@@ -4138,7 +4294,7 @@ class ServingEngine:
         free = self._free_slots()
         placed = []
         admitted = 0
-        a = self.allocator
+        a, wa = self.allocator, self.win_allocator
         with _obs_trace.span('serve.admit', cat='scheduler') as _sp:
             while free and len(self.queue):
                 req = self.queue.peek()
@@ -4192,6 +4348,13 @@ class ServingEngine:
                 revive = sum(1 for p in hit if a.refcount(p) == 0)
                 if need > a.available() - revive:
                     break
+                # the recycled kind holds the pages the next query's
+                # window reaches (a re-prefill writes those alone)
+                wfirst, wgot = 0, []
+                if wa is not None:
+                    wfirst = self._window_first(req.context_len)
+                    if total_pages - wfirst > wa.available():
+                        break
                 held_after = a.in_use() + need + revive
                 if (held_after / a.usable > self.admit_watermark
                         and self.in_flight() > 0):
@@ -4235,6 +4398,8 @@ class ServingEngine:
                         got.append(cp)
                         cow_pair = (hit[-1], cp)
                     got.extend(a.alloc(total_pages - len(hit)))
+                    if wa is not None:
+                        wgot = wa.alloc(total_pages - wfirst)
                 except OutOfBlocks:
                     # transient pool pressure (an injected dry spell,
                     # or stats racing a concurrent free): release any
@@ -4266,7 +4431,7 @@ class ServingEngine:
                 else:
                     pages_for_slot = got
                 slot = free.pop(0)
-                self._place(slot, req, pages_for_slot)
+                self._place(slot, req, pages_for_slot, wgot, wfirst)
                 admitted += 1
                 if self.prefix_cache:
                     if hit:
@@ -4312,13 +4477,19 @@ class ServingEngine:
                           for i in range(0, len(members), rows))
         return sorted(groups, key=lambda kv: -len(kv[1]))
 
-    def _place(self, slot, req, pages):
+    def _place(self, slot, req, pages, wpages=(), wfirst=0):
         """Arm a slot (host bookkeeping only; the batched prefill in
-        `_admit` moves the actual KV rows)."""
+        `_admit` moves the actual KV rows). `wpages` are the recycled
+        kind's, for the logical pages from `wfirst` on."""
         self._slot_req[slot] = req
         self._slot_pages[slot] = pages
         self._btab[slot] = 0
         self._btab[slot, :len(pages)] = pages
+        if self._ring is not None:
+            self._slot_wpages[slot] = list(wpages)
+            self._wfirst[slot] = wfirst
+            self._wtab[slot] = 0
+            self._wtab[slot, wfirst:wfirst + len(wpages)] = wpages
         self._ctx[slot] = req.context_len
         self._budget[slot] = req.remaining
         self._temp[slot] = req.temperature
@@ -4390,7 +4561,11 @@ class ServingEngine:
         less the pages wholly behind the layer's window — beside
         `pages_table`, the table entries those calls are handed. Their
         ratio is what the kernel's time follows, and how far
-        `max_context_len` is over-provisioned."""
+        `max_context_len` is over-provisioned. A model of two kinds of
+        page adds what the slots hold of each (`full_pages_held`,
+        `win_pages_held`, `kv_bytes_held`) beside what they would hold
+        were nothing recycled behind the window (`win_pages_unbounded`,
+        `kv_bytes_unbounded`)."""
         ctx = self._ctx[self._live_rows()].astype(np.int64)
         bs = self.block_size
         last = -(-ctx // bs)
@@ -4398,9 +4573,23 @@ class ServingEngine:
             n * int((last - (np.maximum(ctx - w, 0) // bs if w else 0)).sum())
             for w, n in self._layer_windows)
         layers = sum(n for _, n in self._layer_windows)
-        return dict(pages_needed=needed,
-                    pages_table=layers * self.max_slots
-                    * self.max_blocks_per_seq)
+        counts = dict(pages_needed=needed,
+                      pages_table=layers * self.max_slots
+                      * self.max_blocks_per_seq)
+        if self._ring is not None:
+            # what the slots hold of each kind, beside what the recycled
+            # kind's layers would hold with nothing recycled (a page of
+            # every logical page the rows hold of the other kind)
+            a, wa = self.allocator, self.win_allocator
+            held, wheld = a.in_use(), wa.in_use()
+            counts.update(
+                full_pages_held=held, win_pages_held=wheld,
+                win_pages_unbounded=held,
+                kv_bytes_held=(held * a.bytes_per_page
+                               + wheld * wa.bytes_per_page),
+                kv_bytes_unbounded=held * (a.bytes_per_page
+                                           + wa.bytes_per_page))
+        return counts
 
     def _fill(self, bucket, real_lens, padded_rows=None):
         """How full one fixed-width prefill batch is, as `serve.dispatch`
@@ -4425,6 +4614,7 @@ class ServingEngine:
         ids = np.zeros((K, Sb), np.int32)
         real_len = np.zeros((K,), np.int32)
         btabs = np.zeros((K, self.max_blocks_per_seq), np.int32)
+        wtabs = np.zeros_like(btabs) if self._ring is not None else None
         slots = np.full((K,), self.max_slots, np.int32)      # dummy: drop
         for i, (slot, req) in enumerate(group):
             toks = np.concatenate([req.prompt,
@@ -4432,9 +4622,12 @@ class ServingEngine:
             ids[i, :len(toks)] = toks                        # RIGHT-pad
             real_len[i] = len(toks)
             btabs[i] = self._btab[slot]
+            if wtabs is not None:
+                wtabs[i] = self._wtab[slot]
             slots[i] = slot
         return (self._put(ids), self._put(real_len),
-                self._put(btabs), self._put(slots))
+                jax.tree.map(self._put, self._by_kind(btabs, wtabs)),
+                self._put(slots))
 
     def _prefill_group(self, Sb, group):
         """Standalone prefill dispatch for an admission group that did
@@ -4539,8 +4732,10 @@ class ServingEngine:
         standing — maximal preemption reached — is unservable: that
         request fails alone (pages freed, pool invariants intact) and
         step() keeps decoding whatever remains; `OutOfBlocks` never
-        escapes the scheduler."""
-        a = self.allocator
+        escapes the scheduler. Of the recycled kind a slot first gives
+        back the pages now wholly behind its window (`_recycle`), then
+        takes those the window's writes need: host bookkeeping alone."""
+        a, wa = self.allocator, self.win_allocator
         # per-step maximum commit: a speculative window can land up to
         # k+1 tokens (draft writes beyond the committed region fall on
         # the scratch page, so coverage only needs the committable max)
@@ -4557,11 +4752,31 @@ class ServingEngine:
             target = _ceil_div(
                 int(self._ctx[slot]) + min(adv, req.remaining),
                 self.block_size)
-            while (self._slot_req[slot] is req
-                   and target > len(self._slot_pages[slot])):
+            if wa is not None:
+                self._recycle(slot, req)
+            while self._slot_req[slot] is req:
+                pages, wpages = self._slot_pages[slot], self._slot_wpages[slot]
+                need = target - len(pages)
+                wneed = (0 if wa is None
+                         else target - self._wfirst[slot] - len(wpages))
+                if need <= 0 and wneed <= 0:
+                    break
                 try:
                     a.phase = 'window'
-                    new = a.alloc(target - len(self._slot_pages[slot]))
+                    # each kind's pages are the slot's as soon as they
+                    # are taken: a dry pool below has nothing to unwind
+                    if need > 0:
+                        new = a.alloc(need)
+                        self._btab[slot, len(pages):len(pages) + need] = new
+                        pages.extend(new)
+                        self._tables_changed()
+                    if wneed > 0:
+                        wa.phase = 'window'
+                        new = wa.alloc(wneed)
+                        at = self._wfirst[slot] + len(wpages)
+                        self._wtab[slot, at:at + wneed] = new
+                        wpages.extend(new)
+                        self._tables_changed()
                 except OutOfBlocks as e:
                     others = any(
                         r is not None and s != slot
@@ -4584,10 +4799,34 @@ class ServingEngine:
                     break
                 finally:
                     a.phase = None
-                pages = self._slot_pages[slot]
-                self._btab[slot, len(pages):len(pages) + len(new)] = new
-                pages.extend(new)
-                self._dev = None
+                    if wa is not None:
+                        wa.phase = None
+
+    def _window_first(self, ctx):
+        """The first logical page the recycled kind's window reaches
+        from a query at position `ctx`: what lies before is never read
+        again."""
+        window = self._kinds[self._ring].window
+        return max(0, int(ctx) - window + 1) // self.block_size
+
+    def _recycle(self, slot, req):
+        """Give back the slot's recycled-kind pages that now lie wholly
+        behind its window, zero their table entries, and say so
+        (`serve.recycle`). The ids go to the top of the kind's free
+        list: the next taker, often this very slot, is handed them
+        again."""
+        first = self._window_first(self._ctx[slot])
+        drop = first - self._wfirst[slot]
+        if drop <= 0:
+            return
+        pages = self._slot_wpages[slot][:drop]
+        del self._slot_wpages[slot][:drop]
+        self._wtab[slot, self._wfirst[slot]:first] = 0
+        self._wfirst[slot] = first
+        self.win_allocator.free(pages)
+        self._tables_changed()
+        _obs_trace.instant('serve.recycle', cat='scheduler', rid=req.rid,
+                           slot=slot, pages=len(pages))
 
     def _preempt_one(self):
         """Evict the lowest-priority (then youngest) in-flight request:
@@ -4698,6 +4937,11 @@ class ServingEngine:
             # the slot died before its first chunk dispatched: release
             # the copy-pin reference on the CoW source page too
             self.allocator.free([self._cow_pending[slot][0]])
+        if self._ring is not None:
+            self.win_allocator.free(self._slot_wpages[slot])
+            self._slot_wpages[slot] = []
+            self._wfirst[slot] = 0
+            self._wtab[slot] = 0
         self._slot_req[slot] = None
         self._slot_pages[slot] = []
         self._btab[slot] = 0
@@ -4717,4 +4961,4 @@ class ServingEngine:
 __all__ = ['ServingEngine', 'BlockAllocator', 'RequestQueue', 'Request',
            'OutOfBlocks', 'QueueFull', 'RequestError', 'RequestFailed',
            'RequestExpired', 'RequestCancelled', 'InvalidSamplingParams',
-           'prompt_page_hashes']
+           'PageKindsUnsupported', 'prompt_page_hashes']

@@ -147,6 +147,75 @@ class PagedKVCache(typing.NamedTuple):
     vp: jax.Array        # (num_blocks, Hkv, block_size, D) pages
 
 
+class PageKind(typing.NamedTuple):
+    """One kind of KV page a model keeps, as the model states it
+    (`GenerationMixin.page_kinds`) and the caches, the serving engine's
+    allocators and tables, and its page counts read it: the layers whose
+    pools have this shape, their kv heads, the widths of a K and of a V
+    row, and `window` where no layer of the kind attends further back
+    than that many positions, so that pages wholly behind it need not
+    be kept (None: every page of the context is kept)."""
+
+    name: str
+    layers: tuple
+    kv_heads: int
+    k_width: int
+    v_width: int
+    window: typing.Optional[int] = None
+
+
+def lane_padded(width):
+    """The minor dim a page pool gives a row of `width`: the row itself up
+    to one 128-lane tile or at whole tiles, else the next whole tile, the
+    rest zero. The chip's HBM tiling holds such a row in whole tiles
+    whatever the logical shape says, and Mosaic refuses to slice a page
+    whose minor dim is neither (a K row of 192): stating the tiles keeps
+    the pool's bytes what they were and lets the paged kernel copy it."""
+    return width if width <= 128 else -(-width // 128) * 128
+
+
+def pad_lanes(x, width):
+    """x with its minor dim zero-padded to `width` (a pool's, see
+    `lane_padded`); x itself where it is that wide."""
+    short = width - x.shape[-1]
+    return x if not short else jnp.pad(
+        x, [(0, 0)] * (x.ndim - 1) + [(0, short)])
+
+
+def pool_rows_set(pool, pages, slots, rows):
+    """`pool` (num_blocks, Hkv, block_size, D) with `rows` (n, Hkv, D)
+    written at (pages[i], :, slots[i], :): what a decode step and an
+    admission prefill do to a page pool. With fewer than 8 kv heads the
+    write goes to the pool seen as (num_blocks, Hkv * block_size, D) rows
+    (the same bytes): XLA lays the operand of the 4-D scatter out heads-
+    minor for such a pool, and the paged kernel, which wants a page's
+    (block_size, D) tiles, then gets a copy of the WHOLE pool every
+    token-step (two full-layer pools of 0.8 GB cost 12 % of a serving
+    cell's device time: chip, PR 33). From 8 heads on the 4-D form keeps
+    the layout and stays as it was."""
+    nb, hkv, bs, d = pool.shape
+    if hkv >= 8:
+        return pool.at[pages, :, slots, :].set(rows)
+    at = jnp.arange(hkv, dtype=slots.dtype) * bs + slots[:, None]
+    return pool.reshape(nb, hkv * bs, d).at[pages[:, None], at, :].set(
+        rows).reshape(pool.shape)
+
+
+def layer_kinds(kinds, num_layers):
+    """Per layer, the index in `kinds` of its kind."""
+    of = {l: i for i, k in enumerate(kinds) for l in k.layers}
+    return [of[l] for l in range(num_layers)]
+
+
+def layer_tables(kinds, block_tables, num_layers):
+    """Per layer, its kind's block table: `block_tables` is one table
+    where the model has one kind of page, else one a kind in the kinds'
+    order."""
+    if len(kinds) == 1:
+        return [block_tables] * num_layers
+    return [block_tables[i] for i in layer_kinds(kinds, num_layers)]
+
+
 def quantize_kv_rows(x, scale):
     """Symmetric int8 quantization of new K/V rows (B, S, Hkv, D) with
     per-(head, dim) scales; saturates rows that exceed the prefill
@@ -256,20 +325,46 @@ class GenerationMixin:
         (usually the embedding table's dtype)."""
         raise NotImplementedError
 
-    def init_cache(self, batch_size, max_len, dtype=None, quantized=False):
-        """Per-layer (k, v) zero pairs of (B, max_len, kv_heads, head_dim),
-        derived from `self.config` (`head_dim` property or
-        hidden_size // num_attention_heads).
-
-        quantized=True returns QuantKVCache entries (int8 data +
-        per-(head, dim) scales). The first cached call must be a
-        multi-token prefill — that's where the scales calibrate."""
+    def page_kinds(self):
+        """The kinds of KV page this model's layers keep (`PageKind`),
+        the ONE statement of its cache's shape: `init_cache`,
+        `init_paged_cache` and the serving engine read it and nothing
+        else guesses. Default: one kind for every layer, from
+        `self.config` (`num_key_value_heads`, `head_dim` or hidden_size
+        // num_attention_heads), every page kept. A model whose layers
+        differ in kv heads or widths, or whose window layers' pages may
+        be recycled, overrides this."""
         cfg = self.config
         head_dim = getattr(cfg, 'head_dim', None)
         if head_dim is None:
             head_dim = cfg.hidden_size // cfg.num_attention_heads
         kv_heads = (getattr(cfg, 'num_key_value_heads', None)
                     or cfg.num_attention_heads)
+        return (PageKind('full', tuple(range(cfg.num_hidden_layers)),
+                         kv_heads, head_dim, head_dim),)
+
+    def init_cache(self, batch_size, max_len, dtype=None, quantized=False):
+        """Per-layer (k, v) zero pairs of (B, max_len, kv_heads, head_dim),
+        in the shapes `page_kinds` states.
+
+        quantized=True returns QuantKVCache entries (int8 data +
+        per-(head, dim) scales). The first cached call must be a
+        multi-token prefill — that's where the scales calibrate."""
+        cfg = self.config
+        kinds = self.page_kinds()
+        if len(kinds) > 1:
+            if quantized:
+                raise NotImplementedError(
+                    f'{type(self).__name__} keeps {len(kinds)} kinds of '
+                    f'KV page: its contiguous cache has no int8 form')
+            dtype = dtype or self.cache_dtype()
+            rows = (batch_size, max_len)
+            return [(jnp.zeros(rows + (kinds[i].kv_heads, kinds[i].k_width),
+                               dtype),
+                     jnp.zeros(rows + (kinds[i].kv_heads, kinds[i].v_width),
+                               dtype))
+                    for i in layer_kinds(kinds, cfg.num_hidden_layers)]
+        kv_heads, head_dim = kinds[0].kv_heads, kinds[0].k_width
         dtype = dtype or self.cache_dtype()
         shape = (batch_size, max_len, kv_heads, head_dim)
 
@@ -325,27 +420,40 @@ class GenerationMixin:
 
     def init_paged_cache(self, num_blocks, block_size, dtype=None):
         """Per-layer PagedKVCache pools of (num_blocks, kv_heads,
-        block_size, head_dim) zero pages. The pool is request-agnostic:
+        block_size, head_dim) zero pages (a row wider than a lane tile
+        in whole tiles: `lane_padded`). The pool is request-agnostic:
         the ServingEngine's BlockAllocator hands page ids to requests
         and the per-request block tables ride into each decode step as
         device data (inference/serving.py). Page 0 is the reserved
-        scratch page, so a usable pool needs num_blocks >= 2."""
+        scratch page, so a usable pool needs num_blocks >= 2. The pools'
+        shapes are `page_kinds`'; a model of several kinds takes one
+        `num_blocks` a kind, in their order."""
+        from ..distributed.mesh import get_mesh
+
         cfg = self.config
-        head_dim = getattr(cfg, 'head_dim', None)
-        if head_dim is None:
-            head_dim = cfg.hidden_size // cfg.num_attention_heads
-        kv_heads = (getattr(cfg, 'num_key_value_heads', None)
-                    or cfg.num_attention_heads)
+        kinds = self.page_kinds()
         dtype = dtype or self.cache_dtype()
         dtype = jnp.dtype(dtype)
         quant = dtype == jnp.int8
+        if len(kinds) > 1:
+            # a pool group a kind, each in its own shape (no padding of
+            # one kind to another's heads or widths)
+            if quant or get_mesh() is not None:
+                raise NotImplementedError(
+                    f'{type(self).__name__} keeps {len(kinds)} kinds of '
+                    f'KV page: no int8 and no tp-sharded pools for it')
+            shapes = [(int(n), k.kv_heads, int(block_size))
+                      for n, k in zip(num_blocks, kinds)]
+            return [PagedKVCache(
+                jnp.zeros(shapes[i] + (lane_padded(kinds[i].k_width),), dtype),
+                jnp.zeros(shapes[i] + (lane_padded(kinds[i].v_width),), dtype))
+                for i in layer_kinds(kinds, cfg.num_hidden_layers)]
+        kv_heads, head_dim = kinds[0].kv_heads, lane_padded(kinds[0].k_width)
         shape = (int(num_blocks), kv_heads, int(block_size), head_dim)
         sshape = shape[:3]                    # per-row scales (NB,Hkv,BS)
 
         def make(sh=shape, dt=dtype):
             return jnp.zeros(sh, dt)
-
-        from ..distributed.mesh import get_mesh
 
         mesh = get_mesh()
         if mesh is not None:

@@ -193,9 +193,45 @@ def apply_rotary(x, cos, sin):
 # Blocks
 # ---------------------------------------------------------------------------
 
+def masked_attention(q, k, v, mask, sink=None):
+    """softmax(q kᵀ / sqrt(D)) v over the keys `mask` allows, grouped
+    (each kv head's queries meet its keys unrepeated), products in the
+    inputs' type with float32 accumulation. What `cached_attention`'s XLA
+    path computes where `scaled_dot_product_attention` cannot: a V row
+    of another width than a K row, and `sink` (H,), one logit a query
+    head that joins the softmax as one more column and is dropped before
+    the values are weighed. q (B, S, H, D), k (B, T, Hkv, D), v (B, T,
+    Hkv, Dv), mask broadcastable to (B, H, S, T). Returns (B, S, H, Dv)."""
+    B, S, H, D = q.shape
+    T, Hkv = k.shape[1], k.shape[2]
+    g = H // Hkv
+    scores = jnp.einsum('bshgd,bthd->bhgst', q.reshape(B, S, Hkv, g, D), k,
+                        preferred_element_type=jnp.float32) / math.sqrt(D)
+    mask = jnp.broadcast_to(mask, (B, H, S, T)).reshape(B, Hkv, g, S, T)
+    scores = jnp.where(mask, scores, -1e30)
+    if sink is not None:
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, Hkv, g, 1, 1),
+            (B, Hkv, g, S, 1))
+        scores = jnp.concatenate([scores, col], axis=-1)
+    p = jax.nn.softmax(scores, axis=-1)[..., :T]
+    out = jnp.einsum('bhgst,bthd->bshgd', p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, S, H, v.shape[-1]).astype(q.dtype)
+
+
+def _xla_attention(q, k, v, mask, sink):
+    """The masked XLA path of `cached_attention`: the shared functional
+    where it can compute this (no sink, V rows as wide as K rows), else
+    `masked_attention`."""
+    if sink is None and v.shape[-1] == q.shape[-1]:
+        return F.scaled_dot_product_attention(q, k, v, attn_mask=mask)
+    return masked_attention(q, k, v, mask, sink)
+
+
 def cached_attention(q, k, v, cache, cache_index, kvalid=None,
                      kv_start=None, kv_write_pos=None, window=None,
-                     block_tables=None):
+                     block_tables=None, sink=None):
     """Shared KV-cached attention step (LlamaAttention, GPTAttention):
     write the S new rows at cache_index, attend over the full cache
     masked by position; single-token steps dispatch to the fused pallas
@@ -212,7 +248,11 @@ def cached_attention(q, k, v, cache, cache_index, kvalid=None,
     position. `window` (int) applies sliding-window attention over the
     cache: only the last `window` positions are attended — on the fused
     decode path this is just a larger per-row start, so the kernel still
-    streams only the live band. Returns (out (B, S, H, D), new_cache).
+    streams only the live band. `sink` (H,) float32: one logit a query
+    head in the softmax's denominator, with no value (a learned attention
+    sink); it and a V row of another width than a K row take the XLA path
+    (`masked_attention`) but on paged decode, where the paged kernel has
+    both. Returns (out (B, S, H, Dv), new_cache).
 
     A QuantKVCache stores K/V int8 with per-(head, dim) scales: prefill
     (S > 1) calibrates the scales from its own rows, decode steps
@@ -237,7 +277,7 @@ def cached_attention(q, k, v, cache, cache_index, kvalid=None,
     if isinstance(cache, (PagedKVCache, QuantPagedKVCache)):
         return _paged_cached_attention(q, k, v, cache, kv_write_pos,
                                        block_tables, window, kvalid,
-                                       kv_start)
+                                       kv_start, sink)
     if kv_write_pos is not None:
         wp = jnp.reshape(jnp.asarray(kv_write_pos, jnp.int32), (-1,))
         wp = jnp.broadcast_to(wp, (B,))
@@ -301,7 +341,9 @@ def cached_attention(q, k, v, cache, cache_index, kvalid=None,
         new_cache = (ck, cv)
     max_len = ck.shape[1]
     out = None
-    if S == 1 and D % 8 == 0 and (kvalid is None or kv_start is not None):
+    plain = sink is None and v.shape[-1] == D
+    if (S == 1 and D % 8 == 0 and plain
+            and (kvalid is None or kv_start is not None)):
         from ..ops import use_pallas
 
         if use_pallas():
@@ -368,17 +410,18 @@ def cached_attention(q, k, v, cache, cache_index, kvalid=None,
             # bandwidth win lives in the pallas kernel)
             ck = (ck.astype(jnp.float32) * kscale[None, None]).astype(q.dtype)
             cv = (cv.astype(jnp.float32) * vscale[None, None]).astype(q.dtype)
-        out = F.scaled_dot_product_attention(q, ck, cv, attn_mask=mask)
+        out = _xla_attention(q, ck, cv, mask, sink)
     return out, new_cache
 
 
 def _paged_cached_attention(q, k, v, cache, kv_write_pos, block_tables,
-                            window, kvalid, kv_start):
+                            window, kvalid, kv_start, sink=None):
     """Single-token decode over a PagedKVCache: scatter the new row
     into its page, then attend over the row's pages masked by the
     per-row valid length (kv_write_pos + 1) and, with `window`, to its
-    last `window` positions (pages behind them stay allocated; the
-    kernel skips them). See cached_attention."""
+    last `window` positions (the kernel skips the pages behind them;
+    whether they stay allocated is the table's owner's affair: their
+    entries are never read). See cached_attention."""
     B, S, H, D = q.shape
     if kvalid is not None or kv_start is not None:
         # these are masking CONTRACTS on the other branches — dropping
@@ -400,14 +443,19 @@ def _paged_cached_attention(q, k, v, cache, kv_write_pos, block_tables,
             'PagedKVCache needs kv_write_pos (per-row write positions) '
             'and block_tables (per-row page ids)')
     from .generation import (QuantPagedKVCache, dequantize_kv_row,
-                             quantize_kv_row)
+                             pad_lanes, pool_rows_set, quantize_kv_row)
 
     quant = isinstance(cache, QuantPagedKVCache)
     if quant:
         kp, vp, kss, vss = cache
     else:
         kp, vp = cache
-    NB, Hkv, BS, _ = kp.shape
+    NB, Hkv, BS, Dp = kp.shape
+    Dv, Dvp = v.shape[-1], vp.shape[-1]
+
+    def cut(x, to):
+        return x if x.shape[-1] == to else x[..., :to]
+
     tbl = jnp.asarray(block_tables, jnp.int32)
     maxb = tbl.shape[1]
     wp = jnp.broadcast_to(
@@ -431,8 +479,10 @@ def _paged_cached_attention(q, k, v, cache, kv_write_pos, block_tables,
         vss = vss.at[page, :, slot].set(vsr)
         new_cache = QuantPagedKVCache(kp, vp, kss, vss)
     else:
-        kp = kp.at[page, :, slot, :].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[page, :, slot, :].set(v[:, 0].astype(vp.dtype))
+        kp = pool_rows_set(kp, page, slot,
+                           pad_lanes(k[:, 0], Dp).astype(kp.dtype))
+        vp = pool_rows_set(vp, page, slot,
+                           pad_lanes(v[:, 0], Dvp).astype(vp.dtype))
         new_cache = PagedKVCache(kp, vp)
     counts = wp + 1
     out = None
@@ -447,10 +497,14 @@ def _paged_cached_attention(q, k, v, cache, kv_write_pos, block_tables,
                 paged_decode_attention)
 
             def kernel(q_, kp_, vp_, tbl_, counts_, *scales):
-                return paged_decode_attention(
-                    q_, kp_, vp_, tbl_, counts_,
+                # q's zero lanes meet the pool's: the scores are the
+                # rows' own, scaled by their own width
+                return cut(paged_decode_attention(
+                    pad_lanes(q_, Dp), kp_, vp_, tbl_, counts_,
+                    scale=1.0 / (D ** 0.5),
                     k_scale=scales[0] if scales else None,
-                    v_scale=scales[1] if scales else None, window=window)
+                    v_scale=scales[1] if scales else None, window=window,
+                    sink=sink), Dv)
 
             # pools split their kv-head dim over tp (init_paged_cache's
             # placement); tables and lengths follow the batch
@@ -470,14 +524,13 @@ def _paged_cached_attention(q, k, v, cache, kv_write_pos, block_tables,
         if quant:
             gk = dequantize_kv_row(gk, kss[tbl], q.dtype)
             gv = dequantize_kv_row(gv, vss[tbl], q.dtype)
-        ck = jnp.swapaxes(gk, 2, 3).reshape(B, maxb * BS, Hkv, D)
-        cv = jnp.swapaxes(gv, 2, 3).reshape(B, maxb * BS, Hkv, D)
+        ck = cut(jnp.swapaxes(gk, 2, 3).reshape(B, maxb * BS, Hkv, Dp), D)
+        cv = cut(jnp.swapaxes(gv, 2, 3).reshape(B, maxb * BS, Hkv, Dvp), Dv)
         kpos = jnp.arange(maxb * BS)[None, :]
         mask = kpos < counts[:, None]
         if window is not None:
             mask = mask & (kpos >= counts[:, None] - window)
-        out = F.scaled_dot_product_attention(
-            q, ck, cv, attn_mask=mask[:, None, None, :])
+        out = _xla_attention(q, ck, cv, mask[:, None, None, :], sink)
     return out, new_cache
 
 

@@ -18,7 +18,10 @@ so a wide table or an idle slot costs nothing. `P` follows the shapes
 only; positions are masked in a row's first and last chunk alone. The
 contiguous head-major cache keeps a BlockSpec grid over S-slices and
 shares the ONE online-softmax update. Optional int8 scales dequantize in
-VMEM. Inference-only (no VJP).
+VMEM. A K row and a V row may differ in width (the pools' own shapes say),
+and an optional per-query-head `sink` logit starts the running max and sum:
+it takes its share of the softmax's mass and adds no value. Inference-only
+(no VJP).
 """
 from __future__ import annotations
 
@@ -53,13 +56,14 @@ def _attend(q_ref, kv, acc, m_scr, l_scr, *, scale, keys, span=None):
     count) masks positions outside [start, count) out of the scores and
     zeroes their V rows, so stale or unspecified memory (inf/nan bit
     patterns) cannot reach the products; without it every key counts."""
-    _, hkv, group, D = q_ref.shape
+    _, hkv, group, _ = q_ref.shape
     keep = vkeep = None
     if span is not None:
         base, start, count = span
         pos = base + jax.lax.broadcasted_iota(jnp.int32, (group, keys), 1)
         keep = (pos < count) & (pos >= start)
-        vpos = base + jax.lax.broadcasted_iota(jnp.int32, (keys, D), 0)
+        vpos = base + jax.lax.broadcasted_iota(
+            jnp.int32, (keys, acc.shape[-1]), 0)
         vkeep = (vpos < count) & (vpos >= start)
     for h in range(hkv):
         k, v = kv(h)
@@ -83,10 +87,17 @@ def _attend(q_ref, kv, acc, m_scr, l_scr, *, scale, keys, span=None):
         l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
 
-def _reset(acc, m_scr, l_scr):
+def _reset(acc, m_scr, l_scr, sink_ref=None):
+    """Nothing attended yet; with a sink, one logit a query head that is
+    already in the running max and sum (exp(sink - sink) = 1) and brings
+    no value."""
     acc[...] = jnp.zeros_like(acc)
-    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
+    if sink_ref is None:
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+    else:
+        m_scr[...] = sink_ref[...]
+        l_scr[...] = jnp.ones_like(l_scr)
 
 
 def _finish(o_ref, acc, l_scr):
@@ -95,11 +106,14 @@ def _finish(o_ref, acc, l_scr):
 
 
 def _paged_kernel(cl_ref, st_ref, tbl_ref, q_ref, k_hbm, v_hbm, *rest, scale,
-                  bs, pages, edge_pages, maxb, quant, rowscale):
+                  bs, pages, edge_pages, maxb, quant, rowscale, sink):
     """Grid step b = row b. Its pages [lo, hi) are walked in chunks of
     `pages`; chunk c lives in buffer slot (first + c) % 2, where `first`
     is the slot the previous row left this row's chunk 0 in (it starts
     that copy during its own last chunk)."""
+    sink_ref = None
+    if sink:
+        sink_ref, rest = rest[0], rest[1:]
     if rowscale:
         ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf = rest[:7]
     elif quant:
@@ -168,7 +182,7 @@ def _paged_kernel(cl_ref, st_ref, tbl_ref, q_ref, k_hbm, v_hbm, *rest, scale,
         fetch(b, lo, hi, 0, first_slot)
 
     carry[1] = 0
-    _reset(acc, m_scr, l_scr)
+    _reset(acc, m_scr, l_scr, sink_ref)
 
     def chunk(c, _):
         slot = (first_slot + c) % 2
@@ -210,8 +224,8 @@ def _paged_kernel(cl_ref, st_ref, tbl_ref, q_ref, k_hbm, v_hbm, *rest, scale,
                 elif quant:
                     k = k * ks_ref[h][None]
                     v = v * vs_ref[h][None]
-                D = k.shape[-1]
-                return k.reshape(npages * bs, D), v.reshape(npages * bs, D)
+                return (k.reshape(npages * bs, k.shape[-1]),
+                        v.reshape(npages * bs, v.shape[-1]))
 
             return functools.partial(_attend, q_ref, kv, acc, m_scr, l_scr,
                                      scale=scale, keys=npages * bs)
@@ -239,14 +253,14 @@ def _paged_kernel(cl_ref, st_ref, tbl_ref, q_ref, k_hbm, v_hbm, *rest, scale,
     _finish(o_ref, acc, l_scr)
 
 
-def _pick_pages(bs, hkv, D, itemsize, maxb):
+def _pick_pages(bs, hkv, D, itemsize, maxb, Dv=None):
     """(pages a chunk, pages a piece of a masked chunk): a piece holds
     EDGE_KEYS keys, a chunk as many whole pieces as CHUNK_KEYS keys take,
     as far as two slots of K and V pages fit CHUNK_VMEM_BUDGET (an int8
     page budgets as 2-byte: it is dequantized to f32 a head at a time, so
     the working set follows the chunk's LENGTH) and the table is wide."""
-    page = hkv * bs * D * max(itemsize, 2)
-    fit = max(1, min(CHUNK_VMEM_BUDGET // (4 * page), maxb))
+    pair = hkv * bs * (D + (D if Dv is None else Dv)) * max(itemsize, 2)
+    fit = max(1, min(CHUNK_VMEM_BUDGET // (2 * pair), maxb))
     piece = min(pl.cdiv(EDGE_KEYS, bs), fit)
     return max(1, min(CHUNK_KEYS // (piece * bs), fit // piece)) * piece, piece
 
@@ -258,6 +272,7 @@ def _head_scales(hkv, D):
 
 
 def _state(hkv, group, D):
+    """The accumulator (as wide as a V row), the running max and sum."""
     return [pltpu.VMEM((hkv, group, D), jnp.float32),
             pltpu.VMEM((hkv, group, 128), jnp.float32),
             pltpu.VMEM((hkv, group, 128), jnp.float32)]
@@ -265,10 +280,12 @@ def _state(hkv, group, D):
 
 def paged_decode_attention(q, key_cache, value_cache, block_tables,
                            context_lens, scale=None, k_scale=None,
-                           v_scale=None, window=None):
+                           v_scale=None, window=None, sink=None):
     """One fused paged decode step.
 
-    q: (B, 1, Hq, D); key_cache/value_cache: (NB, Hkv, BS, D) pages;
+    q: (B, 1, Hq, D); key_cache: (NB, Hkv, BS, D) pages and value_cache:
+    (NB, Hkv, BS, Dv) pages, Dv = D unless the model's V rows are of
+    another width;
     block_tables: (B, MAXB) int32 page ids (entries past the sequence's
     pages may be any value — they are never read); context_lens: (B,)
     valid positions per row. Optional k_scale/v_scale dequantize int8
@@ -278,8 +295,11 @@ def paged_decode_attention(q, key_cache, value_cache, block_tables,
     page's scales are fetched with it). `window` (static int): the query,
     at position context_lens - 1, attends the last `window` positions
     only; pages wholly behind them are neither copied nor computed (they
-    stay allocated: the table is the caller's). A row of length 0 reads
-    nothing and returns zeros. Returns (B, 1, Hq, D).
+    stay allocated or are the caller's to recycle: the table is the
+    caller's, and entries behind the window are never read). `sink`:
+    (Hq,) float32, one logit a query head that joins the softmax's
+    denominator and adds no value. A row of length 0 reads nothing and
+    returns zeros. Returns (B, 1, Hq, Dv).
     """
     B, Sq, Hq, D = q.shape
     if Sq != 1:
@@ -289,10 +309,12 @@ def paged_decode_attention(q, key_cache, value_cache, block_tables,
         raise ValueError(
             f'query heads ({Hq}) must be a multiple of kv heads ({Hkv})')
     pages, edge_pages = _pick_pages(BS, Hkv, D, key_cache.dtype.itemsize,
-                                    block_tables.shape[1])
+                                    block_tables.shape[1],
+                                    value_cache.shape[-1])
     return _paged_call(
         q, key_cache, value_cache, block_tables, context_lens, k_scale,
-        v_scale, scale=scale if scale is not None else 1.0 / (D ** 0.5),
+        v_scale, sink,
+        scale=scale if scale is not None else 1.0 / (D ** 0.5),
         window=None if window is None else int(window), pages=pages,
         edge_pages=edge_pages, interpret=_interpret())
 
@@ -300,14 +322,15 @@ def paged_decode_attention(q, key_cache, value_cache, block_tables,
 @functools.partial(jax.jit, static_argnames=(
     'scale', 'window', 'pages', 'edge_pages', 'interpret'))
 def _paged_call(q, key_cache, value_cache, block_tables, context_lens,
-                k_scale, v_scale, *, scale, window, pages, edge_pages,
-                interpret):
+                k_scale, v_scale, sink=None, *, scale, window, pages,
+                edge_pages, interpret):
     """The kernel's call, jitted by itself: a model calls it once a layer
     with the same shapes, and tracing and lowering the kernel's body (a
     chunk's copies and eight heads' products, unrolled) once a layer was
     ~0.8 s each, in every process, compile cache or not."""
     B, _, Hq, D = q.shape
     NB, Hkv, BS, _ = key_cache.shape
+    Dv = value_cache.shape[-1]
     group = Hq // Hkv
     maxb = block_tables.shape[1]
     # out-of-range / sentinel (-1) page ids must not index OOB: clamp
@@ -332,7 +355,14 @@ def _paged_call(q, key_cache, value_cache, block_tables, context_lens,
     in_specs = [heads, hbm, hbm]
     args = [q.reshape(B, Hkv, group, D), key_cache, value_cache]
     scratch = [pltpu.VMEM((2, pages, Hkv, BS, D), key_cache.dtype),
-               pltpu.VMEM((2, pages, Hkv, BS, D), value_cache.dtype)]
+               pltpu.VMEM((2, pages, Hkv, BS, Dv), value_cache.dtype)]
+    if sink is not None:
+        # a head's logit across the lanes of the running max's tile
+        in_specs.append(pl.BlockSpec((Hkv, group, 128),
+                                     lambda *_: (0, 0, 0)))
+        args.append(jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(Hkv, group, 1),
+            (Hkv, group, 128)))
     if quant:
         scales = [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
         if rowscale:
@@ -351,17 +381,19 @@ def _paged_call(q, key_cache, value_cache, block_tables, context_lens,
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, bs=BS, pages=pages,
                           edge_pages=edge_pages, maxb=maxb, quant=quant,
-                          rowscale=rowscale),
+                          rowscale=rowscale, sink=sink is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch), grid=(B,), in_specs=in_specs,
-            out_specs=heads, scratch_shapes=scratch + _state(Hkv, group, D)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
+            out_specs=pl.BlockSpec((1, Hkv, group, Dv),
+                                   lambda b, *_: (b, 0, 0, 0)),
+            scratch_shapes=scratch + _state(Hkv, group, Dv)),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, Dv), q.dtype),
         # rows in order: a row starts the next row's first copy
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',)),
         interpret=interpret, name='paged_attention',
     )(*prefetch, *args)
-    return out.reshape(B, 1, Hq, D)
+    return out.reshape(B, 1, Hq, Dv)
 
 
 def _headmajor_kernel(cl_ref, q_ref, k_ref, v_ref, *rest, scale, bs, quant):

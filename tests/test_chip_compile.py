@@ -186,6 +186,31 @@ def test_paged_decode_attention_windowed(chip_compile, slots, ctx):
     assert 'tpu_custom_call' in text
 
 
+@pytest.mark.parametrize('kv_heads,pages,window', [
+    (4, 64 * 256 + 1, None),            # its full layers' pool
+    (8, 64 * 10 + 1, 128),              # its window layers' recycled pool
+])
+def test_paged_decode_attention_of_two_widths(chip_compile, kv_heads, pages,
+                                              window):
+    """MiMo-V2's two kinds of page at the benchmark cell's geometry (64
+    rows, a 256-wide table, 64 query heads): K rows of 192 in whole lane
+    tiles (256: Mosaic refuses to slice a page 192 wide), V rows of 128,
+    and on window layers a sink a query head."""
+    from paddle_tpu.models.generation import lane_padded
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    assert lane_padded(192) == 256 and lane_padded(128) == 128
+    text = chip_compile(
+        lambda q, k, v, t, n, s: paged_decode_attention(
+            q, k, v, t, n, scale=192 ** -0.5, window=window,
+            sink=s if window else None),
+        ((64, 1, 64, 256), jnp.bfloat16),
+        ((pages, kv_heads, PAGE, 256), jnp.bfloat16),
+        ((pages, kv_heads, PAGE, 128), jnp.bfloat16),
+        ((64, 256), jnp.int32), ((64,), jnp.int32), ((64,), jnp.float32))
+    assert 'tpu_custom_call' in text
+
+
 def test_rms_norm_at_a_width_that_is_no_power_of_two(chip_compile):
     """3072 features: 2 MB of rows is 170 of them, and a block of rows has
     to be a multiple of 8."""

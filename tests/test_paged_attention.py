@@ -119,6 +119,138 @@ class TestPagedKernel:
         assert np.max(np.abs(np.asarray(got) - np.asarray(want))) < 1e-2
 
 
+class TestTwoWidthsAndASink:
+    """What a second kind of page asks of the kernel (MiMo-V2): K rows and
+    V rows of different widths, a sink logit a query head, and a window
+    that starts inside a page whose predecessors' table entries are 0
+    (recycled). Against `masked_attention` over the gathered pages."""
+
+    B, NB, Hkv, BS, D, Dv, Hq, MAXB = 3, 16, 2, 16, 24, 16, 8, 8
+
+    def _case(self, seed=0):
+        rng = np.random.default_rng(seed)
+        q = jnp.asarray(rng.normal(size=(self.B, 1, self.Hq, self.D)),
+                        jnp.float32)
+        kc = jnp.asarray(rng.normal(size=(self.NB, self.Hkv, self.BS,
+                                          self.D)), jnp.float32)
+        vc = jnp.asarray(rng.normal(size=(self.NB, self.Hkv, self.BS,
+                                          self.Dv)), jnp.float32)
+        tbl = rng.permutation(np.arange(1, 16))[:15].reshape(3, 5)
+        tbl = np.concatenate([tbl, np.zeros((3, 3), np.int64)], 1)
+        sink = jnp.asarray(rng.normal(size=(self.Hq,)) + 1.0, jnp.float32)
+        return q, kc, vc, tbl, jnp.asarray([70, 80, 13], jnp.int32), sink
+
+    def _want(self, q, kc, vc, tbl, counts, window, sink):
+        from paddle_tpu.models.llama import masked_attention
+
+        n = self.MAXB * self.BS
+        ck = jnp.swapaxes(kc[tbl], 2, 3).reshape(self.B, n, self.Hkv, self.D)
+        cv = jnp.swapaxes(vc[tbl], 2, 3).reshape(self.B, n, self.Hkv, self.Dv)
+        pos = jnp.arange(n)[None]
+        seen = pos < counts[:, None]
+        if window:
+            seen &= pos >= counts[:, None] - window
+        return masked_attention(q, ck, cv, seen[:, None, None, :], sink)
+
+    @pytest.mark.parametrize('with_sink', [False, True])
+    @pytest.mark.parametrize('window', [None, 21, 32])
+    def test_matches_the_gather_path(self, window, with_sink):
+        q, kc, vc, tbl, counts, sink = self._case()
+        sink = sink if with_sink else None
+        got = paged_decode_attention(q, kc, vc, jnp.asarray(tbl, jnp.int32),
+                                     counts, window=window, sink=sink)
+        assert got.shape == (self.B, 1, self.Hq, self.Dv)
+        np.testing.assert_allclose(
+            got, self._want(q, kc, vc, tbl, counts, window, sink),
+            rtol=2e-5, atol=2e-5)
+
+    def test_the_sink_takes_mass_and_adds_no_value(self):
+        q, kc, vc, tbl, counts, sink = self._case(1)
+        tbl = jnp.asarray(tbl, jnp.int32)
+        plain = paged_decode_attention(q, kc, vc, tbl, counts, window=21)
+        sunk = paged_decode_attention(q, kc, vc, tbl, counts, window=21,
+                                      sink=sink)
+        # out_sink = out_plain * l / (l + exp(sink - m)): the same
+        # direction, shorter by the sink's share, head by head
+        ratio = np.asarray(sunk / plain)
+        assert (ratio > 0).all() and (ratio < 1).all()
+        np.testing.assert_allclose(
+            ratio, np.broadcast_to(ratio[..., :1], ratio.shape), rtol=1e-4)
+        # a sink far below every score changes nothing
+        np.testing.assert_allclose(
+            paged_decode_attention(q, kc, vc, tbl, counts, window=21,
+                                   sink=sink - 60.0), plain, atol=1e-6)
+
+    def test_pages_behind_the_window_may_be_gone(self):
+        """Entries wholly behind the window zeroed (recycled) and their
+        pages overwritten: the result does not move."""
+        q, kc, vc, tbl, counts, sink = self._case(2)
+        want = paged_decode_attention(q, kc, vc, jnp.asarray(tbl, jnp.int32),
+                                      counts, window=21, sink=sink)
+        gone = tbl.copy()
+        for b, n in enumerate(np.asarray(counts)):
+            first = max(0, n - 21) // self.BS
+            kc = kc.at[tbl[b, :first]].set(jnp.nan)
+            vc = vc.at[tbl[b, :first]].set(jnp.nan)
+            gone[b, :first] = 0
+        got = paged_decode_attention(q, kc, vc, jnp.asarray(gone, jnp.int32),
+                                     counts, window=21, sink=sink)
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize('hkv', [2, 4, 8])
+    def test_a_pools_rows_are_written_alike_in_either_form(self, hkv):
+        """`pool_rows_set`: under 8 kv heads the write goes through the
+        pool's (pages, heads x slots, D) view; the result is the 4-D
+        scatter's, duplicates on the scratch page included."""
+        from paddle_tpu.models.generation import pool_rows_set
+
+        rng = np.random.default_rng(hkv)
+        pool = jnp.asarray(rng.normal(size=(9, hkv, 16, 24)), jnp.float32)
+        pages = jnp.asarray([3, 0, 7, 0, 3], jnp.int32)
+        slots = jnp.asarray([5, 1, 15, 2, 6], jnp.int32)
+        rows = jnp.asarray(rng.normal(size=(5, hkv, 24)), jnp.float32)
+        np.testing.assert_array_equal(
+            pool_rows_set(pool, pages, slots, rows),
+            pool.at[pages, :, slots, :].set(rows))
+
+    def test_a_row_wider_than_a_lane_tile_is_kept_in_whole_tiles(self):
+        """K rows of 192 through `cached_attention`'s paged branch: pools
+        256 wide (`lane_padded`), written and read at the rows' own width,
+        kernel and gather path alike."""
+        from paddle_tpu import ops
+        from paddle_tpu.models.generation import PagedKVCache, lane_padded
+        from paddle_tpu.models.llama import cached_attention
+
+        rng = np.random.default_rng(3)
+        B, Hq, Hkv, D, Dv, BS, NB = 2, 4, 2, 192, 128, 16, 9
+        assert lane_padded(D) == 256 and lane_padded(Dv) == 128
+        q, k = (jnp.asarray(rng.normal(size=(B, 1, h, D)), jnp.float32)
+                for h in (Hq, Hkv))
+        v = jnp.asarray(rng.normal(size=(B, 1, Hkv, Dv)), jnp.float32)
+        cache = PagedKVCache(
+            jnp.asarray(rng.normal(size=(NB, Hkv, BS, 256)), jnp.float32
+                        ).at[..., D:].set(0.0),
+            jnp.asarray(rng.normal(size=(NB, Hkv, BS, Dv)), jnp.float32))
+        tbl = jnp.asarray([[1, 2, 3, 0], [4, 5, 6, 7]], jnp.int32)
+        wp = jnp.asarray([40, 50], jnp.int32)
+        sink = jnp.asarray(rng.normal(size=(Hq,)), jnp.float32)
+        outs = {}
+        for name, on in (('kernel', True), ('gather', False)):
+            was, ops.use_pallas = ops.use_pallas, lambda on=on: on
+            try:
+                outs[name], new = cached_attention(
+                    q, k, v, cache, None, kv_write_pos=wp, window=24,
+                    block_tables=tbl, sink=sink)
+            finally:
+                ops.use_pallas = was
+            assert outs[name].shape == (B, 1, Hq, Dv)
+            assert new.kp.shape == cache.kp.shape
+            np.testing.assert_array_equal(new.kp[3, :, 8, :D], k[0, 0])
+            assert not np.asarray(new.kp[..., D:]).any()
+        np.testing.assert_allclose(outs['kernel'], outs['gather'],
+                                   rtol=2e-5, atol=2e-5)
+
+
 @pytest.fixture
 def small_chunks(monkeypatch):
     """Chunks of 4 pages of 16 tokens, masked pieces of 2: rows of a few

@@ -220,6 +220,37 @@ class TestServingParity:
         # everything was released on drain
         assert srv.allocator.in_use() == 0
 
+    def test_a_page_taken_uploads_the_table_alone(self):
+        """A live row that only crosses into a new page keeps the slots'
+        device state and refreshes the block table; admission and retire
+        still upload all of it. Outputs stay the batch-1 engine's."""
+        prompt, mnt = _prompt(31, 5), 12
+        srv = ServingEngine(_model(), max_slots=2, block_size=4,
+                            max_context_len=32, max_new_tokens=mnt,
+                            decode_window=2)
+        puts = []
+        real_put = srv._put
+        srv._put = lambda x: (puts.append(np.shape(x)), real_put(x))[1]
+        rid = srv.submit(prompt, mnt)
+        per_step = []
+        while srv.in_flight() or len(srv.queue):
+            del puts[:]
+            held = len(srv._slot_pages[0])
+            dev = srv._dev
+            srv.step()
+            per_step.append((dev is not None and srv._dev is dev,
+                             len(srv._slot_pages[0]) > held > 0,
+                             list(puts)))
+        np.testing.assert_array_equal(srv.result(rid),
+                                      _refs([prompt], [mnt])[0])
+        table = np.shape(srv._btab)
+        grew = [p for kept, took, p in per_step if kept and took]
+        assert len(grew) >= 2               # pages of 4, windows of 2
+        for p in grew:
+            # the table and the budget every window uploads
+            assert sorted(p) == sorted([table, (srv.max_slots,)])
+        assert len(per_step[0][2]) > 8      # the admitting step: all of it
+
     def test_priority_admission_order(self):
         """With one slot, the high-priority request must be served
         first even when submitted last."""
