@@ -25,6 +25,7 @@ from .engine import (
     MosaicRule,
     PallasCall,
     VMEM_BYTES_PER_CORE,
+    VMEM_BYTES_PHYSICAL,
     extract_pallas_calls,
     force_tpu_variant,
     iter_eqns,
@@ -40,6 +41,7 @@ from .rules import all_rules, get_rule
 __all__ = [
     'Entry', 'KernelContext', 'MosaicRule', 'PallasCall',
     'VMEM_BYTES_PER_CORE',
+    'VMEM_BYTES_PHYSICAL',
     'extract_pallas_calls', 'force_tpu_variant', 'iter_eqns',
     'lint_and_report', 'lint_entries', 'sublane_multiple', 'trace_entry',
     'vmem_report',

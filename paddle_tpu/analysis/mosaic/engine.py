@@ -40,7 +40,11 @@ import os
 
 from ..engine import Violation
 
+# what Mosaic gives a kernel that asks for nothing, and the most one may
+# ask for through `CompilerParams(vmem_limit_bytes=...)`: a v5e core's
+# physical VMEM
 VMEM_BYTES_PER_CORE = 16 * 1024 * 1024
+VMEM_BYTES_PHYSICAL = 128 * 1024 * 1024
 
 # Mosaic min-tile second-minor (sublane) size by dtype itemsize; the
 # minor (lane) dim is always 128.
@@ -119,6 +123,7 @@ class PallasCall:
     scratch: list                # [ScratchInfo]
     num_scalar_prefetch: int
     body: object                 # the kernel jaxpr (jax.core.Jaxpr)
+    vmem_limit: int = None       # the call's own vmem_limit_bytes, if any
 
     def input_blocks(self):
         return [b for b in self.blocks if b.kind == 'input']
@@ -130,6 +135,13 @@ class PallasCall:
         est += sum(s.nbytes() for s in self.scratch
                    if s.memory_space != 'smem')
         return est
+
+    def vmem_budget(self):
+        """What the working set has to fit: the limit the call states
+        for itself (never more than the core has), else the default."""
+        if self.vmem_limit is None:
+            return VMEM_BYTES_PER_CORE
+        return min(int(self.vmem_limit), VMEM_BYTES_PHYSICAL)
 
 
 def iter_eqns(jaxpr):
@@ -215,6 +227,8 @@ def _normalize(eqn):
         scratch=scratch,
         num_scalar_prefetch=gm.num_index_operands,
         body=body,
+        vmem_limit=getattr((eqn.params.get('compiler_params') or {}).get(
+            'mosaic_tpu'), 'vmem_limit_bytes', None),
     )
 
 

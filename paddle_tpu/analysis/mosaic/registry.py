@@ -244,6 +244,31 @@ def _build_quant_matmul(weight_dtype='int8'):
 
 
 # ---------------------------------------------------------------------------
+# grouped matmul (a served rank's expert products)
+# ---------------------------------------------------------------------------
+
+def _build_grouped(rows, experts, K, N, gated=False):
+    """The benchmark's expert cells: `rows` (token, choice) pairs sorted
+    by held expert, `experts` held matrices of (K, N)."""
+    def build():
+        import jax
+
+        from paddle_tpu.ops.pallas.grouped_matmul import (grouped_gated,
+                                                          grouped_matmul)
+
+        x = _sds((rows, K), 'bfloat16')
+        w = _sds((experts, K, N), 'bfloat16')
+        sizes = _sds((experts,), 'int32')
+        if gated:
+            return (lambda x, g, u, s: grouped_gated(x, g, u, s,
+                                                     jax.nn.silu),
+                    (x, w, w, sizes), {})
+        return grouped_matmul, (x, w, sizes), {}
+
+    return build
+
+
+# ---------------------------------------------------------------------------
 # rms_norm / softmax_xent (fwd + bwd)
 # ---------------------------------------------------------------------------
 
@@ -452,6 +477,42 @@ def _onchip_headmajor():
     assert np.isfinite(out.astype(np.float32)).all()
 
 
+def _onchip_grouped():
+    """Groups of 0 to 40 rows over two row tiles' worth of slabs, an
+    empty expert and un-held rows behind, against `lax.ragged_dot`: the
+    schedule, the masked slabs and both epilogues, on the chip."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops.pallas.grouped_matmul import (grouped_gated,
+                                                      grouped_matmul)
+
+    rng = np.random.default_rng(0)
+    for rows, sizes in ((256, [3, 0, 17, 1, 40, 0, 5, 9]),
+                        (2048, [130, 0, 1000, 64, 16, 1, 500, 0])):
+        E, K, N = len(sizes), 512, 1024
+        x = jnp.asarray(rng.normal(size=(rows, K)), jnp.bfloat16)
+        wg, wu = (jnp.asarray(rng.normal(size=(E, K, N)) * K ** -0.5,
+                              jnp.bfloat16) for _ in range(2))
+        gs = jnp.asarray(sizes, jnp.int32)
+        held = sum(sizes)
+
+        def ragged(w):
+            return jax.lax.ragged_dot(x, w, gs,
+                                      preferred_element_type=jnp.float32)
+
+        got = np.asarray(grouped_matmul(x, wg, gs))[:held]
+        want = np.asarray(ragged(wg))[:held]
+        assert np.isfinite(got).all()
+        assert np.abs(got - want).max() < 2e-2, np.abs(got - want).max()
+        got = np.asarray(grouped_gated(x, wg, wu, gs, jax.nn.silu).astype(
+            jnp.float32))[:held]
+        want = np.asarray((jax.nn.silu(ragged(wg)) * ragged(wu)).astype(
+            jnp.bfloat16).astype(jnp.float32))[:held]
+        assert np.abs(got - want).max() < 5e-2, np.abs(got - want).max()
+
+
 def _onchip_quant_matmul_int4():
     """The packed-int4 unpack (widen, shift, interleave) against the
     native-XLA path over the same packed codes."""
@@ -485,6 +546,8 @@ _DISPATCH = ('paddle_tpu.ops.pallas.decode_attention:'
 _PAGED = 'paddle_tpu.ops.pallas.paged_attention:paged_decode_attention'
 _HEADMAJOR = ('paddle_tpu.ops.pallas.paged_attention:'
               'decode_attention_headmajor')
+_GMM = 'paddle_tpu.ops.pallas.grouped_matmul:grouped_matmul'
+_GATED = 'paddle_tpu.ops.pallas.grouped_matmul:grouped_gated'
 _QMM = 'paddle_tpu.ops.pallas.quant_matmul:quant_matmul'
 _QMM4 = 'paddle_tpu.ops.pallas.quant_matmul:quant_matmul_int4'
 _RMS = 'paddle_tpu.ops.pallas.rms_norm:rms_norm'
@@ -519,6 +582,17 @@ ENTRIES = (
           onchip=_onchip_serve_decode_window),
     Entry('paged_attention/headmajor', _HEADMAJOR, _build_headmajor,
           onchip=_onchip_headmajor),
+    Entry('grouped_matmul/trinity_decode_gated', _GATED,
+          _build_grouped(256, 32, 3072, 3072, gated=True),
+          onchip=_onchip_grouped),
+    Entry('grouped_matmul/trinity_decode_down', _GMM,
+          _build_grouped(256, 32, 3072, 3072)),
+    Entry('grouped_matmul/mimo_decode_gated', _GATED,
+          _build_grouped(512, 16, 4096, 2048, gated=True)),
+    Entry('grouped_matmul/mimo_decode_down', _GMM,
+          _build_grouped(512, 16, 2048, 4096)),
+    Entry('grouped_matmul/mimo_admission_gated', _GATED,
+          _build_grouped(16384, 16, 4096, 2048, gated=True)),
     Entry('quant_matmul/int8', _QMM, _build_quant_matmul('int8')),
     Entry('quant_matmul/fp8', _QMM, _build_quant_matmul('float8_e4m3fn')),
     Entry('quant_matmul/int4', _QMM4, _build_quant_matmul('int4'),

@@ -1,4 +1,4 @@
-"""ML006 — per-pallas_call VMEM budget vs the ~16 MB/core limit.
+"""ML006 — per-pallas_call VMEM budget vs the limit the call runs under.
 
 Every input/output block lives in VMEM twice (the pallas pipeline
 double-buffers: the DMA for grid step i+1 overlaps compute on step i)
@@ -12,10 +12,15 @@ compiler temporaries (dequant copies, relayouts), so the rule warns
 from 75% of the limit and errors past 100%.  bench.py stamps the
 per-kernel estimates into its detail blob so footprint regressions
 show up in the bench history, not just at the gate.
+
+~16 MB is what Mosaic gives a call that asks for nothing. A call that
+states its own `CompilerParams(vmem_limit_bytes=...)` (a weight stream
+in blocks of several MB, `ops/pallas/grouped_matmul.py`) is held to
+that, and never to more than a core has (`VMEM_BYTES_PHYSICAL`).
 """
 from __future__ import annotations
 
-from ..engine import VMEM_BYTES_PER_CORE, MosaicRule
+from ..engine import MosaicRule
 from . import register
 
 WARN_FRACTION = 0.75
@@ -36,20 +41,20 @@ class VmemBudget(MosaicRule):
 
     def check(self, ctx):
         for call in ctx.calls:
-            est = call.vmem_estimate()
-            if est > VMEM_BYTES_PER_CORE:
+            est, budget = call.vmem_estimate(), call.vmem_budget()
+            if est > budget:
                 yield self.violation(
                     ctx,
                     f'{call.name}: estimated VMEM working set '
                     f'{_mb(est):.1f} MB (2x blocks + scratch) exceeds '
-                    f'the ~{_mb(VMEM_BYTES_PER_CORE):.0f} MB/core '
+                    f'the ~{_mb(budget):.0f} MB/core '
                     f'budget — shrink the blocks')
-            elif est > WARN_FRACTION * VMEM_BYTES_PER_CORE:
+            elif est > WARN_FRACTION * budget:
                 yield self.violation(
                     ctx,
                     f'{call.name}: estimated VMEM working set '
                     f'{_mb(est):.1f} MB is within '
                     f'{100 * (1 - WARN_FRACTION):.0f}% of the '
-                    f'~{_mb(VMEM_BYTES_PER_CORE):.0f} MB/core budget — '
+                    f'~{_mb(budget):.0f} MB/core budget — '
                     f'compiler temporaries may tip it over',
                     severity='warning')

@@ -134,14 +134,19 @@ def ragged_expert_apply(tokens, expert_idx, gate_vals, w_gate, w_up, w_down,
     w_gate = _dense_expert(w_gate, x.dtype)
     w_up = _dense_expert(w_up, x.dtype)
     w_down = _dense_expert(w_down, x.dtype)
-    acc = None if held is None else jnp.float32
-    h = act(jax.lax.ragged_dot(x, w_gate, group_sizes,
-                               preferred_element_type=acc))
-    h = h * jax.lax.ragged_dot(x, w_up, group_sizes,
-                               preferred_element_type=acc)
-    y = jax.lax.ragged_dot(h.astype(x.dtype), w_down, group_sizes,
-                           preferred_element_type=acc)        # (T·k, H)
-    y = y * jnp.take(flat_g, order)[:, None].astype(y.dtype)
+    if held is None:
+        h = act(jax.lax.ragged_dot(x, w_gate, group_sizes))
+        h = h * jax.lax.ragged_dot(x, w_up, group_sizes)
+        y = jax.lax.ragged_dot(h.astype(x.dtype), w_down, group_sizes)
+    else:
+        # a served rank's share has 1-8 rows an expert and its cost is
+        # the weights read: on TPU a kernel that reads the experts HIT
+        # (ops/pallas/grouped_matmul.py). The branch above is trained
+        # and differentiated, and stays XLA's op
+        from ..ops import grouped_expert_mlp
+
+        y = grouped_expert_mlp(x, w_gate, w_up, w_down, group_sizes, act)
+    y = y * jnp.take(flat_g, order)[:, None].astype(y.dtype)  # (T·k, H)
     if held is not None:
         # rows past the held groups are whatever the grouped product
         # left there: they are dropped, not weighed
@@ -432,7 +437,7 @@ class MoELayer(Layer):
 # ---------------------------------------------------------------------------
 
 ROUTING_FIELDS = ('picks_total', 'picks_local', 'experts_hit', 'load_max',
-                  'load_mean', 'layer_steps')
+                  'load_mean', 'layer_steps', 'weight_visits')
 _COUNTING = []          # the open `routing_counts()` collectors, innermost last
 
 
@@ -441,7 +446,11 @@ class RoutingCounts:
     routed, summed over those layers in ROUTING_FIELDS' order (float32):
     (token, choice) pairs in all, those that chose an expert held here,
     held experts with at least one pick, the fullest held expert's picks,
-    the mean over the held, and the layers counted. Only `rows` count."""
+    the mean over the held, the layers counted, and the (expert, row tile)
+    visits the grouped-matmul kernel makes for those picks, each one read
+    of an expert's matrices (`ops.pallas.grouped_matmul.visits`: equal to
+    the experts hit where a call's rows are one tile, as a decode step's
+    are). Only `rows` count."""
 
     def __init__(self, rows):
         self.rows = rows
@@ -523,6 +532,8 @@ class ExpertShare(Layer):
                                   self.route_norm, self.route_scale)
 
     def _count(self, expert_idx, shape):
+        from ..ops.pallas.grouped_matmul import visits
+
         rows = _COUNTING[-1].rows
         rows = (jnp.ones(shape, bool) if rows is None
                 else jnp.broadcast_to(rows, shape)).reshape(-1, 1)
@@ -536,7 +547,9 @@ class ExpertShare(Layer):
             rows.sum().astype(jnp.float32) * self.top_k, local_picks,
             (load > 0).sum().astype(jnp.float32), load.max(),
             local_picks / self.experts_held,
-            rows.any().astype(jnp.float32)]))
+            rows.any().astype(jnp.float32),
+            visits(load.astype(jnp.int32), expert_idx.size).sum().astype(
+                jnp.float32)]))
 
     def forward(self, x):
         B, S, H = x.shape

@@ -110,3 +110,33 @@ def softmax_cross_entropy(logits, labels):
                            (logits, labels), (rows, P(*rows[:-1])), out=1)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     return -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+
+
+def grouped_expert_mlp(x, w_gate, w_up, w_down, group_sizes, act):
+    """The three grouped products of ONE RANK'S SHARE of an expert layer
+    (`distributed.moe.ExpertShare`), over rows sorted by held expert:
+    act(x @ w_gate[e]) * (x @ w_up[e]), rounded to x's dtype, then
+    @ w_down[e]; float32 accumulation, (rows, H) float32. Rows behind the
+    held groups hold whatever the products left there. Pallas on TPU
+    (ops/pallas/grouped_matmul.py: the weights of the experts HIT, once
+    each; whole on every shard of a mesh), `lax.ragged_dot` elsewhere."""
+    import jax.numpy as jnp
+
+    if use_pallas():
+        from jax.sharding import PartitionSpec as P
+
+        from .pallas.grouped_matmul import grouped_gated, grouped_matmul
+
+        def kernel(x_, gate_, up_, down_, sizes_):
+            return grouped_matmul(
+                grouped_gated(x_, gate_, up_, sizes_, act), down_, sizes_)
+
+        return mesh_kernel(kernel, (x, w_gate, w_up, w_down, group_sizes),
+                           (P(),) * 5)
+
+    def product(rows, w):
+        return jax.lax.ragged_dot(rows, w, group_sizes,
+                                  preferred_element_type=jnp.float32)
+
+    h = act(product(x, w_gate)) * product(x, w_up)
+    return product(h.astype(x.dtype), w_down)

@@ -9,6 +9,15 @@ with its bench-representative shape suites.  mosaiclint
 enforces the TPU lowering rules (tile alignment, tail masking, VMEM
 budget, ...); `tests/test_mosaiclint.py::TestMeta` fails if a module
 here has no registry entry, so a new kernel cannot land unanalyzed.
+
+The kernels: `flash_attention` (training and prefill attention, custom
+VJP), `decode_attention` (a contiguous cache's single-token step),
+`paged_attention` (block-table decode over page pools, and the
+head-major form), `grouped_matmul` (a served rank's expert products: the
+hit experts' weights once; its row tile follows the call's static row
+count, the whole call up to 1024 rows; `distributed.moe`'s branch without
+`expert_offset` keeps `lax.ragged_dot`), `quant_matmul` (weight-only
+int8 / fp8 / int4), `rms_norm`, `softmax_xent`.
 """
 
 

@@ -255,3 +255,28 @@ def test_quant_matmul(chip_compile, bits, rows):
         text = chip_compile(quant_matmul, x, ((HIDDEN, FFN), jnp.int8),
                             scale)
     assert 'tpu_custom_call' in text
+
+
+@pytest.mark.parametrize('rows,experts,K,N', [
+    (256, 32, 3072, 3072),              # Trinity's token-step: 64 x top 4
+    (512, 16, 4096, 2048),              # MiMo's, gate and up: 64 x top 8
+    (512, 16, 2048, 4096),              # MiMo's, down
+    (16384, 16, 4096, 2048),            # MiMo's admission of 2,048 tokens
+])
+@pytest.mark.parametrize('gated', [True, False])
+def test_grouped_matmul(chip_compile, gated, rows, experts, K, N):
+    """The served expert layers' products at the benchmark cells' shapes:
+    blocks of several MB under the limit the call states for itself."""
+    from paddle_tpu.ops.pallas.grouped_matmul import (grouped_gated,
+                                                      grouped_matmul)
+
+    x = ((rows, K), jnp.bfloat16)
+    w = ((experts, K, N), jnp.bfloat16)
+    sizes = ((experts,), jnp.int32)
+    if gated:
+        text = chip_compile(
+            lambda x, g, u, s: grouped_gated(x, g, u, s, jax.nn.silu),
+            x, w, w, sizes)
+    else:
+        text = chip_compile(grouped_matmul, x, w, sizes)
+    assert 'tpu_custom_call' in text

@@ -26,7 +26,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.analysis import (filter_new, load_baseline, write_baseline)
 from paddle_tpu.analysis.mosaic import (Entry, KernelContext,
-                                        VMEM_BYTES_PER_CORE, all_entries,
+                                        VMEM_BYTES_PER_CORE,
+                                        VMEM_BYTES_PHYSICAL, all_entries,
                                         all_rules, extract_pallas_calls,
                                         lint_entries, sublane_multiple,
                                         trace_entry, vmem_report)
@@ -350,7 +351,32 @@ class TestML006:
         report = vmem_report(all_entries(), root=REPO)
         assert set(report) == {e.name for e in all_entries()}
         for name, est in report.items():
-            assert 0 < est <= VMEM_BYTES_PER_CORE, (name, est)
+            # a grouped-matmul call states its own limit, and ML006 (the
+            # meta-test below) holds it to that
+            cap = (VMEM_BYTES_PHYSICAL if name.startswith('grouped_matmul/')
+                   else VMEM_BYTES_PER_CORE)
+            assert 0 < est <= cap, (name, est)
+
+    @pytest.mark.parametrize('rows,limit,passes', [
+        # 2 x (4096 x 1024 f32 in + out) = 64 MB
+        (4096, 96 * 1024 * 1024, True),     # its own limit, which a core has
+        (4096, None, False),                # the default: ~16 MB
+        # 2 x 2 x 36 MB = 144 MB
+        (9216, 1024 * 1024 * 1024, False),  # no core has what it states
+    ])
+    def test_a_call_is_held_to_the_limit_it_states(self, rows, limit,
+                                                   passes):
+        def fn(x):
+            return pl.pallas_call(
+                _copy_kernel, grid=(1,),
+                in_specs=[pl.BlockSpec((rows, 1024), lambda i: (0, 0))],
+                out_specs=pl.BlockSpec((rows, 1024), lambda i: (0, 0)),
+                out_shape=SDS((rows, 1024), jnp.float32),
+                compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit),
+                interpret=True)(x)
+
+        assert ('ML006' not in codes(fn, SDS((rows, 1024), jnp.float32))
+                ) == passes
 
 
 # ---------------------------------------------------------------------------
