@@ -86,12 +86,12 @@ FULL = Sizes(width=WIDTH, serve_layers=11, slots=8, context=2048,
              new_tokens=24, tolerance=0.25, train_layers=4, train_batch=3,
              train_seq=2048, train_steps=4)
 
-# The kernels each phase's compiled programs must carry. No flash attention
-# on the serve side: the admission prefill runs the model over a throwaway
-# contiguous cache, and cached_attention attends a multi-token query through
-# the masked XLA path, not the flash kernel (found by this script on the
-# chip; PERF.md, PR 21). The decode windows use the paged kernel.
-SERVE_KERNELS = {'paged_attention', 'rms_norm'}
+# The kernels each phase's compiled programs must carry. On the serve side
+# the admission prefill runs the model over a throwaway contiguous cache
+# from position 0, so cached_attention hands its attention to the flash
+# kernel's forward (`prefill_attention`); the decode windows use the paged
+# kernel.
+SERVE_KERNELS = {'flash_attention', 'paged_attention', 'rms_norm'}
 TRAIN_KERNELS = {'flash_attention', 'rms_norm', 'softmax_xent'}
 
 

@@ -76,6 +76,44 @@ def _build_flash_segmented():
     return fwd, (q, q, q, seg), {}
 
 
+def _prefill(B, S, H, Hkv, D, Dv, window, sink):
+    """The serving prefill's forward kernel at a cell's admission shape
+    (grouped heads a step, a banded grid under a window)."""
+    def build():
+        from paddle_tpu.ops.pallas.flash_attention import prefill_attention
+
+        q = _sds((B, S, H, D), 'bfloat16')
+        k = _sds((B, S, Hkv, D), 'bfloat16')
+        v = _sds((B, S, Hkv, Dv), 'bfloat16')
+        args = (q, k, v) + ((_sds((H,), 'float32'),) if sink else ())
+        return (lambda *a: prefill_attention(*a, window=window)), args, {}
+
+    return build
+
+
+def _onchip_prefill():
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.models.llama import masked_attention
+    from paddle_tpu.ops.pallas.flash_attention import prefill_attention
+
+    rng = np.random.default_rng(0)
+    B, S, H, Hkv, D, Dv, w = 1, 1024, 16, 2, 192, 128, 128
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+
+    q, k, v = draw(B, S, H, D), draw(B, S, Hkv, D), draw(B, S, Hkv, Dv)
+    sink = jnp.asarray(rng.normal(size=(H,)), jnp.float32)
+    got = np.asarray(prefill_attention(q, k, v, sink, window=w),
+                     np.float32)
+    ahead = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+    mask = ((ahead >= 0) & (ahead < w))[None, None]
+    want = np.asarray(masked_attention(q, k, v, mask, sink), np.float32)
+    assert np.max(np.abs(got - want)) <= 2e-2 * np.max(np.abs(want))
+
+
 # ---------------------------------------------------------------------------
 # decode attention (contiguous cache, serving entry)
 # ---------------------------------------------------------------------------
@@ -540,6 +578,7 @@ def _onchip_quant_matmul_int4():
 # ---------------------------------------------------------------------------
 
 _FLASH = 'paddle_tpu.ops.pallas.flash_attention:flash_attention'
+_PREFILL = 'paddle_tpu.ops.pallas.flash_attention:prefill_attention'
 _DECODE = 'paddle_tpu.ops.pallas.decode_attention:decode_attention'
 _DISPATCH = ('paddle_tpu.ops.pallas.decode_attention:'
              'dispatch_decode_attention')
@@ -563,6 +602,15 @@ ENTRIES = (
     Entry('flash_attention/tail_fwd_bwd', _FLASH,
           _flash_fwd_bwd(S=1792, H=8, causal=True)),
     Entry('flash_attention/segmented_fwd', _FLASH, _build_flash_segmented),
+    Entry('flash_attention/mimo_window_prefill', _PREFILL,
+          _prefill(1, 2048, 64, 8, 192, 128, 128, True),
+          onchip=_onchip_prefill),
+    Entry('flash_attention/mimo_full_prefill', _PREFILL,
+          _prefill(1, 2048, 64, 4, 192, 128, None, False)),
+    Entry('flash_attention/mistral_prefill', _PREFILL,
+          _prefill(1, 1024, 32, 8, 128, 128, None, False)),
+    Entry('flash_attention/trinity_prefill', _PREFILL,
+          _prefill(4, 256, 48, 8, 128, 128, None, False)),
     Entry('decode_attention/bf16_start', _DECODE, _build_decode_start,
           onchip=_onchip_decode_start),
     Entry('decode_attention/int8_cache', _DECODE, _build_decode_int8,

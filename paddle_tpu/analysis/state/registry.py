@@ -235,6 +235,8 @@ _SERVING = ClassDecl(
         '_dlogits': device(note='draft logits'),
         '_dummy_slots': device(note='warmup dummy slot buffers'),
         '_draft_shapes': device(note='draft dispatch shape cache'),
+        '_kernel_layers': derived(note='attn_kernel_layers a bucket; '
+                                  'recounted from the model on use'),
         '_zero_ftok': device(note='zero forced-token buffer'),
         '_zero_forced': device(note='zero forced-count buffer'),
         '_rep': device(note='replicated sharding handle'),

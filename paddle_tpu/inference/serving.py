@@ -1749,6 +1749,7 @@ class ServingEngine:
                               and self.win_allocator.num_blocks),
                 self.block_size, dtype=self.kv_cache_dtype)
             self._dpages = None
+            self._kernel_layers = {}        # bucket -> attn_kernel_layers
             if self.draft is not None:
                 # the draft's pools share the target's page-id space:
                 # same num_blocks/block_size, indexed by the same block
@@ -4596,12 +4597,41 @@ class ServingEngine:
         and `serve.prefill` report it: its `rows` real rows and their
         real tokens beside the `padded_rows * bucket` positions the
         batch holds. The width is the admission batch's unless given (a
-        chunk batch is `max_slots` wide); a bare window has none."""
+        chunk batch is `max_slots` wide); a bare window has none. An
+        admission batch also reports `attn_kernel_layers`
+        (`_attn_kernel_layers`); a chunk batch continues rows from their
+        pages and a bare window prefills nothing, so theirs is 0."""
+        admission = bool(bucket) and padded_rows is None
         if padded_rows is None:
             padded_rows = self._prefill_rows(bucket) if bucket else 0
         return dict(bucket=bucket, rows=len(real_lens),
                     padded_rows=padded_rows, real_tokens=sum(real_lens),
-                    padded_tokens=padded_rows * bucket)
+                    padded_tokens=padded_rows * bucket,
+                    attn_kernel_layers=(self._attn_kernel_layers(bucket)
+                                        if admission else 0))
+
+    def _attn_kernel_layers(self, bucket):
+        """How many layers' attention an admission prefill at `bucket`
+        runs through the flash kernel, the target's and the draft's: the
+        layers whose temp-cache entry `models.llama.prefill_takes_kernel`
+        routes to it, which is the test `cached_attention` makes when the
+        dispatch traces (0 off the chip, on int8 pools and under
+        `PREFILL_KERNEL_MIN_ROWS`)."""
+        n = self._kernel_layers.get(bucket)
+        if n is None:
+            from ..models.llama import prefill_takes_kernel
+
+            n = 0
+            for model, pages in ((self.model, self._pages),
+                                 (self.draft, self._dpages)):
+                if model is None:
+                    continue
+                tmp = jax.eval_shape(
+                    lambda: _tmp_cache(model, pages, 1, bucket))
+                n += sum(prefill_takes_kernel(bucket, c[0].shape[-1], c, 0)
+                         for c in tmp)
+            self._kernel_layers[bucket] = n
+        return n
 
     def _prefill_args(self, Sb, group):
         """Device args for one fixed-width admission-prefill batch

@@ -229,6 +229,60 @@ def _xla_attention(q, k, v, mask, sink):
     return masked_attention(q, k, v, mask, sink)
 
 
+# The fewest rows a fresh prefill hands to the flash kernel. On a v5e the
+# kernel took 1.2-2.0 x the masked XLA path's time at 128-512 rows of
+# 128-wide heads (8 x 128 to 2 x 512 rows a call, the admission batches
+# of those buckets) and 0.14-0.48 x from 1,024 rows on
+# (`tools/prefill_attn_sweep.py`; PERF.md section 6).
+PREFILL_KERNEL_MIN_ROWS = 1024
+
+
+def prefill_takes_kernel(S, D, cache, cache_index, kvalid=None,
+                         kv_start=None, kv_write_pos=None):
+    """Whether `cached_attention` hands a call's attention to the flash
+    kernel (`ops.pallas.flash_attention.prefill_attention`): a fresh
+    prefill, S >= PREFILL_KERNEL_MIN_ROWS new rows written from a
+    concrete position 0 (no per-row write offsets, no pad hole, no window
+    start) into a plain floating (k, v) cache, on the pallas path. Its
+    cache holds nothing before those rows, and right-padding sits after
+    the real tokens, so the rows attending each other causally is the
+    whole attention.
+    Int8 caches keep the XLA path: their rows are attended as the int8
+    round trip gives them back, which is what keeps a served stream
+    bit-equal across prefill, chunk and decode. `cache` may be abstract
+    (`jax.eval_shape`): the serving engine counts its layers with it."""
+    from ..ops import use_pallas
+    from .generation import (PagedKVCache, QuantKVCache, QuantPagedKVCache,
+                             RowQuantKVCache)
+
+    return (S >= PREFILL_KERNEL_MIN_ROWS and D % 8 == 0 and kvalid is None
+            and kv_start is None and kv_write_pos is None
+            and not isinstance(cache, (PagedKVCache, QuantPagedKVCache,
+                                       QuantKVCache, RowQuantKVCache))
+            and jnp.issubdtype(cache[0].dtype, jnp.floating)
+            and jnp.dtype(cache[0].dtype).itemsize >= 2
+            and not isinstance(cache_index, jax.core.Tracer)
+            and int(cache_index) == 0 and use_pallas())
+
+
+def _prefill_kernel(q, k, v, window, sink):
+    """The fresh prefill's attention through the flash kernel, per shard
+    under a mesh (batch over the data axes, heads over tp) as the decode
+    kernel runs."""
+    from ..ops import DATA_AXES, head_axis, mesh_kernel
+    from ..ops.pallas.flash_attention import prefill_attention
+
+    def kernel(q_, k_, v_, *sink_):
+        return prefill_attention(q_, k_, v_, *sink_, window=window)
+
+    heads = head_axis(q.shape[2], k.shape[2])
+    hspec = P(DATA_AXES, None, heads, None)
+    args, specs = (q, k, v), (hspec,) * 3
+    if sink is not None:
+        args, specs = args + (sink,), specs + (P(heads),)
+    return mesh_kernel(kernel, args, specs)
+
+
 def cached_attention(q, k, v, cache, cache_index, kvalid=None,
                      kv_start=None, kv_write_pos=None, window=None,
                      block_tables=None, sink=None):
@@ -252,7 +306,9 @@ def cached_attention(q, k, v, cache, cache_index, kvalid=None,
     head in the softmax's denominator, with no value (a learned attention
     sink); it and a V row of another width than a K row take the XLA path
     (`masked_attention`) but on paged decode, where the paged kernel has
-    both. Returns (out (B, S, H, Dv), new_cache).
+    both, and on a fresh prefill (`prefill_takes_kernel`), whose rows
+    attend each other through the flash kernel, which has both too.
+    Returns (out (B, S, H, Dv), new_cache).
 
     A QuantKVCache stores K/V int8 with per-(head, dim) scales: prefill
     (S > 1) calibrates the scales from its own rows, decode steps
@@ -378,6 +434,11 @@ def cached_attention(q, k, v, cache, cache_index, kvalid=None,
                 kernel, (q, ck, cv, vl, st) + scales,
                 (hspec, hspec, hspec, P(DATA_AXES), P(DATA_AXES))
                 + (P(heads, None),) * len(scales))
+    elif prefill_takes_kernel(S, D, cache, cache_index, kvalid, kv_start,
+                              kv_write_pos):
+        # the new rows as the cache holds them
+        out = _prefill_kernel(q, k.astype(ck.dtype), v.astype(cv.dtype),
+                              window, sink)
     if out is None:
         # valid keys: position <= current query position (& kvalid)
         kpos = jnp.arange(max_len)

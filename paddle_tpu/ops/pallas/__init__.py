@@ -10,8 +10,10 @@ enforces the TPU lowering rules (tile alignment, tail masking, VMEM
 budget, ...); `tests/test_mosaiclint.py::TestMeta` fails if a module
 here has no registry entry, so a new kernel cannot land unanalyzed.
 
-The kernels: `flash_attention` (training and prefill attention, custom
-VJP), `decode_attention` (a contiguous cache's single-token step),
+The kernels: `flash_attention` (training attention, custom VJP; and
+`prefill_attention`, the forward a fresh serving prefill takes: a sink,
+V narrower than K, a kv head's query heads a grid step and a grid that
+walks only a window's band), `decode_attention` (a contiguous cache's single-token step),
 `paged_attention` (block-table decode over page pools, and the
 head-major form), `grouped_matmul` (a served rank's expert products: the
 hit experts' weights once; its row tile follows the call's static row

@@ -12,7 +12,9 @@ or collection time: only one process may hold libtpu, and under xdist every
 worker imports this file while only one is handed its tests. Keep these
 tests in this one file for the same reason.
 """
+import hashlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +92,87 @@ def test_flash_attention_fwd_bwd(chip_compile):
         _grad_of_sum(lambda q, k, v: flash_attention(q, k, v, causal=True),
                      (0, 1, 2)), QKV, QKV, QKV)
     assert text.count('tpu_custom_call') >= 3        # fwd, dq, dkv
+
+
+def _without_locations(text):
+    """A compiled program's text less what moves with a source line: the
+    stack-frame tables, each op's metadata and each Mosaic body (which
+    carries its kernel's locations; the kernel's jaxpr stands for it)."""
+    text = re.sub(r'\n\n(FileNames|FunctionNames|FileLocations|StackFrames)'
+                  r'\n.*?(?=\n\n)', '', text, flags=re.S)
+    text = re.sub(r'"body":"[^"]*"', '"body":""', text)
+    return re.sub(r', metadata=\{[^}]*\}', '', text)
+
+
+# The train cell's flash call (qwen25_3b_pretrain_4k: 1 x 4096, 16 query
+# heads over 2, head 128), sha256 of its jaxpr and of its compiled text
+# less locations, as they were before the forward kernel learned sinks,
+# narrower V rows, grouped heads and banded grids. Those features are
+# the serving prefill's; the train program has to be this one, byte for
+# byte, or its numbers are another program's.
+TRAIN_FLASH = {
+    'fwd': ('2856787e3e3ef3231cdc9ec78944917590b1911e504f3356536633bf76f1136e',
+            'b465e2c50753097c60aab218c9dfb4ec42237ea9a2d434c9169115d73052a697'),
+    'fwd_bwd': (
+        '7a80cc81d8bc0d99b170c0e72081f09ed3a86432d62f182f2c9e504d24ccea05',
+        '9b43c9cfe24ff1c1ace5b083041aed3111eed29e8d0a803186341858009acfdd'),
+}
+
+
+@pytest.mark.parametrize('what', sorted(TRAIN_FLASH))
+def test_the_train_cells_flash_program_is_unchanged(chip_compile, one_chip,
+                                                    what):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    specs = [((1, 4096, h, 128), jnp.bfloat16) for h in (16, 2, 2)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    fn = fwd if what == 'fwd' else _grad_of_sum(fwd, (0, 1, 2))
+    jaxpr = str(jax.make_jaxpr(fn)(*args))
+    text = _without_locations(chip_compile(fn, *specs))
+    assert tuple(hashlib.sha256(t.encode()).hexdigest()
+                 for t in (jaxpr, text)) == TRAIN_FLASH[what]
+
+
+# The admission prefill's attention (`prefill_attention`, what
+# `cached_attention` calls on a fresh prefill) at the serving cells'
+# shapes: (rows, bucket, query heads, kv heads, K width, V width, window,
+# sink)
+PREFILL = {
+    'mimo_window_2048': (1, 2048, 64, 8, 192, 128, 128, True),
+    'mimo_full_2048': (1, 2048, 64, 4, 192, 128, None, False),
+    'mistral_1024': (1, 1024, 32, 8, 128, 128, None, False),
+    'trinity_256': (4, 256, 48, 8, 128, 128, None, False),
+}
+
+
+@pytest.mark.parametrize('cell', sorted(PREFILL))
+def test_prefill_attention(chip_compile, cell):
+    from paddle_tpu.ops.pallas.flash_attention import prefill_attention
+
+    B, S, H, Hkv, D, Dv, window, sink = PREFILL[cell]
+    specs = [((B, S, H, D), jnp.bfloat16), ((B, S, Hkv, D), jnp.bfloat16),
+             ((B, S, Hkv, Dv), jnp.bfloat16)]
+    if sink:
+        specs.append(((H,), jnp.float32))
+    text = chip_compile(
+        lambda *a: prefill_attention(*a, window=window), *specs)
+    assert text.count('tpu_custom_call') == 1
+
+
+def test_prefill_blocks_fit_the_vmem_budget():
+    """mosaiclint's ML006 over the prefill kernel's registered shapes: the
+    double-buffered blocks and the scratch of every grid step fit a
+    core's default scoped VMEM."""
+    from paddle_tpu.analysis.mosaic import all_entries, get_rule, lint_entries
+
+    entries = [e for e in all_entries() if e.name.endswith('_prefill')]
+    assert len(entries) == 4
+    violations, _ = lint_entries(entries, rules=[get_rule('ML006')])
+    assert not violations, violations
 
 
 def test_rms_norm_fwd_bwd(chip_compile):

@@ -300,3 +300,25 @@ def test_a_model_of_one_kind_is_served_as_it_was():
         and not {'win_pages_held', 'kv_bytes_held', 'full_pages_held'} & set(a)
         for a in counted)
     assert not [e for e in TRACER.events() if e['name'] == 'serve.recycle']
+
+
+def test_its_prefill_through_the_flash_kernel_is_the_xla_prefill(monkeypatch):
+    """On the pallas path (interpret mode here) a fresh prefill's window
+    layers (sink, band) and full layers (V narrower than K) attend through
+    the flash kernel: the logits are the XLA path's, and the engine counts
+    every layer for `serve.prefill` / `serve.dispatch`."""
+    from paddle_tpu import ops
+
+    engine = fresh_engine()
+    model = engine.model
+    S = llama.PREFILL_KERNEL_MIN_ROWS
+    (ids,) = prompts_of(S)
+    ids = jnp.asarray(ids)[None]
+    want, _ = model(ids, caches=model.init_cache(1, S), cache_index=0)
+    assert engine._attn_kernel_layers(S) == 0
+    engine._kernel_layers.clear()
+    monkeypatch.setattr(ops, '_on_tpu', lambda: True)
+    got, _ = model(ids, caches=model.init_cache(1, S), cache_index=0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=EXACT * 4, atol=EXACT * 4)
+    assert engine._attn_kernel_layers(S) == CFG['num_hidden_layers']
