@@ -118,40 +118,43 @@ def ragged_expert_apply(tokens, expert_idx, gate_vals, w_gate, w_up, w_down,
     """
     T, H = tokens.shape
     k = expert_idx.shape[1]
-    flat_e = expert_idx.reshape(-1).astype(jnp.int32)         # (T·k,)
-    flat_g = gate_vals.reshape(-1)
-    held = None
-    if expert_offset is not None:
-        flat_e = flat_e - expert_offset
-        held = (flat_e >= 0) & (flat_e < num_experts)
-        flat_e = jnp.where(held, flat_e, num_experts)         # sorts last
-    order = jnp.argsort(flat_e, stable=True)
-    tok_ids = order // k                                      # source token
-    x = jnp.take(tokens, tok_ids, axis=0)                     # (T·k, H)
-    group_sizes = jnp.bincount(
-        flat_e, length=num_experts + (held is not None))[:num_experts]
-    group_sizes = group_sizes.astype(jnp.int32)
-    w_gate = _dense_expert(w_gate, x.dtype)
-    w_up = _dense_expert(w_up, x.dtype)
-    w_down = _dense_expert(w_down, x.dtype)
-    if held is None:
-        h = act(jax.lax.ragged_dot(x, w_gate, group_sizes))
-        h = h * jax.lax.ragged_dot(x, w_up, group_sizes)
-        y = jax.lax.ragged_dot(h.astype(x.dtype), w_down, group_sizes)
-    else:
-        # a served rank's share has 1-8 rows an expert and its cost is
-        # the weights read: on TPU a kernel that reads the experts HIT
-        # (ops/pallas/grouped_matmul.py). The branch above is trained
-        # and differentiated, and stays XLA's op
-        from ..ops import grouped_expert_mlp
+    with jax.named_scope('dispatch'):
+        flat_e = expert_idx.reshape(-1).astype(jnp.int32)     # (T·k,)
+        flat_g = gate_vals.reshape(-1)
+        held = None
+        if expert_offset is not None:
+            flat_e = flat_e - expert_offset
+            held = (flat_e >= 0) & (flat_e < num_experts)
+            flat_e = jnp.where(held, flat_e, num_experts)     # sorts last
+        order = jnp.argsort(flat_e, stable=True)
+        tok_ids = order // k                                  # source token
+        x = jnp.take(tokens, tok_ids, axis=0)                 # (T·k, H)
+        group_sizes = jnp.bincount(
+            flat_e, length=num_experts + (held is not None))[:num_experts]
+        group_sizes = group_sizes.astype(jnp.int32)
+        w_gate = _dense_expert(w_gate, x.dtype)
+        w_up = _dense_expert(w_up, x.dtype)
+        w_down = _dense_expert(w_down, x.dtype)
+    with jax.named_scope('matmul'):
+        if held is None:
+            h = act(jax.lax.ragged_dot(x, w_gate, group_sizes))
+            h = h * jax.lax.ragged_dot(x, w_up, group_sizes)
+            y = jax.lax.ragged_dot(h.astype(x.dtype), w_down, group_sizes)
+        else:
+            # a served rank's share has 1-8 rows an expert and its cost is
+            # the weights read: on TPU a kernel that reads the experts HIT
+            # (ops/pallas/grouped_matmul.py). The branch above is trained
+            # and differentiated, and stays XLA's op
+            from ..ops import grouped_expert_mlp
 
-        y = grouped_expert_mlp(x, w_gate, w_up, w_down, group_sizes, act)
-    y = y * jnp.take(flat_g, order)[:, None].astype(y.dtype)  # (T·k, H)
-    if held is not None:
-        # rows past the held groups are whatever the grouped product
-        # left there: they are dropped, not weighed
-        y = jnp.where(jnp.take(held, order)[:, None], y, 0.0)
-    return jnp.zeros((T, H), y.dtype).at[tok_ids].add(y)
+            y = grouped_expert_mlp(x, w_gate, w_up, w_down, group_sizes, act)
+    with jax.named_scope('combine'):
+        y = y * jnp.take(flat_g, order)[:, None].astype(y.dtype)  # (T·k,H)
+        if held is not None:
+            # rows past the held groups are whatever the grouped product
+            # left there: they are dropped, not weighed
+            y = jnp.where(jnp.take(held, order)[:, None], y, 0.0)
+        return jnp.zeros((T, H), y.dtype).at[tok_ids].add(y)
 
 
 # ---------------------------------------------------------------------------
@@ -492,7 +495,10 @@ class ExpertShare(Layer):
     (`ragged_expert_apply`); the router's product runs in float32."""
 
     no_quantize = ('router', 'expert_bias')
-    SCOPE = 'expert_share'      # the jax.named_scope its ops run under
+    # the jax.named_scope its ops run under, one entry of
+    # `observability.tracing.DEVICE_SCOPES`: its stages (route, dispatch,
+    # matmul, combine, shared) open theirs inside it
+    SCOPE = 'expert_share'
 
     def __init__(self, hidden, intermediate, num_experts, top_k,
                  experts_held=None, expert_offset=0, shared_intermediate=0,
@@ -526,30 +532,32 @@ class ExpertShare(Layer):
 
     def route(self, tokens):
         """(gate_vals (T, k) f32, expert_idx (T, k)) over all experts."""
-        logits = jnp.matmul(tokens.astype(jnp.float32), self.router,
-                            precision=jax.lax.Precision.HIGHEST)
-        return sigmoid_topk_gates(logits, self.expert_bias, self.top_k,
-                                  self.route_norm, self.route_scale)
+        with jax.named_scope('route'):
+            logits = jnp.matmul(tokens.astype(jnp.float32), self.router,
+                                precision=jax.lax.Precision.HIGHEST)
+            return sigmoid_topk_gates(logits, self.expert_bias, self.top_k,
+                                      self.route_norm, self.route_scale)
 
     def _count(self, expert_idx, shape):
         from ..ops.pallas.grouped_matmul import visits
 
-        rows = _COUNTING[-1].rows
-        rows = (jnp.ones(shape, bool) if rows is None
-                else jnp.broadcast_to(rows, shape)).reshape(-1, 1)
-        local = expert_idx - self.expert_offset
-        held = rows & (local >= 0) & (local < self.experts_held)
-        load = jnp.zeros((self.experts_held,), jnp.float32).at[
-            jnp.where(held, local, self.experts_held).reshape(-1)].add(
-                1.0, mode='drop')
-        local_picks = load.sum()
-        _COUNTING[-1].layers.append(jnp.stack([
-            rows.sum().astype(jnp.float32) * self.top_k, local_picks,
-            (load > 0).sum().astype(jnp.float32), load.max(),
-            local_picks / self.experts_held,
-            rows.any().astype(jnp.float32),
-            visits(load.astype(jnp.int32), expert_idx.size).sum().astype(
-                jnp.float32)]))
+        with jax.named_scope('route'):
+            rows = _COUNTING[-1].rows
+            rows = (jnp.ones(shape, bool) if rows is None
+                    else jnp.broadcast_to(rows, shape)).reshape(-1, 1)
+            local = expert_idx - self.expert_offset
+            held = rows & (local >= 0) & (local < self.experts_held)
+            load = jnp.zeros((self.experts_held,), jnp.float32).at[
+                jnp.where(held, local, self.experts_held).reshape(-1)].add(
+                    1.0, mode='drop')
+            local_picks = load.sum()
+            _COUNTING[-1].layers.append(jnp.stack([
+                rows.sum().astype(jnp.float32) * self.top_k, local_picks,
+                (load > 0).sum().astype(jnp.float32), load.max(),
+                local_picks / self.experts_held,
+                rows.any().astype(jnp.float32),
+                visits(load.astype(jnp.int32), expert_idx.size).sum().astype(
+                    jnp.float32)]))
 
     def forward(self, x):
         B, S, H = x.shape
@@ -563,7 +571,8 @@ class ExpertShare(Layer):
                 self.w_down, self.experts_held, act=self.act,
                 expert_offset=self.expert_offset)
             if self.shared_gate is not None:
-                hid = (self.act(tokens @ self.shared_gate)
-                       * (tokens @ self.shared_up))
-                out = out + (hid @ self.shared_down).astype(out.dtype)
+                with jax.named_scope('shared'):
+                    hid = (self.act(tokens @ self.shared_gate)
+                           * (tokens @ self.shared_up))
+                    out = out + (hid @ self.shared_down).astype(out.dtype)
             return out.reshape(B, S, H).astype(x.dtype)

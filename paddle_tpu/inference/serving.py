@@ -724,14 +724,15 @@ def _tmp_cache(model, pages, K, Sb):
     sees. That shared world is what keeps int8 greedy streams bit-equal
     across monolithic prefill, chunked prefill, speculative windows,
     preemption re-prefill, and prefix-cache hits."""
-    if _pool_quant(pages):
-        from ..models.generation import RowQuantKVCache
+    with jax.named_scope('attn/cache'):
+        if _pool_quant(pages):
+            from ..models.generation import RowQuantKVCache
 
-        _, Hkv, _, D = pages[0].kp.shape
-        z8 = jnp.zeros((K, Sb, Hkv, D), jnp.int8)
-        zs = jnp.zeros((K, Sb, Hkv), jnp.float32)
-        return [RowQuantKVCache(z8, z8, zs, zs) for _ in pages]
-    return model.init_cache(K, Sb)
+            _, Hkv, _, D = pages[0].kp.shape
+            z8 = jnp.zeros((K, Sb, Hkv, D), jnp.int8)
+            zs = jnp.zeros((K, Sb, Hkv), jnp.float32)
+            return [RowQuantKVCache(z8, z8, zs, zs) for _ in pages]
+        return model.init_cache(K, Sb)
 
 
 def _pool_scatter(pc, tmp_entry, pflat, sflat, take=None):
@@ -741,35 +742,36 @@ def _pool_scatter(pc, tmp_entry, pflat, sflat, take=None):
     bodies scatter only the rows they wrote, clamped in-range). Int8
     pools copy int8 bytes AND the per-row scales — no requantization,
     so the pool holds exactly what the temp-cache write produced."""
-    if hasattr(pc, 'ks'):
-        kq, vq, ks, vs = tmp_entry
+    with jax.named_scope('attn/cache'):
+        if hasattr(pc, 'ks'):
+            kq, vq, ks, vs = tmp_entry
+            if take is not None:
+                idx4 = take[:, :, None, None]
+                idx3 = take[:, :, None]
+                kq = jnp.take_along_axis(kq, idx4, axis=1)
+                vq = jnp.take_along_axis(vq, idx4, axis=1)
+                ks = jnp.take_along_axis(ks, idx3, axis=1)
+                vs = jnp.take_along_axis(vs, idx3, axis=1)
+            rows = (pflat.shape[0],) + kq.shape[2:]
+            srows = (pflat.shape[0],) + ks.shape[2:]
+            return type(pc)(
+                pc.kp.at[pflat, :, sflat, :].set(kq.reshape(rows)),
+                pc.vp.at[pflat, :, sflat, :].set(vq.reshape(rows)),
+                pc.ks.at[pflat, :, sflat].set(ks.reshape(srows)),
+                pc.vs.at[pflat, :, sflat].set(vs.reshape(srows)))
+        k, v = tmp_entry
         if take is not None:
             idx4 = take[:, :, None, None]
-            idx3 = take[:, :, None]
-            kq = jnp.take_along_axis(kq, idx4, axis=1)
-            vq = jnp.take_along_axis(vq, idx4, axis=1)
-            ks = jnp.take_along_axis(ks, idx3, axis=1)
-            vs = jnp.take_along_axis(vs, idx3, axis=1)
-        rows = (pflat.shape[0],) + kq.shape[2:]
-        srows = (pflat.shape[0],) + ks.shape[2:]
-        return type(pc)(
-            pc.kp.at[pflat, :, sflat, :].set(kq.reshape(rows)),
-            pc.vp.at[pflat, :, sflat, :].set(vq.reshape(rows)),
-            pc.ks.at[pflat, :, sflat].set(ks.reshape(srows)),
-            pc.vs.at[pflat, :, sflat].set(vs.reshape(srows)))
-    k, v = tmp_entry
-    if take is not None:
-        idx4 = take[:, :, None, None]
-        k = jnp.take_along_axis(k, idx4, axis=1)
-        v = jnp.take_along_axis(v, idx4, axis=1)
-    def rows(x, pool):
-        """x's (K, S) rows flat, at the pool's width (a row wider than a
-        lane tile is kept in whole tiles: `generation.lane_padded`)."""
-        return pad_lanes(x.reshape((pflat.shape[0],) + x.shape[2:]).astype(
-            pool.dtype), pool.shape[-1])
+            k = jnp.take_along_axis(k, idx4, axis=1)
+            v = jnp.take_along_axis(v, idx4, axis=1)
+        def rows(x, pool):
+            """x's (K, S) rows flat, at the pool's width (a row wider than a
+            lane tile is kept in whole tiles: `generation.lane_padded`)."""
+            flat = x.reshape((pflat.shape[0],) + x.shape[2:])
+            return pad_lanes(flat.astype(pool.dtype), pool.shape[-1])
 
-    return type(pc)(pool_rows_set(pc.kp, pflat, sflat, rows(k, pc.kp)),
-                    pool_rows_set(pc.vp, pflat, sflat, rows(v, pc.vp)))
+        return type(pc)(pool_rows_set(pc.kp, pflat, sflat, rows(k, pc.kp)),
+                        pool_rows_set(pc.vp, pflat, sflat, rows(v, pc.vp)))
 
 
 def _pool_gather(pages, btabs, st, Sb):
@@ -781,23 +783,24 @@ def _pool_gather(pages, btabs, st, Sb):
     the SAME roundtripped values a paged decode step would."""
     from ..models.generation import RowQuantKVCache
 
-    K = btabs.shape[0]
-    bs = pages[0].kp.shape[2]
-    maxb = btabs.shape[1]
-    s = jnp.arange(Sb)
-    blk = jnp.minimum(s // bs, maxb - 1)
-    gpage = jnp.take_along_axis(
-        btabs, jnp.broadcast_to(blk[None, :], (K, Sb)), axis=1)
-    gpage = jnp.where(s[None, :] < st[:, None], gpage, 0)
-    soff = jnp.broadcast_to((s % bs)[None, :], (K, Sb))
-    if _pool_quant(pages):
-        return [RowQuantKVCache(pc.kp[gpage, :, soff, :],
-                                pc.vp[gpage, :, soff, :],
-                                pc.ks[gpage, :, soff],
-                                pc.vs[gpage, :, soff])
+    with jax.named_scope('attn/cache'):
+        K = btabs.shape[0]
+        bs = pages[0].kp.shape[2]
+        maxb = btabs.shape[1]
+        s = jnp.arange(Sb)
+        blk = jnp.minimum(s // bs, maxb - 1)
+        gpage = jnp.take_along_axis(
+            btabs, jnp.broadcast_to(blk[None, :], (K, Sb)), axis=1)
+        gpage = jnp.where(s[None, :] < st[:, None], gpage, 0)
+        soff = jnp.broadcast_to((s % bs)[None, :], (K, Sb))
+        if _pool_quant(pages):
+            return [RowQuantKVCache(pc.kp[gpage, :, soff, :],
+                                    pc.vp[gpage, :, soff, :],
+                                    pc.ks[gpage, :, soff],
+                                    pc.vs[gpage, :, soff])
+                    for pc in pages]
+        return [(pc.kp[gpage, :, soff, :], pc.vp[gpage, :, soff, :])
                 for pc in pages]
-    return [(pc.kp[gpage, :, soff, :], pc.vp[gpage, :, soff, :])
-            for pc in pages]
 
 
 # per-request sampling randomness is STATELESS: the key for one
@@ -837,12 +840,13 @@ def _row_asks(temp, topk, topp, live):
     top-k filter, any for the nucleus: replicated device data that does
     not change inside a window, so a caller computes them ONCE, outside
     its scan, and the scan closes over them."""
-    sampled = (temp > 0) & live
-    temp = jnp.where(sampled, temp, 0.0)
-    topk = jnp.where(sampled, jnp.asarray(topk, jnp.int32), 0)
-    topp = jnp.where(sampled, jnp.asarray(topp, jnp.float32), 1.0)
-    return _RowAsks(temp, topk, topp, jnp.any(sampled),
-                    jnp.any(topk > 0), jnp.any(topp < 1.0))
+    with jax.named_scope('sample'):
+        sampled = (temp > 0) & live
+        temp = jnp.where(sampled, temp, 0.0)
+        topk = jnp.where(sampled, jnp.asarray(topk, jnp.int32), 0)
+        topp = jnp.where(sampled, jnp.asarray(topp, jnp.float32), 1.0)
+        return _RowAsks(temp, topk, topp, jnp.any(sampled),
+                        jnp.any(topk > 0), jnp.any(topp < 1.0))
 
 
 def _filtered_logits(lg, asks):
@@ -876,13 +880,14 @@ def _sample_rows(logits, asks, seed, gen):
     trace, so a batch mixing greedy and sampled rows (the per-request
     sampling contract) never retraces as the mix changes; a sampled
     row's token is the same whichever rows sit beside it."""
-    lg = logits.astype(jnp.float32)
-    greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-    return jax.lax.cond(
-        asks.any_sampled,
-        lambda: _draw_rows(_filtered_logits(lg, asks), greedy, asks, seed,
-                           gen),
-        lambda: greedy)
+    with jax.named_scope('sample'):
+        lg = logits.astype(jnp.float32)
+        greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+        return jax.lax.cond(
+            asks.any_sampled,
+            lambda: _draw_rows(_filtered_logits(lg, asks), greedy, asks,
+                               seed, gen),
+            lambda: greedy)
 
 
 def _filtered_dist(logits, asks):
@@ -899,14 +904,15 @@ def _sample_rows_dist(logits, asks, seed, gen):
     separate calls would double the full-vocab sorts in the hottest
     scan of the spec window). The dist has to exist, so the filter pass
     stands outside the "any row samples" `cond`; the draw is inside."""
-    lg = logits.astype(jnp.float32)
-    greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-    f = _filtered_logits(lg, asks)
-    tok = jax.lax.cond(
-        asks.any_sampled,
-        lambda: _draw_rows(f, greedy, asks, seed, gen),
-        lambda: greedy)
-    return tok, jax.nn.softmax(f, -1)
+    with jax.named_scope('sample'):
+        lg = logits.astype(jnp.float32)
+        greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+        f = _filtered_logits(lg, asks)
+        tok = jax.lax.cond(
+            asks.any_sampled,
+            lambda: _draw_rows(f, greedy, asks, seed, gen),
+            lambda: greedy)
+        return tok, jax.nn.softmax(f, -1)
 
 
 def _prefill_kv(model, pages, ids, real_len, btabs):
@@ -930,8 +936,9 @@ def _prefill_kv(model, pages, ids, real_len, btabs):
     tmp = _tmp_cache(model, pages, K, Sb)
     logits, tmp = model(ids, caches=tmp, cache_index=0)
     rl = jnp.reshape(jnp.asarray(real_len, jnp.int32), (K,))
-    last = jnp.take_along_axis(
-        logits, jnp.maximum(rl - 1, 0)[:, None, None], axis=1)[:, 0]
+    with jax.named_scope('head'):
+        last = jnp.take_along_axis(
+            logits, jnp.maximum(rl - 1, 0)[:, None, None], axis=1)[:, 0]
     bs = pages[0].kp.shape[2]
     s = jnp.arange(Sb)
 
@@ -946,8 +953,10 @@ def _prefill_kv(model, pages, ids, real_len, btabs):
         return page.reshape(-1)
 
     kinds = model.page_kinds()
-    pflat = [targets(t) for t in (btabs if len(kinds) > 1 else (btabs,))]
-    sflat = jnp.broadcast_to(s % bs, (K, Sb)).reshape(-1)
+    with jax.named_scope('attn/cache'):
+        pflat = [targets(t)
+                 for t in (btabs if len(kinds) > 1 else (btabs,))]
+        sflat = jnp.broadcast_to(s % bs, (K, Sb)).reshape(-1)
     out_pages = [_pool_scatter(pc, t, pflat[i], sflat)
                  for t, pc, i in zip(tmp, pages,
                                      layer_kinds(kinds, len(pages)))]
@@ -959,8 +968,9 @@ def _prefill_body(model, pages, last_logits, ids, real_len, btabs, slots):
     next-token logits land in its slot's row of `last_logits` (dummy
     rows carry slot == SLOTS, dropped by the out-of-bounds scatter)."""
     last, out_pages = _prefill_kv(model, pages, ids, real_len, btabs)
-    last_logits = last_logits.at[slots].set(
-        last.astype(last_logits.dtype), mode='drop')
+    with jax.named_scope('head'):
+        last_logits = last_logits.at[slots].set(
+            last.astype(last_logits.dtype), mode='drop')
     return _pin(last_logits), _pin_pages(out_pages)
 
 

@@ -130,26 +130,30 @@ class AfmoeAttention(Layer):
                 kvalid=None, kv_start=None, kv_write_pos=None,
                 block_tables=None):
         B, S, _ = x.shape
-        q = (x @ self.q_proj).reshape(B, S, self.num_heads, self.head_dim)
-        k = (x @ self.k_proj).reshape(B, S, self.num_kv_heads, self.head_dim)
-        v = (x @ self.v_proj).reshape(B, S, self.num_kv_heads, self.head_dim)
-        gate = x @ self.gate_proj
-        q = rms_norm(q, self.q_norm, self.eps)
-        k = rms_norm(k, self.k_norm, self.eps)
-        if self.sliding:
-            cos, sin = rope_cos_sin(positions, self.head_dim, self.rope_theta)
-            q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+        with jax.named_scope('attn/qkv'):
+            d = self.head_dim
+            q = (x @ self.q_proj).reshape(B, S, self.num_heads, d)
+            k = (x @ self.k_proj).reshape(B, S, self.num_kv_heads, d)
+            v = (x @ self.v_proj).reshape(B, S, self.num_kv_heads, d)
+            gate = x @ self.gate_proj
+            q = rms_norm(q, self.q_norm, self.eps)
+            k = rms_norm(k, self.k_norm, self.eps)
+            if self.sliding:
+                cos, sin = rope_cos_sin(positions, d, self.rope_theta)
+                q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
         if cache is None:
-            out = F.scaled_dot_product_attention(
-                q, k, v, is_causal=True, window_size=self.sliding_window)
+            with jax.named_scope('attn/core'):
+                out = F.scaled_dot_product_attention(
+                    q, k, v, is_causal=True, window_size=self.sliding_window)
             new_cache = None
         else:
             out, new_cache = cached_attention(
                 q, k, v, cache, cache_index, kvalid=kvalid,
                 kv_start=kv_start, kv_write_pos=kv_write_pos,
                 window=self.sliding_window, block_tables=block_tables)
-        out = out.reshape(B, S, self.num_heads * self.head_dim)
-        return _gated(out, gate) @ self.o_proj, new_cache
+        with jax.named_scope('attn/out'):
+            out = out.reshape(B, S, self.num_heads * self.head_dim)
+            return _gated(out, gate) @ self.o_proj, new_cache
 
 
 class AfmoeDecoderLayer(Layer):
@@ -211,9 +215,11 @@ class AfmoeForCausalLM(GenerationMixin, Layer):
         B, S = input_ids.shape
         if positions is None:
             positions = default_positions(B, S, cache_index, kv_write_pos)
-        x = self.embed_tokens[input_ids]
-        if self.config.mup_enabled:
-            x = x * jnp.asarray(math.sqrt(self.config.hidden_size), x.dtype)
+        with jax.named_scope('embed'):
+            x = self.embed_tokens[input_ids]
+            if self.config.mup_enabled:
+                x = x * jnp.asarray(math.sqrt(self.config.hidden_size),
+                                    x.dtype)
         new_caches = [] if caches is not None else None
         for i, layer in enumerate(self.layers):
             x, nc = layer(x, positions,
@@ -222,7 +228,9 @@ class AfmoeForCausalLM(GenerationMixin, Layer):
                           block_tables)
             if new_caches is not None:
                 new_caches.append(nc)
-        logits = self.norm(x) @ self.lm_head
+        x = self.norm(x)
+        with jax.named_scope('head'):
+            logits = x @ self.lm_head
         return logits if caches is None else (logits, new_caches)
 
     def loss(self, input_ids, labels=None):
@@ -233,7 +241,9 @@ class AfmoeForCausalLM(GenerationMixin, Layer):
         if labels is None:
             labels = input_ids[:, 1:]
             input_ids = input_ids[:, :-1]
-        return softmax_cross_entropy(self(input_ids), labels).mean()
+        logits = self(input_ids)
+        with jax.named_scope('head'):
+            return softmax_cross_entropy(logits, labels).mean()
 
     def cache_dtype(self):
         return self.embed_tokens.dtype

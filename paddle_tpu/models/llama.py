@@ -334,144 +334,148 @@ def cached_attention(q, k, v, cache, cache_index, kvalid=None,
         return _paged_cached_attention(q, k, v, cache, kv_write_pos,
                                        block_tables, window, kvalid,
                                        kv_start, sink)
-    if kv_write_pos is not None:
-        wp = jnp.reshape(jnp.asarray(kv_write_pos, jnp.int32), (-1,))
-        wp = jnp.broadcast_to(wp, (B,))
-        rows = jnp.arange(B)[:, None]
-        wcols = wp[:, None] + jnp.arange(S)[None, :]
-
-        def write(buf, new):
-            return buf.at[rows, wcols].set(new.astype(buf.dtype))
-    else:
-        def write(buf, new):
-            return jax.lax.dynamic_update_slice(
-                buf, new.astype(buf.dtype), (0, cache_index, 0, 0))
-    rowquant = isinstance(cache, RowQuantKVCache)
-    if rowquant:
-        # per-row int8 (the serving engine's fused multi-token bodies):
-        # rows quantize one at a time against their own amax — the
-        # exact rule the QuantPagedKVCache pools apply — and the whole
-        # cache dequantizes EAGERLY for attention, so every attended
-        # value is the int8-roundtripped one a paged decode step would
-        # see. That shared roundtrip is what keeps int8 serving streams
-        # bit-equal across prefill / chunk / speculative / decode paths.
-        kq, vq, ks, vs = cache
-        knew, ks_new = quantize_kv_row(k)
-        vnew, vs_new = quantize_kv_row(v)
-        kq = write(kq, knew)
-        vq = write(vq, vnew)
+    with jax.named_scope('attn/cache'):
         if kv_write_pos is not None:
-            ks = ks.at[rows, wcols].set(ks_new)
-            vs = vs.at[rows, wcols].set(vs_new)
+            wp = jnp.reshape(jnp.asarray(kv_write_pos, jnp.int32), (-1,))
+            wp = jnp.broadcast_to(wp, (B,))
+            rows = jnp.arange(B)[:, None]
+            wcols = wp[:, None] + jnp.arange(S)[None, :]
+
+            def write(buf, new):
+                return buf.at[rows, wcols].set(new.astype(buf.dtype))
         else:
-            ks = jax.lax.dynamic_update_slice(ks, ks_new,
-                                              (0, cache_index, 0))
-            vs = jax.lax.dynamic_update_slice(vs, vs_new,
-                                              (0, cache_index, 0))
-        new_cache = RowQuantKVCache(kq, vq, ks, vs)
-        ck = dequantize_kv_row(kq, ks, q.dtype)
-        cv = dequantize_kv_row(vq, vs, q.dtype)
-    quant = isinstance(cache, QuantKVCache)
-    if quant:
-        kq, vq, kscale, vscale = cache
-        # calibrate ONLY on the index-0 prefill: a later multi-token
-        # chunk (chunked prefill, speculative verify) must keep the
-        # existing scales — recalibrating would reinterpret every int8
-        # row already in the cache under new scales. cache_index is a
-        # concrete 0 at prefill in all generation loops; traced indices
-        # are by construction later steps.
-        is_prefill = (S > 1 and kv_write_pos is None
-                      and not isinstance(cache_index, jax.core.Tracer)
-                      and int(cache_index) == 0)
-        if is_prefill:
-            kscale = calibrate_kv_scale(k)
-            vscale = calibrate_kv_scale(v)
-        kq = write(kq, quantize_kv_rows(k, kscale))
-        vq = write(vq, quantize_kv_rows(v, vscale))
-        new_cache = QuantKVCache(kq, vq, kscale, vscale)
-        ck, cv = kq, vq
-    elif not rowquant:                 # rowquant set ck/cv above
-        ck, cv = cache
-        ck = write(ck, k)
-        cv = write(cv, v)
-        new_cache = (ck, cv)
-    max_len = ck.shape[1]
-    out = None
-    plain = sink is None and v.shape[-1] == D
-    if (S == 1 and D % 8 == 0 and plain
-            and (kvalid is None or kv_start is not None)):
-        from ..ops import use_pallas
-
-        if use_pallas():
-            # fused single-token decode: one streaming pass over the
-            # cache, routed through the serving dispatcher
-            # (ops/pallas/decode_attention.py — the same entry point the
-            # DecodeEngine decode loop reaches). Under a mesh each shard
-            # runs its own kernel, placed as init_cache places the cache
-            # (batch over dp/fsdp, heads over tp), so a sharded cache is
-            # NOT all-gathered every decode step
-            from jax.sharding import PartitionSpec as P
-
-            from ..ops import DATA_AXES, head_axis, mesh_kernel
-            from ..ops.pallas.decode_attention import (
-                dispatch_decode_attention)
-
-            def kernel(q_, k_, v_, vl_, st_, *scales):
-                return dispatch_decode_attention(
-                    q_, k_, v_, vl_, start=st_, window=window,
-                    k_scale=scales[0] if scales else None,
-                    v_scale=scales[1] if scales else None)
-
-            vl = jnp.broadcast_to(jnp.asarray(
-                wp + 1 if kv_write_pos is not None else cache_index + 1,
-                jnp.int32), (B,))
-            st = jnp.broadcast_to(jnp.asarray(
-                0 if kv_start is None else kv_start, jnp.int32), (B,))
-            heads = head_axis(H, ck.shape[2])
-            hspec = P(DATA_AXES, None, heads, None)
-            scales = (kscale, vscale) if quant else ()
-            out = mesh_kernel(
-                kernel, (q, ck, cv, vl, st) + scales,
-                (hspec, hspec, hspec, P(DATA_AXES), P(DATA_AXES))
-                + (P(heads, None),) * len(scales))
-    elif prefill_takes_kernel(S, D, cache, cache_index, kvalid, kv_start,
-                              kv_write_pos):
-        # the new rows as the cache holds them
-        out = _prefill_kernel(q, k.astype(ck.dtype), v.astype(cv.dtype),
-                              window, sink)
-    if out is None:
-        # valid keys: position <= current query position (& kvalid)
-        kpos = jnp.arange(max_len)
-        if kv_write_pos is not None:
-            # per-row query positions (batched speculative verify)
-            qpos = wp[:, None] + jnp.arange(S)[None, :]        # (B, S)
-            mask = (kpos[None, None, None, :] <= qpos[:, None, :, None])
-        else:
-            qpos = cache_index + jnp.arange(S)
-            mask = (kpos[None, :] <= qpos[:, None])[None, None]
-        if kvalid is not None:
-            mask = mask & (kvalid[:, None, None, :] > 0)
-        if kv_start is not None:
-            # honor the window start here too: a caller passing only
-            # kv_start must see the same window whether or not the
-            # fused kernel ran
-            st = jnp.reshape(jnp.asarray(kv_start, jnp.int32), (-1,))
-            mask = mask & (kpos[None, :] >= st[:, None])[:, None, None, :]
-        if window is not None:
-            # sliding window: qpos - kpos < window (qpos is (S,) uniform
-            # or (B, S) per-row; both broadcast against kpos)
-            if qpos.ndim == 2:
-                band = (qpos[:, :, None] - kpos[None, None, :]
-                        < window)[:, None]
+            def write(buf, new):
+                return jax.lax.dynamic_update_slice(
+                    buf, new.astype(buf.dtype), (0, cache_index, 0, 0))
+        rowquant = isinstance(cache, RowQuantKVCache)
+        if rowquant:
+            # per-row int8 (the serving engine's fused multi-token bodies):
+            # rows quantize one at a time against their own amax — the
+            # exact rule the QuantPagedKVCache pools apply — and the whole
+            # cache dequantizes EAGERLY for attention, so every attended
+            # value is the int8-roundtripped one a paged decode step would
+            # see. That shared roundtrip is what keeps int8 serving streams
+            # bit-equal across prefill / chunk / speculative / decode paths.
+            kq, vq, ks, vs = cache
+            knew, ks_new = quantize_kv_row(k)
+            vnew, vs_new = quantize_kv_row(v)
+            kq = write(kq, knew)
+            vq = write(vq, vnew)
+            if kv_write_pos is not None:
+                ks = ks.at[rows, wcols].set(ks_new)
+                vs = vs.at[rows, wcols].set(vs_new)
             else:
-                band = (qpos[:, None] - kpos[None, :] < window)[None, None]
-            mask = mask & band
+                ks = jax.lax.dynamic_update_slice(ks, ks_new,
+                                                  (0, cache_index, 0))
+                vs = jax.lax.dynamic_update_slice(vs, vs_new,
+                                                  (0, cache_index, 0))
+            new_cache = RowQuantKVCache(kq, vq, ks, vs)
+            ck = dequantize_kv_row(kq, ks, q.dtype)
+            cv = dequantize_kv_row(vq, vs, q.dtype)
+        quant = isinstance(cache, QuantKVCache)
         if quant:
-            # XLA fallback: whole-cache dequant (correctness path; the
-            # bandwidth win lives in the pallas kernel)
-            ck = (ck.astype(jnp.float32) * kscale[None, None]).astype(q.dtype)
-            cv = (cv.astype(jnp.float32) * vscale[None, None]).astype(q.dtype)
-        out = _xla_attention(q, ck, cv, mask, sink)
+            kq, vq, kscale, vscale = cache
+            # calibrate ONLY on the index-0 prefill: a later multi-token
+            # chunk (chunked prefill, speculative verify) must keep the
+            # existing scales — recalibrating would reinterpret every int8
+            # row already in the cache under new scales. cache_index is a
+            # concrete 0 at prefill in all generation loops; traced indices
+            # are by construction later steps.
+            is_prefill = (S > 1 and kv_write_pos is None
+                          and not isinstance(cache_index, jax.core.Tracer)
+                          and int(cache_index) == 0)
+            if is_prefill:
+                kscale = calibrate_kv_scale(k)
+                vscale = calibrate_kv_scale(v)
+            kq = write(kq, quantize_kv_rows(k, kscale))
+            vq = write(vq, quantize_kv_rows(v, vscale))
+            new_cache = QuantKVCache(kq, vq, kscale, vscale)
+            ck, cv = kq, vq
+        elif not rowquant:                 # rowquant set ck/cv above
+            ck, cv = cache
+            ck = write(ck, k)
+            cv = write(cv, v)
+            new_cache = (ck, cv)
+    with jax.named_scope('attn/core'):
+        max_len = ck.shape[1]
+        out = None
+        plain = sink is None and v.shape[-1] == D
+        if (S == 1 and D % 8 == 0 and plain
+                and (kvalid is None or kv_start is not None)):
+            from ..ops import use_pallas
+
+            if use_pallas():
+                # fused single-token decode: one streaming pass over the
+                # cache, routed through the serving dispatcher
+                # (ops/pallas/decode_attention.py — the same entry point the
+                # DecodeEngine decode loop reaches). Under a mesh each shard
+                # runs its own kernel, placed as init_cache places the cache
+                # (batch over dp/fsdp, heads over tp), so a sharded cache is
+                # NOT all-gathered every decode step
+                from jax.sharding import PartitionSpec as P
+
+                from ..ops import DATA_AXES, head_axis, mesh_kernel
+                from ..ops.pallas.decode_attention import (
+                    dispatch_decode_attention)
+
+                def kernel(q_, k_, v_, vl_, st_, *scales):
+                    return dispatch_decode_attention(
+                        q_, k_, v_, vl_, start=st_, window=window,
+                        k_scale=scales[0] if scales else None,
+                        v_scale=scales[1] if scales else None)
+
+                vl = jnp.broadcast_to(jnp.asarray(
+                    wp + 1 if kv_write_pos is not None else cache_index + 1,
+                    jnp.int32), (B,))
+                st = jnp.broadcast_to(jnp.asarray(
+                    0 if kv_start is None else kv_start, jnp.int32), (B,))
+                heads = head_axis(H, ck.shape[2])
+                hspec = P(DATA_AXES, None, heads, None)
+                scales = (kscale, vscale) if quant else ()
+                out = mesh_kernel(
+                    kernel, (q, ck, cv, vl, st) + scales,
+                    (hspec, hspec, hspec, P(DATA_AXES), P(DATA_AXES))
+                    + (P(heads, None),) * len(scales))
+        elif prefill_takes_kernel(S, D, cache, cache_index, kvalid, kv_start,
+                                  kv_write_pos):
+            # the new rows as the cache holds them
+            out = _prefill_kernel(q, k.astype(ck.dtype), v.astype(cv.dtype),
+                                  window, sink)
+        if out is None:
+            # valid keys: position <= current query position (& kvalid)
+            kpos = jnp.arange(max_len)
+            if kv_write_pos is not None:
+                # per-row query positions (batched speculative verify)
+                qpos = wp[:, None] + jnp.arange(S)[None, :]        # (B, S)
+                mask = (kpos[None, None, None, :] <= qpos[:, None, :, None])
+            else:
+                qpos = cache_index + jnp.arange(S)
+                mask = (kpos[None, :] <= qpos[:, None])[None, None]
+            if kvalid is not None:
+                mask = mask & (kvalid[:, None, None, :] > 0)
+            if kv_start is not None:
+                # honor the window start here too: a caller passing only
+                # kv_start must see the same window whether or not the
+                # fused kernel ran
+                st = jnp.reshape(jnp.asarray(kv_start, jnp.int32), (-1,))
+                mask = mask & (kpos[None, :] >= st[:, None])[:, None, None, :]
+            if window is not None:
+                # sliding window: qpos - kpos < window (qpos is (S,) uniform
+                # or (B, S) per-row; both broadcast against kpos)
+                if qpos.ndim == 2:
+                    band = (qpos[:, :, None] - kpos[None, None, :]
+                            < window)[:, None]
+                else:
+                    band = (qpos[:, None] - kpos[None, :] < window)[None, None]
+                mask = mask & band
+            if quant:
+                # XLA fallback: whole-cache dequant (correctness path; the
+                # bandwidth win lives in the pallas kernel)
+                ck = (ck.astype(jnp.float32)
+                      * kscale[None, None]).astype(q.dtype)
+                cv = (cv.astype(jnp.float32)
+                      * vscale[None, None]).astype(q.dtype)
+            out = _xla_attention(q, ck, cv, mask, sink)
     return out, new_cache
 
 
@@ -517,81 +521,85 @@ def _paged_cached_attention(q, k, v, cache, kv_write_pos, block_tables,
     def cut(x, to):
         return x if x.shape[-1] == to else x[..., :to]
 
-    tbl = jnp.asarray(block_tables, jnp.int32)
-    maxb = tbl.shape[1]
-    wp = jnp.broadcast_to(
-        jnp.reshape(jnp.asarray(kv_write_pos, jnp.int32), (-1,)), (B,))
-    rows = jnp.arange(B)
-    # frozen rows can sit one position past their last allocated page:
-    # clamp the COLUMN (the scheduler parks such rows on table entry 0,
-    # the scratch page, so the clamped write stays harmless)
-    page = tbl[rows, jnp.minimum(wp // BS, maxb - 1)]
-    slot = wp % BS
-    if quant:
-        # per-row int8: the new row quantizes against its own amax (the
-        # same pure-function rule the serving prefill scatter applies),
-        # so this row's int8 bytes are identical whether it was written
-        # here or by a re-prefill after preemption
-        kq, ksr = quantize_kv_row(k[:, 0])       # (B, Hkv, D), (B, Hkv)
-        vq, vsr = quantize_kv_row(v[:, 0])
-        kp = kp.at[page, :, slot, :].set(kq)
-        vp = vp.at[page, :, slot, :].set(vq)
-        kss = kss.at[page, :, slot].set(ksr)
-        vss = vss.at[page, :, slot].set(vsr)
-        new_cache = QuantPagedKVCache(kp, vp, kss, vss)
-    else:
-        kp = pool_rows_set(kp, page, slot,
-                           pad_lanes(k[:, 0], Dp).astype(kp.dtype))
-        vp = pool_rows_set(vp, page, slot,
-                           pad_lanes(v[:, 0], Dvp).astype(vp.dtype))
-        new_cache = PagedKVCache(kp, vp)
-    counts = wp + 1
-    out = None
-    if D % 8 == 0:
-        from ..ops import use_pallas
-
-        if use_pallas():
-            from jax.sharding import PartitionSpec as P
-
-            from ..ops import DATA_AXES, head_axis, mesh_kernel
-            from ..ops.pallas.paged_attention import (
-                paged_decode_attention)
-
-            def kernel(q_, kp_, vp_, tbl_, counts_, *scales):
-                # q's zero lanes meet the pool's: the scores are the
-                # rows' own, scaled by their own width
-                return cut(paged_decode_attention(
-                    pad_lanes(q_, Dp), kp_, vp_, tbl_, counts_,
-                    scale=1.0 / (D ** 0.5),
-                    k_scale=scales[0] if scales else None,
-                    v_scale=scales[1] if scales else None, window=window,
-                    sink=sink), Dv)
-
-            # pools split their kv-head dim over tp (init_paged_cache's
-            # placement); tables and lengths follow the batch
-            heads = head_axis(H, Hkv)
-            pool = P(None, heads, None, None)
-            scales = (kss, vss) if quant else ()
-            out = mesh_kernel(
-                kernel, (q, kp, vp, tbl, counts) + scales,
-                (P(DATA_AXES, None, heads, None), pool, pool,
-                 P(DATA_AXES, None), P(DATA_AXES))
-                + (P(None, heads, None),) * len(scales))
-    if out is None:
-        # gather reference (CPU tests / non-TPU): pages -> a contiguous
-        # (B, MAXB*BS, Hkv, D) view, masked by per-row valid length;
-        # int8 pools dequantize with the shared per-row expression
-        gk, gv = kp[tbl], vp[tbl]                # (B, maxb, Hkv, BS, D)
+    with jax.named_scope('attn/cache'):
+        tbl = jnp.asarray(block_tables, jnp.int32)
+        maxb = tbl.shape[1]
+        wp = jnp.broadcast_to(
+            jnp.reshape(jnp.asarray(kv_write_pos, jnp.int32), (-1,)), (B,))
+        rows = jnp.arange(B)
+        # frozen rows can sit one position past their last allocated page:
+        # clamp the COLUMN (the scheduler parks such rows on table entry 0,
+        # the scratch page, so the clamped write stays harmless)
+        page = tbl[rows, jnp.minimum(wp // BS, maxb - 1)]
+        slot = wp % BS
         if quant:
-            gk = dequantize_kv_row(gk, kss[tbl], q.dtype)
-            gv = dequantize_kv_row(gv, vss[tbl], q.dtype)
-        ck = cut(jnp.swapaxes(gk, 2, 3).reshape(B, maxb * BS, Hkv, Dp), D)
-        cv = cut(jnp.swapaxes(gv, 2, 3).reshape(B, maxb * BS, Hkv, Dvp), Dv)
-        kpos = jnp.arange(maxb * BS)[None, :]
-        mask = kpos < counts[:, None]
-        if window is not None:
-            mask = mask & (kpos >= counts[:, None] - window)
-        out = _xla_attention(q, ck, cv, mask[:, None, None, :], sink)
+            # per-row int8: the new row quantizes against its own amax (the
+            # same pure-function rule the serving prefill scatter applies),
+            # so this row's int8 bytes are identical whether it was written
+            # here or by a re-prefill after preemption
+            kq, ksr = quantize_kv_row(k[:, 0])       # (B, Hkv, D), (B, Hkv)
+            vq, vsr = quantize_kv_row(v[:, 0])
+            kp = kp.at[page, :, slot, :].set(kq)
+            vp = vp.at[page, :, slot, :].set(vq)
+            kss = kss.at[page, :, slot].set(ksr)
+            vss = vss.at[page, :, slot].set(vsr)
+            new_cache = QuantPagedKVCache(kp, vp, kss, vss)
+        else:
+            kp = pool_rows_set(kp, page, slot,
+                               pad_lanes(k[:, 0], Dp).astype(kp.dtype))
+            vp = pool_rows_set(vp, page, slot,
+                               pad_lanes(v[:, 0], Dvp).astype(vp.dtype))
+            new_cache = PagedKVCache(kp, vp)
+    with jax.named_scope('attn/core'):
+        counts = wp + 1
+        out = None
+        if D % 8 == 0:
+            from ..ops import use_pallas
+
+            if use_pallas():
+                from jax.sharding import PartitionSpec as P
+
+                from ..ops import DATA_AXES, head_axis, mesh_kernel
+                from ..ops.pallas.paged_attention import (
+                    paged_decode_attention)
+
+                def kernel(q_, kp_, vp_, tbl_, counts_, *scales):
+                    # q's zero lanes meet the pool's: the scores are the
+                    # rows' own, scaled by their own width
+                    return cut(paged_decode_attention(
+                        pad_lanes(q_, Dp), kp_, vp_, tbl_, counts_,
+                        scale=1.0 / (D ** 0.5),
+                        k_scale=scales[0] if scales else None,
+                        v_scale=scales[1] if scales else None, window=window,
+                        sink=sink), Dv)
+
+                # pools split their kv-head dim over tp (init_paged_cache's
+                # placement); tables and lengths follow the batch
+                heads = head_axis(H, Hkv)
+                pool = P(None, heads, None, None)
+                scales = (kss, vss) if quant else ()
+                out = mesh_kernel(
+                    kernel, (q, kp, vp, tbl, counts) + scales,
+                    (P(DATA_AXES, None, heads, None), pool, pool,
+                     P(DATA_AXES, None), P(DATA_AXES))
+                    + (P(None, heads, None),) * len(scales))
+        if out is None:
+            # gather reference (CPU tests / non-TPU): pages -> a contiguous
+            # (B, MAXB*BS, Hkv, D) view, masked by per-row valid length;
+            # int8 pools dequantize with the shared per-row expression
+            gk, gv = kp[tbl], vp[tbl]                # (B, maxb, Hkv, BS, D)
+            if quant:
+                gk = dequantize_kv_row(gk, kss[tbl], q.dtype)
+                gv = dequantize_kv_row(gv, vss[tbl], q.dtype)
+            ck = cut(jnp.swapaxes(gk, 2, 3).reshape(B, maxb * BS, Hkv, Dp),
+                     D)
+            cv = cut(jnp.swapaxes(gv, 2, 3).reshape(B, maxb * BS, Hkv, Dvp),
+                     Dv)
+            kpos = jnp.arange(maxb * BS)[None, :]
+            mask = kpos < counts[:, None]
+            if window is not None:
+                mask = mask & (kpos >= counts[:, None] - window)
+            out = _xla_attention(q, ck, cv, mask[:, None, None, :], sink)
     return out, new_cache
 
 
@@ -658,85 +666,88 @@ class LlamaAttention(Layer):
         `kvalid` additionally invalidates rows — left-pad support).
         """
         B, S, _ = x.shape
-        q, k, v = x @ self.q_proj, x @ self.k_proj, x @ self.v_proj
-        if self.q_bias is not None:
-            q, k, v = q + self.q_bias, k + self.k_bias, v + self.v_bias
-        q = q.reshape(B, S, self.num_heads, self.head_dim)
-        k = k.reshape(B, S, self.num_kv_heads, self.head_dim)
-        v = v.reshape(B, S, self.num_kv_heads, self.head_dim)
+        with jax.named_scope('attn/qkv'):
+            q, k, v = x @ self.q_proj, x @ self.k_proj, x @ self.v_proj
+            if self.q_bias is not None:
+                q, k, v = q + self.q_bias, k + self.k_bias, v + self.v_bias
+            q = q.reshape(B, S, self.num_heads, self.head_dim)
+            k = k.reshape(B, S, self.num_kv_heads, self.head_dim)
+            v = v.reshape(B, S, self.num_kv_heads, self.head_dim)
 
-        cos, sin = rope_cos_sin(positions, self.head_dim, self.rope_theta,
-                                rope_scaling=self.rope_scaling)
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
+            cos, sin = rope_cos_sin(positions, self.head_dim, self.rope_theta,
+                                    rope_scaling=self.rope_scaling)
+            q = apply_rotary(q, cos, sin)
+            k = apply_rotary(k, cos, sin)
 
         if cache is None:
-            win = self.sliding_window
-            if kvalid is not None or (win is not None
-                                      and attn_mask is not None):
-                # honor pad-invalidation (and the SWA band when a user
-                # mask blocks the kernel path) on the uncached path too:
-                # fold into an explicit causal mask (silently ignoring
-                # kvalid would let real tokens attend to pads)
-                extra_mask = (jnp.arange(S)[None, :]
-                              <= jnp.arange(S)[:, None])[None, None]
-                if kvalid is not None:
-                    extra_mask = extra_mask & (
-                        kvalid[:, :S] > 0)[:, None, None, :]
-                if win is not None:
-                    extra_mask = extra_mask & (
-                        jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
-                        < win)[None, None]
-                    win = None          # folded; don't pass to sdpa too
-                if attn_mask is None:
-                    attn_mask = extra_mask
-                elif attn_mask.dtype == jnp.bool_:
-                    attn_mask = attn_mask & extra_mask
-                else:                  # additive float mask (see attention.py)
-                    attn_mask = attn_mask + jnp.where(
-                        extra_mask, 0.0, -1e30).astype(attn_mask.dtype)
-            out = None
-            if (self.sequence_parallel and attn_mask is None
-                    and win is None):
-                from ..distributed.mesh import get_mesh
+            with jax.named_scope('attn/core'):
+                win = self.sliding_window
+                if kvalid is not None or (win is not None
+                                          and attn_mask is not None):
+                    # honor pad-invalidation (and the SWA band when a user
+                    # mask blocks the kernel path) on the uncached path too:
+                    # fold into an explicit causal mask (silently ignoring
+                    # kvalid would let real tokens attend to pads)
+                    extra_mask = (jnp.arange(S)[None, :]
+                                  <= jnp.arange(S)[:, None])[None, None]
+                    if kvalid is not None:
+                        extra_mask = extra_mask & (
+                            kvalid[:, :S] > 0)[:, None, None, :]
+                    if win is not None:
+                        extra_mask = extra_mask & (
+                            jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+                            < win)[None, None]
+                        win = None          # folded; don't pass to sdpa too
+                    if attn_mask is None:
+                        attn_mask = extra_mask
+                    elif attn_mask.dtype == jnp.bool_:
+                        attn_mask = attn_mask & extra_mask
+                    else:      # additive float mask (see attention.py)
+                        attn_mask = attn_mask + jnp.where(
+                            extra_mask, 0.0, -1e30).astype(attn_mask.dtype)
+                out = None
+                if (self.sequence_parallel and attn_mask is None
+                        and win is None):
+                    from ..distributed.mesh import get_mesh
 
-                mesh = get_mesh()
-                if (mesh is not None and 'sp' in mesh.axis_names
-                        and mesh.shape['sp'] > 1
-                        and S % mesh.shape['sp'] == 0):
-                    n_sp = mesh.shape['sp']
-                    use_ulysses = self.sp_mode == 'ulysses'
-                    if use_ulysses and (self.num_heads % n_sp
-                                        or self.num_kv_heads % n_sp):
-                        import warnings
+                    mesh = get_mesh()
+                    if (mesh is not None and 'sp' in mesh.axis_names
+                            and mesh.shape['sp'] > 1
+                            and S % mesh.shape['sp'] == 0):
+                        n_sp = mesh.shape['sp']
+                        use_ulysses = self.sp_mode == 'ulysses'
+                        if use_ulysses and (self.num_heads % n_sp
+                                            or self.num_kv_heads % n_sp):
+                            import warnings
 
-                        warnings.warn(
-                            f'sp_mode=ulysses needs heads divisible by the '
-                            f'sp axis ({self.num_heads}/{self.num_kv_heads} '
-                            f'heads vs sp={n_sp}); falling back to ring '
-                            f'attention', stacklevel=2)
-                        use_ulysses = False
-                    if use_ulysses:
-                        # all-to-all swaps the shard dim seq->heads; each
-                        # rank runs full-seq flash for its head slice
-                        from ..distributed.ulysses import (
-                            ulysses_attention_sharded)
+                            warnings.warn(
+                                f'sp_mode=ulysses needs heads divisible by '
+                                f'the sp axis ({self.num_heads}/'
+                                f'{self.num_kv_heads} heads vs sp={n_sp}); '
+                                f'falling back to ring attention',
+                                stacklevel=2)
+                            use_ulysses = False
+                        if use_ulysses:
+                            # all-to-all swaps the shard dim seq->heads; each
+                            # rank runs full-seq flash for its head slice
+                            from ..distributed.ulysses import (
+                                ulysses_attention_sharded)
 
-                        out = ulysses_attention_sharded(
-                            q, k, v, mesh, axis='sp', causal=True)
-                    else:
-                        # KV blocks ring around the ICI via ppermute —
-                        # no device ever holds the full KV
-                        from ..distributed.ring_attention import (
-                            ring_attention_sharded)
+                            out = ulysses_attention_sharded(
+                                q, k, v, mesh, axis='sp', causal=True)
+                        else:
+                            # KV blocks ring around the ICI via ppermute —
+                            # no device ever holds the full KV
+                            from ..distributed.ring_attention import (
+                                ring_attention_sharded)
 
-                        out = ring_attention_sharded(q, k, v, mesh,
-                                                     axis='sp', causal=True)
-            if out is None:
-                out = F.scaled_dot_product_attention(
-                    q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None,
-                    window_size=win)
-            new_cache = None
+                            out = ring_attention_sharded(
+                                q, k, v, mesh, axis='sp', causal=True)
+                if out is None:
+                    out = F.scaled_dot_product_attention(
+                        q, k, v, attn_mask=attn_mask,
+                        is_causal=attn_mask is None, window_size=win)
+                new_cache = None
         else:
             out, new_cache = cached_attention(q, k, v, cache, cache_index,
                                               kvalid=kvalid,
@@ -745,8 +756,9 @@ class LlamaAttention(Layer):
                                               window=self.sliding_window,
                                               block_tables=block_tables)
 
-        out = out.reshape(B, S, self.num_heads * self.head_dim)
-        return out @ self.o_proj, new_cache
+        with jax.named_scope('attn/out'):
+            out = out.reshape(B, S, self.num_heads * self.head_dim)
+            return out @ self.o_proj, new_cache
 
 
 class LlamaMLP(Layer):
@@ -761,7 +773,9 @@ class LlamaMLP(Layer):
         self.down_proj = Parameter(init((m, h), config.dtype), spec=P('tp', None))
 
     def forward(self, x):
-        return (F.silu(x @ self.gate_proj) * (x @ self.up_proj)) @ self.down_proj
+        with jax.named_scope('mlp'):
+            return ((F.silu(x @ self.gate_proj) * (x @ self.up_proj))
+                    @ self.down_proj)
 
 
 class LlamaDecoderLayer(Layer):
@@ -817,7 +831,8 @@ class LlamaModel(Layer):
         # (tp, fsdp) table sharding doesn't force an activation remat
         # (see distributed.embedding_lookup)
         from ..distributed import embedding_lookup
-        x = embedding_lookup(self.embed_tokens, input_ids)
+        with jax.named_scope('embed'):
+            x = embedding_lookup(self.embed_tokens, input_ids)
         new_caches = [] if caches is not None else None
         use_remat = self.config.remat and caches is None
         for i, layer in enumerate(self.layers):
@@ -860,9 +875,10 @@ class LlamaForCausalLM(GenerationMixin, Layer):
             )
 
     def logits(self, hidden):
-        if self.lm_head is None:
-            return hidden @ self.model.embed_tokens.T
-        return hidden @ self.lm_head
+        with jax.named_scope('head'):
+            if self.lm_head is None:
+                return hidden @ self.model.embed_tokens.T
+            return hidden @ self.lm_head
 
     def forward(self, input_ids, positions=None, attn_mask=None, caches=None,
                 cache_index=None, kvalid=None, kv_start=None,
@@ -883,7 +899,8 @@ class LlamaForCausalLM(GenerationMixin, Layer):
             labels = input_ids[:, 1:]
             input_ids = input_ids[:, :-1]
         logits = self(input_ids)
-        return softmax_cross_entropy(logits, labels).mean()
+        with jax.named_scope('head'):
+            return softmax_cross_entropy(logits, labels).mean()
 
 
     # -- generation (loops from GenerationMixin) ---------------------------

@@ -36,6 +36,7 @@ from __future__ import annotations
 import dataclasses
 import typing
 
+import jax
 import jax.numpy as jnp
 
 from .. import nn
@@ -157,20 +158,24 @@ class MimoV2Attention(Layer):
                 kvalid=None, kv_start=None, kv_write_pos=None,
                 block_tables=None):
         B, S, _ = x.shape
-        q = (x @ self.q_proj).reshape(B, S, self.num_heads, self.head_dim)
-        k = (x @ self.k_proj).reshape(B, S, self.num_kv_heads, self.head_dim)
-        v = (x @ self.v_proj).reshape(B, S, self.num_kv_heads,
-                                      self.v_head_dim)
-        v = v * jnp.asarray(self.value_scale, v.dtype)
-        cos, sin = rope_cos_sin(positions, self.rotary_dim, self.rope_theta)
-        q, k = self._rotate(q, cos, sin), self._rotate(k, cos, sin)
+        with jax.named_scope('attn/qkv'):
+            d = self.head_dim
+            q = (x @ self.q_proj).reshape(B, S, self.num_heads, d)
+            k = (x @ self.k_proj).reshape(B, S, self.num_kv_heads, d)
+            v = (x @ self.v_proj).reshape(B, S, self.num_kv_heads,
+                                          self.v_head_dim)
+            v = v * jnp.asarray(self.value_scale, v.dtype)
+            cos, sin = rope_cos_sin(positions, self.rotary_dim,
+                                    self.rope_theta)
+            q, k = self._rotate(q, cos, sin), self._rotate(k, cos, sin)
         if cache is None:
-            ahead = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
-            seen = ahead >= 0
-            if self.sliding_window is not None:
-                seen = seen & (ahead < self.sliding_window)
-            out = masked_attention(q, k, v, seen[None, None],
-                                   self.attention_sink_bias)
+            with jax.named_scope('attn/core'):
+                ahead = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+                seen = ahead >= 0
+                if self.sliding_window is not None:
+                    seen = seen & (ahead < self.sliding_window)
+                out = masked_attention(q, k, v, seen[None, None],
+                                       self.attention_sink_bias)
             new_cache = None
         else:
             out, new_cache = cached_attention(
@@ -178,8 +183,9 @@ class MimoV2Attention(Layer):
                 kv_start=kv_start, kv_write_pos=kv_write_pos,
                 window=self.sliding_window, block_tables=block_tables,
                 sink=self.attention_sink_bias)
-        out = out.reshape(B, S, self.num_heads * self.v_head_dim)
-        return out @ self.o_proj, new_cache
+        with jax.named_scope('attn/out'):
+            out = out.reshape(B, S, self.num_heads * self.v_head_dim)
+            return out @ self.o_proj, new_cache
 
 
 class MimoV2DecoderLayer(Layer):
@@ -255,7 +261,8 @@ class MimoV2ForCausalLM(GenerationMixin, Layer):
         n = self.config.num_hidden_layers
         tables = ([None] * n if block_tables is None
                   else layer_tables(self.page_kinds(), block_tables, n))
-        x = self.embed_tokens[input_ids]
+        with jax.named_scope('embed'):
+            x = self.embed_tokens[input_ids]
         new_caches = [] if caches is not None else None
         for i, layer in enumerate(self.layers):
             x, nc = layer(x, positions,
@@ -264,7 +271,9 @@ class MimoV2ForCausalLM(GenerationMixin, Layer):
                           tables[i])
             if new_caches is not None:
                 new_caches.append(nc)
-        logits = self.norm(x) @ self.lm_head
+        x = self.norm(x)
+        with jax.named_scope('head'):
+            logits = x @ self.lm_head
         return logits if caches is None else (logits, new_caches)
 
     def loss(self, input_ids, labels=None):
@@ -275,7 +284,9 @@ class MimoV2ForCausalLM(GenerationMixin, Layer):
         if labels is None:
             labels = input_ids[:, 1:]
             input_ids = input_ids[:, :-1]
-        return softmax_cross_entropy(self(input_ids), labels).mean()
+        logits = self(input_ids)
+        with jax.named_scope('head'):
+            return softmax_cross_entropy(logits, labels).mean()
 
     def cache_dtype(self):
         return self.embed_tokens.dtype

@@ -6,6 +6,7 @@ train step carries the new stats out functionally.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .. import functional as F
@@ -122,7 +123,8 @@ class RMSNorm(Layer):
     def forward(self, x):
         from ...ops import rms_norm as fused_rms_norm
 
-        return fused_rms_norm(x, self.weight, self.epsilon)
+        with jax.named_scope('norm'):
+            return fused_rms_norm(x, self.weight, self.epsilon)
 
 
 class GroupNorm(Layer):

@@ -52,7 +52,7 @@ from .metrics import (  # noqa: F401
 from .postmortem import dump_bundle, load_bundle, validate_bundle  # noqa: F401
 from .timeseries import TIMESERIES, WindowedTimeseries  # noqa: F401
 from .tracing import (  # noqa: F401
-    TRACER, HostTracer, annotate, compile_event, instant, span,
+    DEVICE_SCOPES, TRACER, HostTracer, compile_event, instant, span,
 )
 from .watchdog import SLORule, Watchdog, default_serving_rules  # noqa: F401
 
@@ -61,8 +61,8 @@ __all__ = [
     'timeseries', 'watchdog', 'httpd',
     'REGISTRY', 'Counter', 'Gauge', 'Histogram', 'MetricsRegistry',
     'enabled', 'set_enabled', 'inc', 'set_gauge', 'observe',
-    'TRACER', 'HostTracer', 'span', 'instant', 'compile_event',
-    'annotate',
+    'TRACER', 'HostTracer', 'DEVICE_SCOPES', 'span', 'instant',
+    'compile_event',
     'JOURNAL', 'Journal', 'journal_enabled', 'set_journal_enabled',
     'trail', 'trail_complete',
     'dump_bundle', 'validate_bundle', 'load_bundle',

@@ -28,6 +28,35 @@ profiler's trace, on the profiler's clock, beside the device's
 operations, and the ring holds their `args`. A ring event recorded while
 a session was on carries `traced: true`; `HostTracer.traced()` cuts the
 ring to the newest session without a clock match.
+
+The device side carries no spans of its own: an instruction's event in
+the profiler's trace is named by the instruction. What it came from is
+the `jax.named_scope` open where it was traced, which XLA keeps in every
+compiled instruction's `op_name` metadata (Xprof and Perfetto show it
+beside the device op). The model parts open one scope each, from one
+closed vocabulary, `DEVICE_SCOPES` (paths, outermost first):
+
+  embed                  the token embedding's gather
+  norm                   every `nn.RMSNorm`
+  attn/qkv               q/k/v products, biases, q/k norms, rotary
+  attn/cache             K/V row writes into caches and page pools
+  attn/core              the attention itself: paged or flash kernel,
+                         or the masked XLA path
+  attn/out               the output gate and `o_proj`
+  mlp                    a dense SwiGLU (`LlamaMLP`)
+  expert_share/route     router product, top-k, routing counts
+  expert_share/dispatch  sort by expert, gather of the rows
+  expert_share/matmul    the grouped expert products
+  expert_share/combine   gate weighting, held mask, scatter-add
+  expert_share/shared    the shared expert
+  head                   the vocabulary product, the rows kept of it,
+                         the train loss's cross-entropy
+  sample                 the sampler
+
+An expert layer's stages open their last segment inside
+`ExpertShare.SCOPE` (`expert_share`); every other part opens its whole
+path. Scopes are trace-time metadata: the compiled programs hold the
+same instructions as without them, up to some instructions' names.
 """
 from __future__ import annotations
 
@@ -39,8 +68,14 @@ import time
 
 from . import metrics as _metrics
 
-__all__ = ['HostTracer', 'TRACER', 'span', 'instant', 'compile_event',
-           'annotate', 'export', 'save', 'to_chrome_trace']
+__all__ = ['HostTracer', 'TRACER', 'DEVICE_SCOPES', 'span', 'instant',
+           'compile_event', 'export', 'save', 'to_chrome_trace']
+
+DEVICE_SCOPES = (
+    'embed', 'norm', 'attn/qkv', 'attn/cache', 'attn/core', 'attn/out',
+    'mlp', 'expert_share/route', 'expert_share/dispatch',
+    'expert_share/matmul', 'expert_share/combine', 'expert_share/shared',
+    'head', 'sample')
 
 # one process-wide epoch so every event's ts is comparable; perf_counter
 # is monotonic (wall-clock jumps cannot reorder spans)
@@ -229,7 +264,7 @@ class HostTracer:
         return self.events()
 
     def to_json(self, **kw):
-        # default=str: span args are caller-supplied (annotate(**args))
+        # default=str: span args are caller-supplied (span(**args))
         # and a non-serializable arg must degrade to its repr, never
         # make the export raise
         kw.setdefault('default', str)
@@ -275,8 +310,3 @@ def save(path):
 
 def to_chrome_trace():
     return TRACER.to_chrome_trace()
-
-
-# the name profiler.RecordEvent's decorator form and older callers use
-# for the same primitive
-annotate = span

@@ -63,7 +63,7 @@ class RecordEvent:
 
         @functools.wraps(fn)
         def wrapped(*a, **kw):
-            with _tracing.annotate(self.name, cat='record_event'):
+            with _tracing.span(self.name, cat='record_event'):
                 return fn(*a, **kw)
 
         return wrapped

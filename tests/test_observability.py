@@ -422,7 +422,7 @@ class TestHostTracer:
 
     def test_annotate_records_host_span(self):
         n0 = len(obs.TRACER)
-        with obs.annotate('dual_name'):
+        with obs.span('dual_name'):
             pass
         evs = obs.TRACER.events()[n0:]
         assert [e['name'] for e in evs] == ['dual_name']
@@ -696,7 +696,7 @@ class TestMetaTracelint:
         """The registry/tracer/journal/postmortem must be importable
         (and recordable) without a backend — stdlib-only at module
         level by design; tracing only reaches for jax inside
-        annotate(), costs only inside its device/lowering helpers."""
+        span(), costs only inside its device/lowering helpers."""
         import paddle_tpu.observability.costs as c
         import paddle_tpu.observability.httpd as hs
         import paddle_tpu.observability.journal as j
@@ -1019,13 +1019,10 @@ class TestSpansOnTheProfilersClock:
                                                     'committed': 5}
 
     def test_one_code_path_opens_a_span(self):
-        """`annotate` is a name for `span`, and nothing else in the
+        """`span` is the one host span primitive: nothing else in the
         package opens a TraceAnnotation of its own."""
         import re
 
-        from paddle_tpu.observability import tracing
-
-        assert tracing.annotate is tracing.span
         hits = []
         for root, _dirs, files in os.walk(os.path.join(REPO, 'paddle_tpu')):
             for name in files:
